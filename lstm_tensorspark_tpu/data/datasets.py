@@ -1,8 +1,12 @@
 """Dataset registry for the five baseline configs (BASELINE.md).
 
-Each entry returns real data when files exist under ``data_path``, otherwise
-a deterministic synthetic stand-in with identical interface — required by the
-no-network environment (SURVEY.md §7 "Hard parts").
+Each entry returns real data from ``data_path``; with NO ``data_path`` it
+returns a deterministic synthetic stand-in with identical interface —
+required by the no-network environment (SURVEY.md §7 "Hard parts"). A
+``data_path`` that is given and holds no such files is an error
+(`DataPathError`): the stand-ins are smaller than the corpora they stand in
+for (a 5,000-word chain for WikiText-103's 50,000-word vocabulary), so a
+typo would silently train a different model.
 
 Returned dict: {"train","valid","test"} token arrays (LM) or
 (sequences, labels) tuples (classification) or float arrays (forecasting),
@@ -22,6 +26,16 @@ from .corpus import (
     resolve_split_files,
     synthetic_text,
 )
+
+
+class DataPathError(ValueError):
+    """``data_path`` was given and the dataset's files are not there."""
+
+
+def _no_files(data_path: str, what: str) -> DataPathError:
+    return DataPathError(
+        f"--data-path {data_path!r}: {what} not found there; omit "
+        "--data-path to train on the synthetic stand-in")
 
 
 # Version tag for the synthetic word-corpus CACHE FORMAT+ALGORITHM. Bump on
@@ -135,6 +149,11 @@ def _lm_dataset(
 ):
     files = resolve_split_files(data_path or "", basenames)
     synthetic = files is None
+    if synthetic and data_path:
+        raise _no_files(
+            data_path, "train/valid/test files named "
+            f"{basenames[0]}.<split>.txt, <split>.txt or "
+            f"{basenames[0]}.<split>.tokens")
     if synthetic:
         if synthetic_vocab is not None:
             # controlled-entropy stand-in (word LMs): the splits share the
@@ -295,6 +314,8 @@ def imdb(data_path=None, *, num_examples: int | None = None, max_len: int = 400,
     if root is not None:
         return _imdb_real(root, max_len=max_len, seed=seed,
                           max_examples=num_examples)
+    if data_path:
+        raise _no_files(data_path, "aclImdb/{train,test}/{pos,neg}/*.txt")
     num_examples = num_examples or 2000
     rng = np.random.RandomState(seed)
     text = synthetic_text(50_000, seed)
@@ -429,6 +450,8 @@ def uci_electricity(data_path=None, *, num_series: int = 8, length: int = 10_000
     uci_file = _resolve_uci_file(data_path)
     if uci_file is not None:
         return _uci_real(uci_file, num_series=num_series)
+    if data_path:
+        raise _no_files(data_path, "LD2011_2014.txt")
     rng = np.random.RandomState(seed)
     t = np.arange(length, dtype=np.float32)
     series = []

@@ -2,16 +2,23 @@
 with transparent pure-Python fallback.
 
 The .so is built on demand via the checked-in Makefile (g++ is part of the
-toolchain); if the build or load fails, every entry point falls back to the
-numpy/Python implementation with identical results — the native path is a
-host-side throughput optimization, never a correctness dependency.
+toolchain) into ``native/build/`` (gitignored), under a name that carries
+the hash of what it was built from — so a binary is only ever loaded for
+the source it came from, whatever the mtimes say and whatever stray
+binary a copied tree brings along. If the build or load fails, one line
+says so and every entry point falls back to the numpy/Python
+implementation with identical results — the native path is a host-side
+throughput optimization, never a correctness dependency.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
+import sys
 
 import numpy as np
 
@@ -19,10 +26,43 @@ _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     "native",
 )
-_SO_PATH = os.path.join(_NATIVE_DIR, "libfastdata.so")
+_BUILD_DIR = os.path.join(_NATIVE_DIR, "build")
 
 _lib = None
 _load_attempted = False
+
+
+def _so_path() -> str:
+    """``native/build/libfastdata-<hash>.so`` for the CURRENT source and
+    build recipe (content hash of fastdata.cpp + Makefile)."""
+    h = hashlib.sha256()
+    for name in ("fastdata.cpp", "Makefile"):
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    return os.path.join(_BUILD_DIR, f"libfastdata-{h.hexdigest()[:16]}.so")
+
+
+def _build(so_path: str) -> None:
+    """Compile to a private name, publish atomically (concurrent test
+    workers may build at once), then drop binaries of other sources."""
+    tmp = f"{so_path}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(
+            ["make", "-C", _NATIVE_DIR, "-s", f"OUT={tmp}"],
+            check=True, capture_output=True, timeout=120,
+        )
+        os.replace(tmp, so_path)
+    finally:
+        try:
+            os.remove(tmp)
+        except FileNotFoundError:
+            pass  # published by the replace above, or never written
+    for old in glob.glob(os.path.join(_BUILD_DIR, "libfastdata-*.so")):
+        if old != so_path:
+            try:
+                os.remove(old)
+            except OSError:
+                pass  # another process got there first
 
 
 def _load():
@@ -33,17 +73,10 @@ def _load():
     if os.environ.get("LSTM_TSP_NO_NATIVE") == "1":
         return None
     try:
-        src = os.path.join(_NATIVE_DIR, "fastdata.cpp")
-        stale = not os.path.exists(_SO_PATH) or (
-            os.path.exists(src)
-            and os.path.getmtime(src) > os.path.getmtime(_SO_PATH)
-        )
-        if stale:
-            subprocess.run(
-                ["make", "-C", _NATIVE_DIR, "-sB"],
-                check=True, capture_output=True, timeout=120,
-            )
-        lib = ctypes.CDLL(_SO_PATH)
+        so_path = _so_path()
+        if not os.path.exists(so_path):
+            _build(so_path)
+        lib = ctypes.CDLL(so_path)
         lib.encode_bytes.argtypes = [
             ctypes.c_char_p, ctypes.c_int64,
             ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
@@ -73,7 +106,13 @@ def _load():
             ctypes.POINTER(ctypes.c_float), ctypes.c_int64,
         ]
         _lib = lib
-    except Exception:
+    except (OSError, subprocess.SubprocessError, AttributeError) as e:
+        detail = getattr(e, "stderr", None) or e
+        if isinstance(detail, bytes):
+            detail = detail.decode(errors="replace")
+        print("native: could not build/load fastdata ("
+              + " ".join(str(detail).split())[:300]
+              + ") — using the Python data path", file=sys.stderr, flush=True)
         _lib = None
     return _lib
 

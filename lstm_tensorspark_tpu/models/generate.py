@@ -151,6 +151,55 @@ def generate(
     return jnp.concatenate([prompt, new], axis=1)
 
 
+def reference_next_logits(params, tokens, cfg: LMConfig) -> jax.Array:
+    """The plain reference for ONE position: logits [V] of the token that
+    follows ``tokens`` [T], the whole model in float32 at matmul precision
+    "highest" on `lax.scan` — what a greedy pick is judged against when
+    two fast paths disagree (a rounding tie at a wide vocabulary in bf16,
+    or a bug)."""
+    ref = dataclasses.replace(cfg, compute_dtype="float32",
+                              logits_dtype="float32", use_pallas=False,
+                              remat_chunk=None)
+    with jax.default_matmul_precision("highest"):
+        logits, _ = lm_forward(params, jnp.asarray(tokens)[None, :], ref)
+    return logits[0, -1]
+
+
+def judge_greedy_divergence(params, cfg: LMConfig, prompt, got, want):
+    """Two greedy continuations of ``prompt`` that should be one: equal,
+    a rounding TIE, or really different? Two programs (batched windows,
+    a single-sequence scan) round near-tied logits differently, so greedy
+    identity between them can flip without either being wrong. Judged at
+    the FIRST divergence only — after it the two legitimately feed
+    different tokens back: a tie when both picks sit within a stated
+    tolerance of the `reference_next_logits` maximum. The tolerance is
+    ``2^-6 x max|logit|`` where the matmuls take bf16 inputs (bf16
+    compute, or a TPU at default precision), ``2^-16 x`` in true float32.
+
+    Returns ``(verdict, detail)``: verdict is ``"equal"``, ``"tie"`` or
+    ``"real"``; ``detail`` is the sentence to print."""
+    import numpy as np
+
+    got, want = np.asarray(got), np.asarray(want)
+    if np.array_equal(got, want):
+        return "equal", ""
+    if got.shape != want.shape:
+        return "real", f"lengths differ: {got.size} vs {want.size}"
+    j = int(np.argmax(got != want))
+    logits = np.asarray(reference_next_logits(
+        params, np.concatenate([np.asarray(prompt), want[:j]]), cfg))
+    low_precision = (cfg.compute_dtype == "bfloat16"
+                     or jax.default_backend() == "tpu")
+    log2_rel = -6 if low_precision else -16
+    tol = 2.0 ** log2_rel * float(np.abs(logits).max())
+    gap = float(logits.max() - min(logits[got[j]], logits[want[j]]))
+    verdict = "tie" if gap <= tol else "real"
+    return verdict, (
+        f"{verdict.upper()} at token {j}: picks {int(got[j])}/"
+        f"{int(want[j])} are {gap:.3g} below the float32 reference "
+        f"maximum (tolerance {tol:.3g} = 2^{log2_rel} x max|logit|)")
+
+
 def make_generate_fn(
     cfg: LMConfig,
     *,

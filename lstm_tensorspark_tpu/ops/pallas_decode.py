@@ -106,9 +106,84 @@ def plan_fits(batch_b: int, window: int, num_layers: int, hidden: int,
                       sampled=sampled, pbytes=pbytes) <= _VMEM_BUDGET
 
 
-def _decode_window_kernel(*refs, num_layers: int, hidden: int, vocab: int,
+def _argmax_col(x):
+    """``jnp.argmax(x, axis=-1)`` as a [B, 1] int32 column: the first
+    index of the row maximum (NaN counts as maximal, as in jnp.argmax).
+    Built from keepdims reductions because Mosaic cannot relayout the
+    1-D [B] result of an argmax into the column the latches live in."""
+    m = jnp.max(x, axis=-1, keepdims=True)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    first = jnp.where((x == m) | (x != x), lane, x.shape[-1])
+    return jnp.min(first, axis=-1, keepdims=True)
+
+
+def _latch(emit, new, old):
+    """Per-row commit: rows of the [B, 1] bool column ``emit`` take
+    ``new``, the rest keep ``old``."""
+    return [jnp.where(emit, n, o) for o, n in zip(old, new)]
+
+
+def _model_step(tok, hs, cs, emb_ref, layer_refs, head_ref, hb_ref, *,
+                vocab: int, ldtype):
+    """One decode step of one model inside a kernel (the decode window's
+    per-step body; the spec kernel runs it for the target AND the draft).
+    ``tok`` is a [B, 1] int32 column. Returns ``(logits_f32, new_hs,
+    new_cs)`` (uncommitted — the caller latches)."""
+    B = tok.shape[0]
+    # embedding gather as a one-hot MXU matmul (exact: 1.0 * row + zeros
+    # — bit-identical to jnp.take's row copy; PAD's one-hot is all-zero)
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, (B, vocab), 1)
+              == tok).astype(jnp.float32)
+    x = jnp.dot(onehot, emb_ref[:].astype(jnp.float32),
+                preferred_element_type=jnp.float32)
+    if emb_ref.dtype != jnp.float32:
+        # mirror decode_one: jnp.take yields the embedding's dtype, and
+        # lstm_step casts x to the kernel dtype from THERE — narrow back
+        # so the downstream cast chain is identical
+        x = x.astype(emb_ref.dtype)
+    new_hs, new_cs = [], []
+    for l, (w_ref, u_ref, b_ref) in enumerate(layer_refs):
+        # ops/lstm_cell.lstm_step on fused kernels, op for op
+        dtype = w_ref.dtype
+        z = jnp.dot(x.astype(dtype), w_ref[:],
+                    preferred_element_type=jnp.float32)
+        z = z + jnp.dot(hs[l].astype(dtype), u_ref[:],
+                        preferred_element_type=jnp.float32)
+        z = z + b_ref[0]
+        i, f, g, o = jnp.split(z, 4, axis=-1)
+        i = jax.nn.sigmoid(i)
+        f = jax.nn.sigmoid(f)
+        g = jnp.tanh(g)
+        o = jax.nn.sigmoid(o)
+        c_new = f * cs[l] + i * g
+        h_new = o * jnp.tanh(c_new)
+        new_hs.append(h_new)
+        new_cs.append(c_new)
+        x = h_new
+    # head (models/generate.decode_one): same dtype chain — near-tied
+    # logits must argmax identically
+    logits = (
+        jnp.dot(x.astype(head_ref.dtype), head_ref[:],
+                preferred_element_type=ldtype)
+        + hb_ref[0].astype(ldtype)
+    ).astype(jnp.float32)
+    return logits, new_hs, new_cs
+
+
+def _take_model_refs(refs, idx: int, num_layers: int):
+    """``(emb_ref, layer_refs, head_ref, head_bias_ref), next_idx`` from
+    the flat operand list both kernels receive."""
+    emb_ref = refs[idx]
+    idx += 1
+    layer_refs = [tuple(refs[idx + 3 * l: idx + 3 * l + 3])
+                  for l in range(num_layers)]
+    idx += 3 * num_layers
+    return (emb_ref, layer_refs, refs[idx], refs[idx + 1]), idx + 2
+
+
+def _decode_window_kernel(*refs, num_layers: int, vocab: int,
                           window: int, temperature: float, greedy: bool,
-                          sampled: bool, ldtype):
+                          ldtype):
     """One fused decode window. Carries, latches and the token block all
     live in VMEM for the K python-unrolled steps; the latch algebra is
     the scan window's, verbatim (serve/engine.py `window_fn.step`):
@@ -116,110 +191,90 @@ def _decode_window_kernel(*refs, num_layers: int, hidden: int, vocab: int,
     - rows alive at step entry emit this step's token and commit its
       carry update (the EOS-emitting step still writes carries);
     - dead rows emit PAD_TOKEN, keep frozen carries, and feed token 0
-      forward (the value never matters — but a PAD embedding one-hot
-      would be all-zeros, which is equally harmless and exactly what
-      the comparison produces for -1).
+      forward (the value never matters).
+
+    Every per-row value (token, latches, budget) is a [B, 1] column from
+    its ref to its ref: rows on sublanes, like the [B, H] carries they
+    gate. Mosaic cannot reshape a 1-D [B] mask into that column.
     """
     L = num_layers
-    H = hidden
-    idx = 0
-    emb_ref = refs[idx]; idx += 1
-    layer_refs = []
-    for _ in range(L):
-        layer_refs.append((refs[idx], refs[idx + 1], refs[idx + 2]))
-        idx += 3
-    head_ref = refs[idx]; idx += 1
-    hb_ref = refs[idx]; idx += 1
-    h0_ref = refs[idx]; idx += 1
-    c0_ref = refs[idx]; idx += 1
-    tok_ref = refs[idx]; idx += 1
-    alive_ref = refs[idx]; idx += 1
-    rem_ref = refs[idx]; idx += 1
-    eos_ref = refs[idx]; idx += 1
+    model, idx = _take_model_refs(refs, 0, L)
+    h0_ref, c0_ref, tok_ref, alive_ref, rem_ref, eos_ref = refs[idx:idx + 6]
+    idx += 6
     noise_ref = None
-    if sampled:
-        noise_ref = refs[idx]; idx += 1
+    if not greedy:
+        noise_ref = refs[idx]
+        idx += 1
     (toks_ref, next_ref, alive_out_ref, rem_out_ref,
      h_out_ref, c_out_ref) = refs[idx:idx + 6]
 
-    tok = tok_ref[0]                  # [B] int32
-    alive = alive_ref[0] != 0         # [B] bool
-    rem = rem_ref[0]                  # [B] int32
-    eos = eos_ref[0]                  # [B] int32 (-1 = none)
+    tok = tok_ref[...]                # [B, 1] int32
+    alive = alive_ref[...] != 0       # [B, 1] bool
+    rem = rem_ref[...]                # [B, 1] int32
+    eos = eos_ref[...]                # [B, 1] int32 (-1 = none)
     B = tok.shape[0]
     hs = [h0_ref[l] for l in range(L)]
     cs = [c0_ref[l] for l in range(L)]
+    step_lane = jax.lax.broadcasted_iota(jnp.int32, (B, window), 1)
+    toks = jnp.full((B, window), PAD_TOKEN, jnp.int32)
 
     for k in range(window):
-        # embedding gather as a one-hot MXU matmul (exact: 1.0 * row +
-        # zeros — bit-identical to jnp.take's row copy)
-        onehot = (jax.lax.broadcasted_iota(jnp.int32, (B, vocab), 1)
-                  == tok[:, None]).astype(jnp.float32)
-        x = jnp.dot(onehot, emb_ref[:].astype(jnp.float32),
-                    preferred_element_type=jnp.float32)
-        if emb_ref.dtype != jnp.float32:
-            # mirror decode_one: jnp.take yields the embedding's dtype,
-            # and lstm_step casts x to the kernel dtype from THERE —
-            # narrow back so the downstream cast chain is identical
-            x = x.astype(emb_ref.dtype)
-        new_hs, new_cs = [], []
-        for l, (w_ref, u_ref, b_ref) in enumerate(layer_refs):
-            # ops/lstm_cell.lstm_step on fused kernels, op for op
-            dtype = w_ref.dtype
-            z = jnp.dot(x.astype(dtype), w_ref[:],
-                        preferred_element_type=jnp.float32)
-            z = z + jnp.dot(hs[l].astype(dtype), u_ref[:],
-                            preferred_element_type=jnp.float32)
-            z = z + b_ref[0]
-            i, f, g, o = jnp.split(z, 4, axis=-1)
-            i = jax.nn.sigmoid(i)
-            f = jax.nn.sigmoid(f)
-            g = jnp.tanh(g)
-            o = jax.nn.sigmoid(o)
-            c_new = f * cs[l] + i * g
-            h_new = o * jnp.tanh(c_new)
-            new_hs.append(h_new)
-            new_cs.append(c_new)
-            x = h_new
-        # head + sampler (models/generate.decode_one + sample_logits):
-        # same dtype chain — near-tied logits must argmax identically
-        logits = (
-            jnp.dot(x.astype(head_ref.dtype), head_ref[:],
-                    preferred_element_type=ldtype)
-            + hb_ref[0].astype(ldtype)
-        ).astype(jnp.float32)
-        if greedy:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
+        logits, new_hs, new_cs = _model_step(
+            tok, hs, cs, *model, vocab=vocab, ldtype=ldtype)
+        if not greedy:
             if temperature != 1.0:
                 logits = logits / max(temperature, 1e-6)
             # Gumbel-argmax == jax.random.categorical (float addition is
             # commutative bit-exactly; the wrapper drew noise with the
             # scan path's exact split chain)
-            nxt = jnp.argmax(logits + noise_ref[k], axis=-1).astype(jnp.int32)
+            logits = logits + noise_ref[k]
+        nxt = _argmax_col(logits)
         # the scan window's latch algebra, verbatim
         emit = alive
-        out_tok = jnp.where(emit, nxt, PAD_TOKEN).astype(jnp.int32)
+        out_tok = jnp.where(emit, nxt, PAD_TOKEN)
         new_rem = rem - emit.astype(rem.dtype)
         hit_eos = emit & (eos >= 0) & (nxt == eos)
         new_alive = emit & ~hit_eos & (new_rem > 0)
-        hs = [jnp.where(emit[:, None], hn, ho)
-              for ho, hn in zip(hs, new_hs)]
-        cs = [jnp.where(emit[:, None], cn, co)
-              for co, cn in zip(cs, new_cs)]
-        tok = jnp.where(new_alive, nxt, 0).astype(jnp.int32)
+        hs = _latch(emit, new_hs, hs)
+        cs = _latch(emit, new_cs, cs)
+        tok = jnp.where(new_alive, nxt, 0)
         alive = new_alive
         rem = new_rem
-        toks_ref[k] = out_tok
+        toks = jnp.where(step_lane == k, out_tok, toks)
 
     # the per-row summary the scheduler tick reads (one tiny readback
     # per window instead of Python bookkeeping per row)
-    next_ref[0] = tok
-    alive_out_ref[0] = alive.astype(jnp.int32)
-    rem_out_ref[0] = rem
+    toks_ref[...] = toks
+    next_ref[...] = tok
+    alive_out_ref[...] = alive.astype(jnp.int32)
+    rem_out_ref[...] = rem
     for l in range(L):
         h_out_ref[l] = hs[l].astype(jnp.float32)
         c_out_ref[l] = cs[l].astype(jnp.float32)
+
+
+def _model_operands(params, fused_layers, cfg):
+    """One model's weights in `_take_model_refs` order."""
+    V, E = cfg.vocab_size, cfg.embed
+    # E is only consulted by plan_fits; checked here so a config whose
+    # layer-0 width disagrees with the embedding table fails loudly at
+    # trace time instead of producing shape errors inside the kernel
+    if params["embedding"].shape != (V, E):
+        raise ValueError(
+            f"embedding table {params['embedding'].shape} != {(V, E)}")
+    head = params["head"]
+    head_kernel = (params["embedding"].T if cfg.tie_embeddings
+                   else head["kernel"])
+    operands = [params["embedding"]]
+    for fused in fused_layers:
+        operands += [fused.kernel, fused.recurrent,
+                     fused.bias.reshape(1, -1)]
+    return operands + [head_kernel, head["bias"].reshape(1, -1)]
+
+
+def _col(x):
+    """A per-row [B] vector as the [B, 1] int32 column the kernels use."""
+    return x.reshape(-1, 1).astype(jnp.int32)
 
 
 def decode_window_call(params, fused_layers, cfg, h_in, c_in, tokens,
@@ -231,65 +286,38 @@ def decode_window_call(params, fused_layers, cfg, h_in, c_in, tokens,
 
     ``h_in``/``c_in`` [L, B, H] f32; ``tokens``/``remaining``/``eos_ids``
     [B] int32; ``alive`` [B] bool; ``noise`` [K, B, V] f32 gumbel draws
-    (None when greedy). Returns ``(h_out, c_out, toks [K, B] int32,
+    (None when greedy). Returns ``(h_out, c_out, toks [B, K] int32,
     next_tok [B] int32, alive_out [B] bool, rem_out [B] int32)`` — the
     exact shapes/dtypes the scan window produces, so the two kernels are
     interchangeable behind one `DecodeWindow`."""
     L, B, H = h_in.shape
-    V = cfg.vocab_size
-    E = cfg.embed
-    sampled = not greedy
-    head = params["head"]
-    head_kernel = (params["embedding"].T if cfg.tie_embeddings
-                   else head["kernel"])
-
-    operands = [params["embedding"]]
-    in_specs = [pl.BlockSpec(memory_space=pltpu.VMEM)]
-    for fused in fused_layers:
-        operands += [fused.kernel, fused.recurrent,
-                     fused.bias.reshape(1, -1)]
-        in_specs += [pl.BlockSpec(memory_space=pltpu.VMEM)] * 3
-    operands += [
-        head_kernel, head["bias"].reshape(1, -1),
-        h_in, c_in,
-        tokens.reshape(1, -1).astype(jnp.int32),
-        alive.reshape(1, -1).astype(jnp.int32),
-        remaining.reshape(1, -1).astype(jnp.int32),
-        eos_ids.reshape(1, -1).astype(jnp.int32),
-    ]
-    in_specs += [pl.BlockSpec(memory_space=pltpu.VMEM)] * 8
-    if sampled:
+    operands = _model_operands(params, fused_layers, cfg) + [
+        h_in, c_in, _col(tokens), _col(alive), _col(remaining),
+        _col(eos_ids)]
+    if not greedy:
         operands.append(noise)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.VMEM))
-
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    col = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+    carry = jax.ShapeDtypeStruct((L, B, H), jnp.float32)
     out_shape = [
-        jax.ShapeDtypeStruct((window, B), jnp.int32),   # token block
-        jax.ShapeDtypeStruct((1, B), jnp.int32),        # next token
-        jax.ShapeDtypeStruct((1, B), jnp.int32),        # alive summary
-        jax.ShapeDtypeStruct((1, B), jnp.int32),        # remaining summary
-        jax.ShapeDtypeStruct((L, B, H), jnp.float32),   # h out
-        jax.ShapeDtypeStruct((L, B, H), jnp.float32),   # c out
+        jax.ShapeDtypeStruct((B, window), jnp.int32),   # token block
+        col, col, col,          # next token, alive summary, remaining
+        carry, carry,           # h out, c out
     ]
-    out_specs = [pl.BlockSpec(memory_space=pltpu.VMEM)] * 6
-
     toks, next_tok, alive_out, rem_out, h_out, c_out = pl.pallas_call(
         functools.partial(
-            _decode_window_kernel, num_layers=L, hidden=H, vocab=V,
+            _decode_window_kernel, num_layers=L, vocab=cfg.vocab_size,
             window=window, temperature=temperature, greedy=greedy,
-            sampled=sampled, ldtype=cfg.ldtype,
+            ldtype=cfg.ldtype,
         ),
-        in_specs=in_specs,
-        out_specs=out_specs,
+        in_specs=[vmem] * len(operands),
+        out_specs=[vmem] * len(out_shape),
         out_shape=out_shape,
         interpret=interpret,
+        name="decode_window",
     )(*operands)
-    # E is only consulted by plan_fits; asserted here so a config whose
-    # layer-0 width disagrees with the embedding table fails loudly at
-    # trace time instead of producing shape errors inside the kernel
-    assert params["embedding"].shape == (V, E), (params["embedding"].shape,
-                                                 (V, E))
-    return (h_out, c_out, toks, next_tok[0],
-            alive_out[0].astype(bool), rem_out[0])
+    return (h_out, c_out, toks, next_tok[:, 0],
+            alive_out[:, 0].astype(bool), rem_out[:, 0])
 
 
 # ---- speculative verify window (draft + target, fused) -----------------
@@ -325,48 +353,8 @@ def spec_plan_fits(batch_b: int, k_draft: int, num_layers: int,
         pbytes=pbytes) <= _VMEM_BUDGET
 
 
-def _model_step(tok, hs, cs, emb_ref, layer_refs, head_ref, hb_ref, *,
-                vocab: int, ldtype):
-    """One greedy decode step of one model inside the kernel — the
-    `_decode_window_kernel` per-step body, factored so the spec kernel
-    runs it for the target AND the draft. Returns ``(logits_f32,
-    new_hs, new_cs)`` (uncommitted — the caller latches)."""
-    B = tok.shape[0]
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, (B, vocab), 1)
-              == tok[:, None]).astype(jnp.float32)
-    x = jnp.dot(onehot, emb_ref[:].astype(jnp.float32),
-                preferred_element_type=jnp.float32)
-    if emb_ref.dtype != jnp.float32:
-        x = x.astype(emb_ref.dtype)
-    new_hs, new_cs = [], []
-    for l, (w_ref, u_ref, b_ref) in enumerate(layer_refs):
-        dtype = w_ref.dtype
-        z = jnp.dot(x.astype(dtype), w_ref[:],
-                    preferred_element_type=jnp.float32)
-        z = z + jnp.dot(hs[l].astype(dtype), u_ref[:],
-                        preferred_element_type=jnp.float32)
-        z = z + b_ref[0]
-        i, f, g, o = jnp.split(z, 4, axis=-1)
-        i = jax.nn.sigmoid(i)
-        f = jax.nn.sigmoid(f)
-        g = jnp.tanh(g)
-        o = jax.nn.sigmoid(o)
-        c_new = f * cs[l] + i * g
-        h_new = o * jnp.tanh(c_new)
-        new_hs.append(h_new)
-        new_cs.append(c_new)
-        x = h_new
-    logits = (
-        jnp.dot(x.astype(head_ref.dtype), head_ref[:],
-                preferred_element_type=ldtype)
-        + hb_ref[0].astype(ldtype)
-    ).astype(jnp.float32)
-    return logits, new_hs, new_cs
-
-
-def _spec_window_kernel(*refs, num_layers: int, hidden: int,
-                        draft_layers: int, draft_hidden: int, vocab: int,
-                        k_draft: int, ldtype, dldtype):
+def _spec_window_kernel(*refs, num_layers: int, draft_layers: int,
+                        vocab: int, k_draft: int, ldtype, dldtype):
     """The fused speculative step, greedy-only. Phase 1: the draft
     decodes ``k_draft`` proposals from its VMEM-resident carries (the
     propose-time carries are discarded). Phase 2: ``W = k_draft + 1``
@@ -377,38 +365,22 @@ def _spec_window_kernel(*refs, num_layers: int, hidden: int,
     the plain greedy sequence by construction, and the disagreement-
     detecting step emits the target's own argmax as the correction
     token. The returned ``alive`` is the SESSION latch (EOS/budget) —
-    a draft miss ends the window, never the conversation."""
+    a draft miss ends the window, never the conversation. Per-row
+    values are [B, 1] columns, as in `_decode_window_kernel`."""
     L, Ld = num_layers, draft_layers
-    idx = 0
-    emb_ref = refs[idx]; idx += 1
-    layer_refs = []
-    for _ in range(L):
-        layer_refs.append((refs[idx], refs[idx + 1], refs[idx + 2]))
-        idx += 3
-    head_ref = refs[idx]; idx += 1
-    hb_ref = refs[idx]; idx += 1
-    demb_ref = refs[idx]; idx += 1
-    dlayer_refs = []
-    for _ in range(Ld):
-        dlayer_refs.append((refs[idx], refs[idx + 1], refs[idx + 2]))
-        idx += 3
-    dhead_ref = refs[idx]; idx += 1
-    dhb_ref = refs[idx]; idx += 1
-    h0_ref = refs[idx]; idx += 1
-    c0_ref = refs[idx]; idx += 1
-    dh0_ref = refs[idx]; idx += 1
-    dc0_ref = refs[idx]; idx += 1
-    tok_ref = refs[idx]; idx += 1
-    alive_ref = refs[idx]; idx += 1
-    rem_ref = refs[idx]; idx += 1
-    eos_ref = refs[idx]; idx += 1
+    target, idx = _take_model_refs(refs, 0, L)
+    draft, idx = _take_model_refs(refs, idx, Ld)
+    (h0_ref, c0_ref, dh0_ref, dc0_ref,
+     tok_ref, alive_ref, rem_ref, eos_ref) = refs[idx:idx + 8]
     (toks_ref, next_ref, alive_out_ref, rem_out_ref,
-     h_out_ref, c_out_ref, dh_out_ref, dc_out_ref) = refs[idx:idx + 8]
+     h_out_ref, c_out_ref, dh_out_ref, dc_out_ref) = refs[idx + 8:idx + 16]
 
-    tok = tok_ref[0]                  # [B] int32
-    alive = alive_ref[0] != 0         # [B] bool — window latch, step 0
-    rem = rem_ref[0]                  # [B] int32
-    eos = eos_ref[0]                  # [B] int32 (-1 = none)
+    tok = tok_ref[...]                # [B, 1] int32
+    alive = alive_ref[...] != 0       # [B, 1] bool — window latch, step 0
+    rem = rem_ref[...]                # [B, 1] int32
+    eos = eos_ref[...]                # [B, 1] int32 (-1 = none)
+    B = tok.shape[0]
+    W = k_draft + 1
     hs = [h0_ref[l] for l in range(L)]
     cs = [c0_ref[l] for l in range(L)]
     dhs0 = [dh0_ref[l] for l in range(Ld)]
@@ -422,30 +394,30 @@ def _spec_window_kernel(*refs, num_layers: int, hidden: int,
     ptok = tok
     for _ in range(k_draft):
         dlogits, dhs, dcs = _model_step(
-            ptok, dhs, dcs, demb_ref, dlayer_refs, dhead_ref, dhb_ref,
-            vocab=vocab, ldtype=dldtype)
-        ptok = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
+            ptok, dhs, dcs, *draft, vocab=vocab, ldtype=dldtype)
+        ptok = _argmax_col(dlogits)
         props.append(ptok)
 
     # phase 2 — W joint teacher-forced verify steps
     dhs, dcs = list(dhs0), list(dcs0)
     sess_alive = alive
     final_tok = tok
-    for i in range(k_draft + 1):
+    step_lane = jax.lax.broadcasted_iota(jnp.int32, (B, W), 1)
+    toks = jnp.full((B, W), PAD_TOKEN, jnp.int32)
+    for i in range(W):
         inp = tok if i == 0 else props[i - 1]
         logits, new_hs, new_cs = _model_step(
-            inp, hs, cs, emb_ref, layer_refs, head_ref, hb_ref,
-            vocab=vocab, ldtype=ldtype)
+            inp, hs, cs, *target, vocab=vocab, ldtype=ldtype)
         _, new_dhs, new_dcs = _model_step(
-            inp, dhs, dcs, demb_ref, dlayer_refs, dhead_ref, dhb_ref,
-            vocab=vocab, ldtype=dldtype)
-        t = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            inp, dhs, dcs, *draft, vocab=vocab, ldtype=dldtype)
+        t = _argmax_col(logits)
         emit = alive
-        out_tok = jnp.where(emit, t, PAD_TOKEN).astype(jnp.int32)
+        out_tok = jnp.where(emit, t, PAD_TOKEN)
         new_rem = rem - emit.astype(rem.dtype)
         hit_eos = emit & (eos >= 0) & (t == eos)
         live_on = ~hit_eos & (new_rem > 0)
-        sess_alive = jnp.where(emit, live_on, sess_alive)
+        # (a select between two masks is one Mosaic cannot lower)
+        sess_alive = (emit & live_on) | (~emit & sess_alive)
         if i < k_draft:
             agree = props[i] == t
             alive = emit & live_on & agree
@@ -453,21 +425,18 @@ def _spec_window_kernel(*refs, num_layers: int, hidden: int,
             # past the last proposal nothing can agree — the window
             # always closes here (the scan fn's -2 sentinel)
             alive = jnp.zeros_like(emit)
-        hs = [jnp.where(emit[:, None], hn, ho)
-              for ho, hn in zip(hs, new_hs)]
-        cs = [jnp.where(emit[:, None], cn, co)
-              for co, cn in zip(cs, new_cs)]
-        dhs = [jnp.where(emit[:, None], hn, ho)
-               for ho, hn in zip(dhs, new_dhs)]
-        dcs = [jnp.where(emit[:, None], cn, co)
-               for co, cn in zip(dcs, new_dcs)]
-        final_tok = jnp.where(emit, t, final_tok).astype(jnp.int32)
+        hs = _latch(emit, new_hs, hs)
+        cs = _latch(emit, new_cs, cs)
+        dhs = _latch(emit, new_dhs, dhs)
+        dcs = _latch(emit, new_dcs, dcs)
+        final_tok = jnp.where(emit, t, final_tok)
         rem = new_rem
-        toks_ref[i] = out_tok
+        toks = jnp.where(step_lane == i, out_tok, toks)
 
-    next_ref[0] = jnp.where(sess_alive, final_tok, 0).astype(jnp.int32)
-    alive_out_ref[0] = sess_alive.astype(jnp.int32)
-    rem_out_ref[0] = rem
+    toks_ref[...] = toks
+    next_ref[...] = jnp.where(sess_alive, final_tok, 0)
+    alive_out_ref[...] = sess_alive.astype(jnp.int32)
+    rem_out_ref[...] = rem
     for l in range(L):
         h_out_ref[l] = hs[l].astype(jnp.float32)
         c_out_ref[l] = cs[l].astype(jnp.float32)
@@ -483,63 +452,38 @@ def spec_window_call(params, fused_layers, cfg, dparams, dfused_layers,
     engine's jitted wrapper). ``h_in``/``c_in`` [L, B, H] f32 target
     carries, ``dh_in``/``dc_in`` [L_d, B, H_d] f32 draft carries; row
     vectors as in `decode_window_call`. Returns ``(h_out, c_out,
-    dh_out, dc_out, toks [W, B] int32, next_tok [B] int32, alive_out
+    dh_out, dc_out, toks [B, W] int32, next_tok [B] int32, alive_out
     [B] bool, rem_out [B] int32)`` — the scan spec fn's exact shapes,
     so the two programs are interchangeable behind one spec
     `DecodeWindow`."""
     L, B, H = h_in.shape
     Ld, _, Hd = dh_in.shape
-    V = cfg.vocab_size
     W = k_draft + 1
-    head = params["head"]
-    head_kernel = (params["embedding"].T if cfg.tie_embeddings
-                   else head["kernel"])
-    dhead = dparams["head"]
-    dhead_kernel = (dparams["embedding"].T if dcfg.tie_embeddings
-                    else dhead["kernel"])
-
-    operands = [params["embedding"]]
-    for fused in fused_layers:
-        operands += [fused.kernel, fused.recurrent,
-                     fused.bias.reshape(1, -1)]
-    operands += [head_kernel, head["bias"].reshape(1, -1)]
-    operands.append(dparams["embedding"])
-    for fused in dfused_layers:
-        operands += [fused.kernel, fused.recurrent,
-                     fused.bias.reshape(1, -1)]
-    operands += [
-        dhead_kernel, dhead["bias"].reshape(1, -1),
-        h_in, c_in, dh_in, dc_in,
-        tokens.reshape(1, -1).astype(jnp.int32),
-        alive.reshape(1, -1).astype(jnp.int32),
-        remaining.reshape(1, -1).astype(jnp.int32),
-        eos_ids.reshape(1, -1).astype(jnp.int32),
-    ]
-    in_specs = [pl.BlockSpec(memory_space=pltpu.VMEM)] * len(operands)
-
+    operands = (_model_operands(params, fused_layers, cfg)
+                + _model_operands(dparams, dfused_layers, dcfg)
+                + [h_in, c_in, dh_in, dc_in, _col(tokens), _col(alive),
+                   _col(remaining), _col(eos_ids)])
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    col = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+    carry = jax.ShapeDtypeStruct((L, B, H), jnp.float32)
+    dcarry = jax.ShapeDtypeStruct((Ld, B, Hd), jnp.float32)
     out_shape = [
-        jax.ShapeDtypeStruct((W, B), jnp.int32),        # token block
-        jax.ShapeDtypeStruct((1, B), jnp.int32),        # next token
-        jax.ShapeDtypeStruct((1, B), jnp.int32),        # session alive
-        jax.ShapeDtypeStruct((1, B), jnp.int32),        # remaining
-        jax.ShapeDtypeStruct((L, B, H), jnp.float32),   # target h out
-        jax.ShapeDtypeStruct((L, B, H), jnp.float32),   # target c out
-        jax.ShapeDtypeStruct((Ld, B, Hd), jnp.float32),  # draft h out
-        jax.ShapeDtypeStruct((Ld, B, Hd), jnp.float32),  # draft c out
+        jax.ShapeDtypeStruct((B, W), jnp.int32),        # token block
+        col, col, col,          # next token, session alive, remaining
+        carry, carry, dcarry, dcarry,
     ]
-    out_specs = [pl.BlockSpec(memory_space=pltpu.VMEM)] * 8
-
     (toks, next_tok, alive_out, rem_out,
      h_out, c_out, dh_out, dc_out) = pl.pallas_call(
         functools.partial(
-            _spec_window_kernel, num_layers=L, hidden=H,
-            draft_layers=Ld, draft_hidden=Hd, vocab=V, k_draft=k_draft,
+            _spec_window_kernel, num_layers=L, draft_layers=Ld,
+            vocab=cfg.vocab_size, k_draft=k_draft,
             ldtype=cfg.ldtype, dldtype=dcfg.ldtype,
         ),
-        in_specs=in_specs,
-        out_specs=out_specs,
+        in_specs=[vmem] * len(operands),
+        out_specs=[vmem] * len(out_shape),
         out_shape=out_shape,
         interpret=interpret,
+        name="spec_window",
     )(*operands)
-    return (h_out, c_out, dh_out, dc_out, toks, next_tok[0],
-            alive_out[0].astype(bool), rem_out[0])
+    return (h_out, c_out, dh_out, dc_out, toks, next_tok[:, 0],
+            alive_out[:, 0].astype(bool), rem_out[:, 0])

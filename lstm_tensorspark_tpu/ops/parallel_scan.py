@@ -2,9 +2,8 @@
 
 The training bottleneck for long sequences is not the matmuls — it is the
 T-deep sequential dependency chain that `lax.scan` (ops/scan.py) and its
-reverse-mode transpose walk step by step (BENCH_TABLE.json: the T=400
-rows sit at ~20-25% MFU; the roofline section shows the chain latency,
-not FLOPs, as the binding constraint). *BPPSA: Scaling Back-propagation
+reverse-mode transpose walk step by step (at T=400 the chain latency,
+not FLOPs, is the binding constraint). *BPPSA: Scaling Back-propagation
 by Parallel Scan Algorithm* (PAPERS.md) observes that even though the
 forward cell is nonlinear, **backprop through a recurrence is a linear
 chain of per-step Jacobian operators**:

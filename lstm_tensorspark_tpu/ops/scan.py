@@ -31,6 +31,8 @@ T raises instead (same error from `parallel_scan.assoc_lstm_scan`).
 
 from __future__ import annotations
 
+import os
+import sys
 from typing import Sequence
 
 import jax
@@ -154,6 +156,83 @@ def lstm_scan(
     return final, jnp.moveaxis(ys, 0, 1)
 
 
+def recurrence_path(
+    batch: int,
+    seq_len: int,
+    d_in: int,
+    hidden: int,
+    *,
+    use_pallas: bool,
+    compute_dtype=None,
+    has_mask: bool = False,
+    remat_chunk: int | None = None,
+    bptt: str = "sequential",
+    bidir: bool = False,
+) -> tuple[str, str]:
+    """THE dispatch decision of `auto_lstm_scan` / `bidir_lstm_scan` for
+    one layer of these shapes, as ``(path, note)``: ``path`` is
+    ``"bilstm"`` (stacked-direction kernel), ``"pallas"`` (fused
+    single-direction kernels) or ``"scan"`` (`lax.scan`); ``note`` says
+    which kernel strategies, or why not — the line the CLI's ``start``
+    record carries, so nobody has to guess which recurrence ran. A pure
+    function of shapes, flags and `jax.default_backend()`."""
+    if not use_pallas:
+        return "scan", "lax.scan (--use-pallas not given)"
+    if bptt == "assoc":
+        return "scan", "lax.scan (--bptt-mode assoc overrides --use-pallas)"
+    from . import pallas_lstm as pk
+
+    pbytes = 2 if compute_dtype == jnp.bfloat16 else 4
+    shape = f"B={batch} T={seq_len} H={hidden} {pbytes} bytes/param"
+    if (bidir and remat_chunk is None
+            and os.environ.get("LSTM_TSP_NO_BIDIR_FUSE") != "1"):
+        from .pallas_bilstm import bilstm_supported
+
+        if bilstm_supported(batch, hidden, d_in, seq_len,
+                            param_dtype_bytes=pbytes, has_mask=has_mask):
+            return "bilstm", f"pallas stacked bi-LSTM residentx ({shape})"
+    if not pk.supported(batch, hidden, param_dtype_bytes=pbytes,
+                        has_mask=has_mask):
+        platform = jax.default_backend()
+        if platform != "tpu":
+            why = f"the kernels are TPU programs and this is {platform}"
+        elif batch % 8:
+            why = "the per-device batch is not a multiple of 8"
+        else:
+            why = "no kernel strategy fits VMEM"
+        return "scan", f"lax.scan ({why}; {shape})"
+    hp = pk._pad_to_lane(hidden)
+    dp = pk._pad_to_lane(d_in) if seq_len >= pk._FUSEDX_MIN_T else None
+    bwd = pk.chosen_bwd_strategy(batch, seq_len, hp, pbytes,
+                                 has_mask=has_mask, Dp=dp,
+                                 remat_chunk=remat_chunk)
+    fused_bwd = bwd != "recompute"
+    fwd = pk._plan_fwd(
+        batch, hp, pbytes, save_residuals=fused_bwd, has_mask=has_mask,
+        Dp=dp if bwd == "residentx" or not fused_bwd else None)
+    if not fused_bwd:
+        bwd = ("recompute lax.scan (--remat-chunk)" if remat_chunk
+               else "recompute lax.scan (no fused backward fits)")
+    return "pallas", f"pallas fwd={fwd[0]} bwd={bwd} ({shape})"
+
+
+#: every distinct note a trace has taken, in order (insertion-ordered set)
+_TRACED_PATHS: dict[str, None] = {}
+
+
+def _note_traced(note: str) -> None:
+    """Log a recurrence path the first time a trace takes it."""
+    if note not in _TRACED_PATHS:
+        _TRACED_PATHS[note] = None
+        print(f"recurrence: {note}", file=sys.stderr, flush=True)
+
+
+def traced_paths() -> list[str]:
+    """The recurrence paths traced so far in this process — what RAN, as
+    opposed to `recurrence_path`'s prediction for the ``start`` record."""
+    return list(_TRACED_PATHS)
+
+
 def auto_lstm_scan(
     params: LSTMParams,
     xs: jax.Array,
@@ -190,11 +269,15 @@ def auto_lstm_scan(
             unroll=unroll, bptt=bptt,
         )
     if use_pallas:
-        from .pallas_lstm import pallas_lstm_scan, supported
+        B, T, D = xs.shape
+        path, note = recurrence_path(
+            B, T, D, params.hidden_size, use_pallas=True,
+            compute_dtype=compute_dtype, has_mask=mask is not None,
+            remat_chunk=remat_chunk, bptt=bptt)
+        _note_traced(note)
+        if path == "pallas":
+            from .pallas_lstm import pallas_lstm_scan
 
-        pbytes = 2 if compute_dtype == jnp.bfloat16 else 4
-        if supported(xs.shape[0], params.hidden_size,
-                     param_dtype_bytes=pbytes, has_mask=mask is not None):
             return pallas_lstm_scan(
                 params, xs, carry, mask=mask, reverse=reverse,
                 compute_dtype=compute_dtype, remat_chunk=remat_chunk,
@@ -232,20 +315,16 @@ def bidir_lstm_scan(
 
     Returns ``(((hT_f, cT_f), ys_f), ((hT_b, cT_b), ys_b))``.
     """
-    import os
+    B, T, D = xs.shape
+    if use_pallas and params_fwd.hidden_size == params_bwd.hidden_size:
+        path, note = recurrence_path(
+            B, T, D, params_fwd.hidden_size, use_pallas=True,
+            compute_dtype=compute_dtype, has_mask=mask is not None,
+            remat_chunk=remat_chunk, bptt=bptt, bidir=True)
+        if path == "bilstm":
+            from .pallas_bilstm import pallas_bilstm_scan
 
-    # explicit assoc wins over the stacked-direction fused forward, same
-    # precedence as auto_lstm_scan (auto defers to the kernels)
-    if (use_pallas and remat_chunk is None and bptt != "assoc"
-            and os.environ.get("LSTM_TSP_NO_BIDIR_FUSE") != "1"):
-        from .pallas_bilstm import bilstm_supported, pallas_bilstm_scan
-
-        pbytes = 2 if compute_dtype == jnp.bfloat16 else 4
-        B, T, D = xs.shape
-        if (params_fwd.hidden_size == params_bwd.hidden_size
-                and bilstm_supported(B, params_fwd.hidden_size, D, T,
-                                     param_dtype_bytes=pbytes,
-                                     has_mask=mask is not None)):
+            _note_traced(note)
             return pallas_bilstm_scan(
                 params_fwd, params_bwd, xs, mask=mask,
                 compute_dtype=compute_dtype,

@@ -26,10 +26,7 @@ import jax
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 # TrainState plus re-exports from train.loop (their dependency-free
 # home): the per-shard rng fold-in and the pmean gradient reduction

@@ -53,10 +53,7 @@ from jax import lax
 from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.4.35
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..train.loop import TrainState, dp_rng_transform
 

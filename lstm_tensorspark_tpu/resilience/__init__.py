@@ -4,10 +4,8 @@ Two halves, deliberately dependency-light (no jax at import time — the
 supervisor and shell tooling import from here without paying backend init):
 
 - :mod:`.exit_codes` — the ONE table of process exit codes used by the
-  training loop, the supervisor, bench.py's liveness contract and
-  tools/chip_recovery.py. Replaces the magic numbers that used to be
-  scattered (and once collided: bench's liveness failure reused the
-  regression gate's rc=3).
+  training loop, the supervisor and the verification gates. Replaces the
+  magic numbers that used to be scattered (and once collided).
 - :mod:`.faults` — a seeded, deterministic fault-injection plane
   (``LSTM_TSP_FAULTS`` / ``--faults``) that provokes the failure modes the
   self-healing code claims to survive: process crash at step N, NaN/Inf
@@ -19,14 +17,12 @@ supervisor and shell tooling import from here without paying backend init):
 
 from .exit_codes import (  # noqa: F401
     ANOMALY_RC,
-    CHILD_FAIL_RC,
     FAULT_CRASH_RC,
     LIVENESS_RC,
     POISON_RC,
     REGRESSION_RC,
     RETRYABLE_RCS,
     USAGE_RC,
-    WEDGE_RC,
 )
 from .faults import (  # noqa: F401
     FaultPlane,

@@ -1,27 +1,21 @@
 """The process exit-code table — ONE authority for every failure class.
 
-These codes are a cross-process protocol: the training CLI, the supervisor,
-bench.py, tools/chip_recovery.py and tools/chip_watch.sh all route on them,
-so they live in a module with NO third-party imports (the supervisor and
-shell tooling must be able to read them without initialising a backend).
-
-History (why a table, not inline literals): bench.py's liveness contract
-used to exit 3 — the same code as chip_recovery.py's throughput-regression
-gate — forcing the recovery tooling to scan stdout for a marker string to
-tell a wedged chip from a real regression (ADVICE r5 finding 1). Dedicated,
+These codes are a cross-process protocol: the training CLI, the supervisor
+and the verification tooling (tools/tier1_diff.py, tools/lint) all route on
+them, so they live in a module with NO third-party imports (the supervisor
+and shell tooling must be able to read them without initialising a
+backend). A table, not inline literals: two gates once shared one literal
+and their callers had to scan stdout to tell them apart — dedicated,
 documented codes make the routing structural.
 
 | code | name            | meaning                                          | retry? |
 |------|-----------------|--------------------------------------------------|--------|
 | 2    | USAGE_RC        | argparse/flag-validation error (deterministic)   | no     |
-| 3    | REGRESSION_RC   | chip_recovery.py's throughput-regression gate    | no     |
-| 70   | CHILD_FAIL_RC   | recovery-queue child failed for a non-wedge      | no     |
-|      |                 | reason (EX_SOFTWARE)                             |        |
-| 75   | WEDGE_RC        | chip wedged / re-wedged (EX_TEMPFAIL): the       | yes    |
-|      |                 | watcher resumes probing                          |        |
-| 76   | LIVENESS_RC     | bench.py liveness contract fired (probe window   | yes    |
-|      |                 | exhausted or whole-run watchdog) — the 0-value   |        |
-|      |                 | JSON record precedes it                          |        |
+| 3    | REGRESSION_RC   | a verification gate found NEW failures           | no     |
+|      |                 | (tools/tier1_diff.py, tools/lint)                |        |
+| 76   | LIVENESS_RC     | a bounded run did not finish inside its window   | yes    |
+|      |                 | (tools/tier1_diff.py: the tier-1 suite timed     |        |
+|      |                 | out) — there is no verdict, run it again         |        |
 | 77   | ANOMALY_RC      | train loop aborted after K consecutive           | yes    |
 |      |                 | non-finite (NaN/Inf) steps: restart from         |        |
 |      |                 | checkpoint (updates were skipped, params clean)  |        |
@@ -38,11 +32,9 @@ restart-from-checkpoint to make progress.
 
 USAGE_RC = 2
 REGRESSION_RC = 3
-CHILD_FAIL_RC = 70
-WEDGE_RC = 75
 LIVENESS_RC = 76
 ANOMALY_RC = 77
 POISON_RC = 78
 FAULT_CRASH_RC = 81
 
-RETRYABLE_RCS = frozenset({WEDGE_RC, LIVENESS_RC, ANOMALY_RC, FAULT_CRASH_RC})
+RETRYABLE_RCS = frozenset({LIVENESS_RC, ANOMALY_RC, FAULT_CRASH_RC})

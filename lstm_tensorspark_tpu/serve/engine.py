@@ -257,6 +257,8 @@ class ServeEngine:
             cache_sharding = NamedSharding(self.mesh, P(None, None, "model"))
         elif mesh_devices is not None:
             raise ValueError("mesh_devices needs mesh_shards > 1")
+        else:
+            params = self._place_params(params)
         self.params = params
         self.fused_layers = fuse_layers(params, cfg)  # once, at init
         # ---- resident models -----------------------------------------
@@ -448,6 +450,14 @@ class ServeEngine:
         suffix = () if mid == self.model_id else (mid,)
         return mid, res["params"], res["fused"], suffix
 
+    def _place_params(self, params):
+        """Single-device placement: COMMITTED to this replica's device
+        (device-per-replica — an uncommitted tree on device 0 would be
+        copied across on every dispatch of a replica on device 3), or
+        at least resident on the default device (a checkpoint restore
+        hands over numpy arrays, which every dispatch would upload)."""
+        return jax.device_put(params, self.device)
+
     def has_model(self, model_id: str | None) -> bool:
         return (model_id is None
                 or model_id in self._residents)  # graftlint: disable=cross-thread-state
@@ -479,6 +489,8 @@ class ServeEngine:
         if self.mesh is not None:
             from ..parallel.tensor_parallel import place_lm_params
             params = place_lm_params(params, self.mesh)
+        else:
+            params = self._place_params(params)
         fused = fuse_layers(params, self.cfg)
         with self._lock:
             residents = dict(self._residents)
@@ -867,7 +879,6 @@ class ServeEngine:
                     greedy=sampling.greedy, interpret=interpret))
             h_cache = h_cache.at[:, slots, :].set(h_out)
             c_cache = c_cache.at[:, slots, :].set(c_out)
-            toks = jnp.moveaxis(toks, 0, 1)  # [K, B] → [B, K]
             return h_cache, c_cache, toks, next_tok, alive_out, rem_out
 
         fn = jax.jit(window_fn)
@@ -1062,7 +1073,6 @@ class ServeEngine:
             c_cache = c_cache.at[:, slots, :].set(c_out)
             dh_cache = dh_cache.at[:, slots, :].set(dh_out)
             dc_cache = dc_cache.at[:, slots, :].set(dc_out)
-            toks = jnp.moveaxis(toks, 0, 1)  # [W, B] → [B, W]
             return (h_cache, c_cache, dh_cache, dc_cache, toks, next_tok,
                     sess_alive, rem_out)
 
@@ -1606,7 +1616,21 @@ class ServeEngine:
             compiles = dict(self.compile_counts)
             fallbacks = self.decode_window_scan_fallbacks
         draft = self.draft  # graftlint: disable=cross-thread-state
+        # every result names the device it ran on — read off the arrays
+        # themselves, not off what placement was asked for
+        def ids(x):
+            return sorted(d.id for d in x.devices())
+
+        # lock-free like every resident read (wholesale-replace protocol)
+        params = self._residents[self.model_id]["params"]  # graftlint: disable=cross-thread-state
+        dev = min(self.cache.h.devices(), key=lambda d: d.id)
+        memory = dev.memory_stats() or {}  # None on the CPU
         return {
+            "device": {"platform": dev.platform, "kind": dev.device_kind,
+                       "process_devices": jax.device_count(),
+                       "cache_on": ids(self.cache.h),
+                       "params_on": ids(jax.tree.leaves(params)[0]),
+                       "peak_bytes_in_use": memory.get("peak_bytes_in_use")},
             "decode_kernel": self.decode_kernel,
             "mesh_shards": self.mesh_shards,
             "model_id": self.model_id,

@@ -13,7 +13,8 @@ import jax
 
 
 def run_classifier(args, logger) -> int:
-    from ..cli import _make_logged_loop, _setup_training
+    from ..cli import (_make_logged_loop, _mfu_logging, _setup_training,
+                       recurrence_note)
     from ..data import get_dataset, padded_batches
     from ..models.classifier import ClassifierConfig, classifier_loss, init_classifier
 
@@ -268,15 +269,18 @@ def run_classifier(args, logger) -> int:
         "max_len": max_len, "devices": jax.device_count(), "partitions": shards,
         "steps_per_epoch": steps_per_epoch,
         "backend": "dp" if mesh is not None else "single",
+        "recurrence": recurrence_note(
+            args, cfg, shards, max_len,
+            [cfg.embed] + [2 * cfg.hidden_size] * (cfg.num_layers - 1),
+            has_mask=True, bidir=True),
     })
-    from ..cli import _mfu_logging
     from ..utils.flops import classifier_fwd_flops_per_token
 
     flops_per_token, peak = _mfu_logging(
         args,
         classifier_fwd_flops_per_token(cfg.vocab_size, cfg.hidden_size,
                                        cfg.num_layers, cfg.embed),
-        mesh,
+        mesh, logger,
     )
     state = _make_logged_loop(
         args, state, train_step, stream, steps_per_epoch, logger,

@@ -13,7 +13,8 @@ import numpy as np
 
 
 def run_forecaster(args, logger) -> int:
-    from ..cli import _make_logged_loop, _setup_training
+    from ..cli import (_make_logged_loop, _mfu_logging, _setup_training,
+                       recurrence_note)
     from ..data import get_dataset
     from ..data.batching import forecast_windows
     from ..models.seq2seq import Seq2SeqConfig, forecast, init_seq2seq, seq2seq_loss
@@ -258,8 +259,10 @@ def run_forecaster(args, logger) -> int:
         "horizon": horizon, "devices": jax.device_count(), "partitions": shards,
         "steps_per_epoch": steps_per_epoch,
         "backend": "dp" if mesh is not None else "single",
+        "recurrence": recurrence_note(
+            args, cfg, shards, context_len,
+            [cfg.num_features] + [cfg.hidden_size] * (cfg.num_layers - 1)),
     })
-    from ..cli import _mfu_logging
     from ..utils.flops import seq2seq_fwd_flops_per_seq
 
     # tokens_per_batch counts context positions; spread the per-sequence
@@ -270,7 +273,7 @@ def run_forecaster(args, logger) -> int:
         seq2seq_fwd_flops_per_seq(cfg.num_features, cfg.hidden_size,
                                   cfg.num_layers, context_len,
                                   horizon) / context_len,
-        mesh,
+        mesh, logger,
     )
     state = _make_logged_loop(
         args, state, train_step, stream, steps_per_epoch, logger,

@@ -674,6 +674,26 @@ class Checkpointer:
                 self._quarantine_step(step, f"vanished mid-read "
                                             f"(peer quarantine?): {e}")
 
+    def restore_latest_params(self, params):
+        """The newest restorable checkpoint's ``params`` ALONE, into the
+        structure of ``params`` — whatever optimizer, clipping, schedule
+        or recurrent carries the producing run had. Serving and
+        distillation need the weights and must not have to re-state the
+        training flags to get them (a template built from `--optimizer`
+        alone cannot match a run that also clipped). None when nothing
+        restores (same fallback/quarantine policy as `restore_latest`).
+
+        A template field left None restores to nothing: the single-file
+        format hands back its raw sub-dict, which is dropped here; the
+        sharded format numbers leaves in `TrainState` order, where
+        ``step`` and ``params`` come first, so their numbers hold."""
+        from .loop import TrainState
+
+        state = self.restore_latest(TrainState(
+            step=np.zeros((), np.int32), params=params, opt_state=None,
+            rng=None))
+        return None if state is None else state.params
+
     def _deserialize_verified(self, template, path: str):
         """Checksum-check then deserialize one single-file checkpoint,
         classifying failures: checksum mismatch → CorruptCheckpointError
@@ -766,4 +786,7 @@ class Checkpointer:
         return v
 
     def _reshard_like(self, template, restored):
-        return jax.tree.map(self._place_leaf, template, restored)
+        # a None in the template (restore_latest_params) takes whatever
+        # the file held there, unplaced
+        return jax.tree.map(self._place_leaf, template, restored,
+                            is_leaf=lambda t: t is None)

@@ -1,6 +1,6 @@
 from .tracing import Tracer, get_tracer, set_tracer, span, instant
 from .flops import (
-    PEAK_TFLOPS,
+    PEAK_BF16_TFLOPS,
     TRAIN_FLOPS_MULTIPLIER,
     classifier_fwd_flops_per_token,
     lm_fwd_flops_per_token,
@@ -9,7 +9,7 @@ from .flops import (
 
 __all__ = [
     "Tracer", "get_tracer", "set_tracer", "span", "instant",
-    "PEAK_TFLOPS", "TRAIN_FLOPS_MULTIPLIER",
+    "PEAK_BF16_TFLOPS", "TRAIN_FLOPS_MULTIPLIER",
     "classifier_fwd_flops_per_token", "lm_fwd_flops_per_token",
     "seq2seq_fwd_flops_per_seq",
 ]

@@ -9,11 +9,13 @@ matmul).
 
 from __future__ import annotations
 
-import os
-
-# bf16 peak for MFU. TPU v5 lite (v5e): 197 TFLOP/s bf16 (public spec).
-# Override with LSTM_TSP_PEAK_TFLOPS on other chips.
-PEAK_TFLOPS = float(os.environ.get("LSTM_TSP_PEAK_TFLOPS", 197.0))
+# bf16 peak TFLOP/s of ONE chip, keyed by `jax.Device.device_kind`. A
+# device that is not here has no MFU: --log-flops says so and reports
+# model_tflops alone; a benchmark treats it as an error, never a default.
+PEAK_BF16_TFLOPS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16 per chip
+    "TPU v5 lite": 197.0,
+}
 
 # fwd + bwd(2x) matmul accounting
 TRAIN_FLOPS_MULTIPLIER = 3.0

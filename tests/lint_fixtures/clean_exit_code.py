@@ -4,7 +4,7 @@ stay legal."""
 
 import sys
 
-from lstm_tensorspark_tpu.resilience.exit_codes import ANOMALY_RC, WEDGE_RC
+from lstm_tensorspark_tpu.resilience.exit_codes import ANOMALY_RC, LIVENESS_RC
 
 
 def main():
@@ -24,8 +24,8 @@ def anomaly_abort():
     raise SystemExit(ANOMALY_RC)
 
 
-def wedge_exit():
-    sys.exit(WEDGE_RC)
+def liveness_exit():
+    sys.exit(LIVENESS_RC)
 
 
 def ok():

@@ -1,0 +1,358 @@
+"""Every `pallas_call` of the main paths, compiled by the TPU's own
+compiler for a DESCRIBED v5e (no chip attached): interpret mode accepts
+kernels Mosaic refuses (a 1-D mask reshaped into a column, an unaligned
+slice, too much VMEM), so these compiles are what stands between a
+kernel edit and a boot failure on the chip. A compile that passes here
+is NOT a chip run — it says nothing about results or times.
+
+Unmarked cases: the kernels alone at published widths (seconds each).
+``slow`` cases: the whole jitted config-5 train step and the serve
+prefill / decode-step / decode-window programs at 4x1024, V=50,000 —
+up to a minute and a half each, run by hand before a chip call:
+
+    JAX_PLATFORMS=cpu python -m pytest tests/test_chip_compile.py -m slow
+"""
+
+import contextlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from lstm_tensorspark_tpu.models.generate import fuse_layers
+from lstm_tensorspark_tpu.models.lstm_lm import LMConfig, init_lm
+from lstm_tensorspark_tpu.ops import pallas_decode
+from lstm_tensorspark_tpu.ops.lstm_cell import init_lstm_params
+from lstm_tensorspark_tpu.ops.pallas_bilstm import pallas_bilstm_scan
+from lstm_tensorspark_tpu.ops.pallas_lstm import pallas_lstm_scan
+
+HBM_BYTES = 16 * 10**9  # one v5e chip
+
+
+def _v5e_devices():
+    """The four devices of a described v5e 2x2 host (none is attached)."""
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+    except Exception as e:  # no libtpu, or it cannot describe this chip
+        pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """Sharding on one device of the described host."""
+    return SingleDeviceSharding(_v5e_devices()[0])
+
+
+@contextlib.contextmanager
+def _kernels_selectable():
+    """Steer the dispatch onto the kernels: `pallas_lstm.supported()` asks
+    `jax.default_backend()`, which is the CPU here — in the test, not
+    through an option of the program."""
+    import lstm_tensorspark_tpu.ops.pallas_lstm as pallas_lstm
+
+    real = pallas_lstm.supported
+    pallas_lstm.supported = lambda *a, **k: real(*a, **{**k, "platform": "tpu"})
+    try:
+        yield
+    finally:
+        pallas_lstm.supported = real
+
+
+@pytest.fixture(autouse=True)
+def _as_the_program_compiles():
+    """Two process-wide settings differ between the tests and the
+    program. tests/conftest.py forces matmul precision "highest" (CPU
+    parity tests); Mosaic refuses an fp32-precision contraction of bf16
+    operands, and the program never asks for one — compile at JAX's
+    default. And a described-device executable is written to the
+    persistent cache but cannot be read back without a chip (it warns
+    and recompiles): keep the cache off around these compiles."""
+    cache_on = jax.config.jax_enable_compilation_cache
+    precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+    jax.config.update("jax_default_matmul_precision", precision)
+    compilation_cache.reset_cache()
+
+
+def _on(chip, tree):
+    """Shapes of ``tree`` (arrays or ShapeDtypeStructs) placed on the
+    described device — there is no device to hold an array."""
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _kernel_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+# ---- train recurrence, forward + backward -----------------------------
+
+# (name, B, T, D, H, masked, reversed) — bench.py CONFIGS shapes, bf16
+# matmuls as bench.py and the launch scripts run them
+TRAIN_SHAPES = [
+    ("ptb_char", 64, 64, 128, 128, False, False),
+    ("imdb_bilstm_fwd", 64, 400, 256, 256, True, False),
+    ("imdb_bilstm_rev", 64, 400, 256, 256, True, True),
+    ("wikitext2", 64, 35, 650, 650, False, False),
+    ("uci_seq2seq_enc", 64, 168, 370, 256, False, False),
+    ("uci_seq2seq_masked_rev", 64, 168, 256, 256, True, True),
+    ("wikitext103", 32, 64, 1024, 1024, False, False),
+]
+
+
+def _scan_args(chip, B, T, D, masked):
+    xs = jax.ShapeDtypeStruct((B, T, D), jnp.float32)
+    mask = jax.ShapeDtypeStruct((B, T), jnp.bool_) if masked else None
+    return _on(chip, (xs, mask))
+
+
+@pytest.mark.parametrize("name,B,T,D,H,masked,reverse", TRAIN_SHAPES,
+                         ids=[s[0] for s in TRAIN_SHAPES])
+def test_train_recurrence_fwd_bwd_compiles(chip, name, B, T, D, H, masked,
+                                           reverse):
+    params = jax.eval_shape(
+        lambda: init_lstm_params(jax.random.PRNGKey(0), D, H))
+
+    def loss(params, xs, mask):
+        (hT, _), ys = pallas_lstm_scan(
+            params, xs, mask=mask, reverse=reverse,
+            compute_dtype=jnp.bfloat16)
+        return jnp.sum(ys) + jnp.sum(hT)
+
+    xs, mask = _scan_args(chip, B, T, D, masked)
+    compiled = _compile(jax.grad(loss), _on(chip, params), xs, mask)
+    # forward kernel + fused backward kernel (the recompute backward
+    # would leave one)
+    assert _kernel_calls(compiled) >= 2, name
+
+
+def test_stacked_bilstm_fwd_bwd_compiles(chip):
+    B, T, D, H = 64, 400, 256, 256  # config 2, one bi-LSTM layer
+    pf, pb = (jax.eval_shape(
+        lambda: init_lstm_params(jax.random.PRNGKey(0), D, H))
+        for _ in range(2))
+
+    def loss(pf, pb, xs, mask):
+        ((hf, _), ys_f), ((hb, _), ys_b) = pallas_bilstm_scan(
+            pf, pb, xs, mask=mask, compute_dtype=jnp.bfloat16)
+        return jnp.sum(ys_f) + jnp.sum(ys_b) + jnp.sum(hf) + jnp.sum(hb)
+
+    xs, mask = _scan_args(chip, B, T, D, True)
+    compiled = _compile(jax.grad(loss, argnums=(0, 1)),
+                        _on(chip, pf), _on(chip, pb), xs, mask)
+    assert _kernel_calls(compiled) >= 2
+
+
+# ---- serve decode windows ---------------------------------------------
+
+
+def _lm_shapes(chip, cfg):
+    return _on(chip, jax.eval_shape(
+        lambda: init_lm(jax.random.PRNGKey(0), cfg)))
+
+
+def _row_args(chip, L, B, H):
+    carry = jax.ShapeDtypeStruct((L, B, H), jnp.float32)
+    row = jax.ShapeDtypeStruct((B,), jnp.int32)
+    alive = jax.ShapeDtypeStruct((B,), jnp.bool_)
+    return carry, row, alive
+
+
+# the default `cli serve` widths (where `auto` picks this kernel on a
+# TPU) and a larger plan that still fits the kernel's VMEM budget
+DECODE_SHAPES = [(89, 128, 2, 8, 8), (1024, 256, 2, 16, 8)]
+
+
+@pytest.mark.parametrize("greedy", [True, False], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("V,H,L,B,K", DECODE_SHAPES,
+                         ids=["default_widths", "v1024_h256"])
+def test_decode_window_compiles(chip, V, H, L, B, K, greedy):
+    cfg = LMConfig(vocab_size=V, hidden_size=H, num_layers=L)
+    assert pallas_decode.plan_fits(B, K, L, H, cfg.embed, V,
+                                   sampled=not greedy)
+    carry, row, alive = _row_args(chip, L, B, H)
+    noise = (None if greedy
+             else jax.ShapeDtypeStruct((K, B, V), jnp.float32))
+
+    def window(params, h, c, tok, alive, rem, eos, noise):
+        return pallas_decode.decode_window_call(
+            params, fuse_layers(params, cfg), cfg, h, c, tok, alive, rem,
+            eos, noise, window=K, temperature=0.8, greedy=greedy,
+            interpret=False)
+
+    compiled = _compile(window, _lm_shapes(chip, cfg), *_on(
+        chip, (carry, carry, row, alive, row, row, noise)))
+    assert _kernel_calls(compiled) == 1
+
+
+def test_spec_verify_window_compiles(chip):
+    V, B, k_draft = 89, 8, 4
+    cfg = LMConfig(vocab_size=V, hidden_size=128, num_layers=2)
+    dcfg = LMConfig(vocab_size=V, hidden_size=64, num_layers=1)
+    assert pallas_decode.spec_plan_fits(
+        B, k_draft, cfg.num_layers, cfg.hidden_size, cfg.embed, V,
+        dcfg.num_layers, dcfg.hidden_size, dcfg.embed)
+    carry, row, alive = _row_args(chip, cfg.num_layers, B, cfg.hidden_size)
+    dcarry, _, _ = _row_args(chip, dcfg.num_layers, B, dcfg.hidden_size)
+
+    def window(params, dparams, h, c, dh, dc, tok, alive, rem, eos):
+        return pallas_decode.spec_window_call(
+            params, fuse_layers(params, cfg), cfg,
+            dparams, fuse_layers(dparams, dcfg), dcfg,
+            h, c, dh, dc, tok, alive, rem, eos, k_draft=k_draft,
+            interpret=False)
+
+    compiled = _compile(
+        window, _lm_shapes(chip, cfg), _lm_shapes(chip, dcfg), *_on(
+            chip, (carry, carry, dcarry, dcarry, row, alive, row, row)))
+    assert _kernel_calls(compiled) == 1
+
+
+# ---- whole programs at config-5 widths (slow: run before a chip call) --
+
+CONFIG5 = dict(vocab_size=50_000, hidden_size=1024, num_layers=4,
+               compute_dtype="bfloat16")
+
+
+def _fits_hbm(compiled) -> int:
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert total < HBM_BYTES, m
+    return total
+
+
+@pytest.mark.slow
+def test_config5_train_step_compiles(chip):
+    """`make_train_step` at the chip_smoke train shape (B=32, T=64,
+    --use-pallas, no remat): both fused kernels of every layer present,
+    and the program fits one chip's HBM."""
+    from lstm_tensorspark_tpu.models import lm_loss
+    from lstm_tensorspark_tpu.train import make_optimizer, make_train_step
+    from lstm_tensorspark_tpu.train.loop import init_train_state
+
+    cfg = LMConfig(**CONFIG5, logits_dtype="bfloat16", use_pallas=True)
+    optimizer = make_optimizer("sgd", 1.0, clip_norm=0.25)
+    state = jax.eval_shape(lambda: init_train_state(
+        init_lm(jax.random.PRNGKey(0), cfg), optimizer,
+        jax.random.PRNGKey(1)))
+
+    def loss_fn(params, batch, rng):
+        return lm_loss(params, batch, cfg)
+
+    step = make_train_step(loss_fn, optimizer)
+    batch = {k: jax.ShapeDtypeStruct((32, 64), jnp.int32)
+             for k in ("inputs", "targets")}
+    with _kernels_selectable():
+        compiled = jax.jit(step).lower(
+            _on(chip, state), _on(chip, batch)).compile()
+    assert _kernel_calls(compiled) >= 2 * cfg.num_layers
+    _fits_hbm(compiled)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("program", ["prefill", "decode", "decode_window"])
+def test_config5_serve_programs_compile(chip, program):
+    """The serve engine's three program families at config-5 widths —
+    the scan window, since the fused kernel's VMEM plan cannot hold a
+    50,000-row embedding (`auto` resolves to scan there)."""
+    from lstm_tensorspark_tpu.serve.engine import GREEDY, ServeEngine
+
+    cfg = LMConfig(**CONFIG5)
+    assert not pallas_decode.plan_fits(
+        8, 8, cfg.num_layers, cfg.hidden_size, cfg.embed, cfg.vocab_size,
+        sampled=False)
+    # a tiny real engine only to borrow its program builders; the
+    # programs are lowered at config-5 SHAPES for the described device
+    small = LMConfig(vocab_size=32, hidden_size=8, num_layers=4)
+    engine = ServeEngine(init_lm(jax.random.PRNGKey(0), small), small,
+                         num_slots=8, decode_kernel="scan")
+    engine.cfg = cfg
+    params = _lm_shapes(chip, cfg)
+    fused = _on(chip, jax.eval_shape(lambda p: fuse_layers(p, cfg), params))
+    B, S = 8, 64
+    cache = jax.ShapeDtypeStruct(
+        (cfg.num_layers, S + 1, cfg.hidden_size), jnp.float32)
+    row = jax.ShapeDtypeStruct((B,), jnp.int32)
+    flag = jax.ShapeDtypeStruct((B,), jnp.bool_)
+    rng = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    if program == "prefill":
+        fn = engine._get_prefill_fn(B, 128, GREEDY)
+        prompts = jax.ShapeDtypeStruct((B, 128), jnp.int32)
+        args = (params, cache, cache, row, row, flag, prompts, row, rng)
+    elif program == "decode":
+        fn = engine._get_decode_fn(B, GREEDY)
+        args = (params, fused, cache, cache, row, row, rng)
+    else:
+        fn = engine._get_decode_window_fn(B, 8, GREEDY)
+        args = (params, fused, cache, cache, row, row, flag, row, row, rng)
+    compiled = fn.lower(*_on(chip, args)).compile()
+    _fits_hbm(compiled)
+
+
+@pytest.mark.slow
+def test_config5_dp4_train_step_compiles():
+    """`chip_smoke.py --multichip`'s DP program, compiled for the four
+    described chips before a four-chip call is paid for: the shard_map
+    device-data step over a ("data",) mesh of 4, B=32 global (8 rows and
+    both fused kernels per chip), the gradient all-reduce in the compiled
+    text, and the per-device memory inside one chip's HBM."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from lstm_tensorspark_tpu.data.device_dataset import DeviceLMData
+    from lstm_tensorspark_tpu.models import lm_loss
+    from lstm_tensorspark_tpu.models.lstm_lm import init_carries
+    from lstm_tensorspark_tpu.train import (
+        make_device_dp_lm_train_step, make_optimizer)
+    from lstm_tensorspark_tpu.train.loop import init_train_state
+
+    mesh = Mesh(np.asarray(_v5e_devices()), ("data",))
+    B, T, n_windows = 32, 64, 100
+    cfg = LMConfig(**CONFIG5, logits_dtype="bfloat16", use_pallas=True)
+    optimizer = make_optimizer("adam", 1e-3, clip_norm=1.0)
+
+    def loss_fn(params, batch, rng, carries):
+        return lm_loss(params, batch, cfg, carries=carries)
+
+    def put(tree, spec):
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), tree)
+
+    state = jax.eval_shape(lambda: init_train_state(
+        init_lm(jax.random.PRNGKey(0), cfg), optimizer,
+        jax.random.PRNGKey(1), carries=init_carries(cfg, B)))
+    state = state._replace(
+        **{f: put(getattr(state, f), P())
+           for f in ("step", "params", "opt_state", "rng")},
+        carries=put(state.carries, P("data")))
+    stream = jax.ShapeDtypeStruct((B, n_windows * T), jnp.int32)
+    arrays = put({"streams": stream, "shifted": stream}, P("data", None))
+    data = DeviceLMData(arrays=arrays, batch_size=B, seq_len=T,
+                        n_windows=n_windows)
+    step = make_device_dp_lm_train_step(
+        loss_fn, optimizer, data, mesh, steps_per_call=4, stateful=True)
+    w0 = put(jax.ShapeDtypeStruct((), jnp.int32), P())
+    with _kernels_selectable():
+        compiled = step.lower(state, arrays, w0).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2 * cfg.num_layers
+    assert "all-reduce" in text
+    _fits_hbm(compiled)

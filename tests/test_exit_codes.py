@@ -1,79 +1,12 @@
-"""Exit-code contract (resilience/exit_codes.py) and its consumers:
-uniqueness of the table, and chip_recovery's rc-first wedge routing
-(ADVICE r5 finding 1, closed properly: the dedicated liveness rc routes a
-wedge-shaped bench failure without scanning stdout)."""
-
-import os
-import sys
-
-import pytest
+"""Exit-code contract (resilience/exit_codes.py): uniqueness of the table."""
 
 from lstm_tensorspark_tpu.resilience import exit_codes as ec
 
-_TOOLS = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"
-)
-if _TOOLS not in sys.path:
-    sys.path.insert(0, _TOOLS)
-
 
 def test_codes_are_unique_and_in_range():
-    codes = [ec.USAGE_RC, ec.REGRESSION_RC, ec.CHILD_FAIL_RC, ec.WEDGE_RC,
-             ec.LIVENESS_RC, ec.ANOMALY_RC, ec.POISON_RC, ec.FAULT_CRASH_RC]
+    codes = [ec.USAGE_RC, ec.REGRESSION_RC, ec.LIVENESS_RC, ec.ANOMALY_RC,
+             ec.POISON_RC, ec.FAULT_CRASH_RC]
     assert len(set(codes)) == len(codes)  # no collisions, ever again
     assert all(0 < c < 128 for c in codes)  # never masquerade as a signal
     assert ec.RETRYABLE_RCS <= set(codes)
     assert ec.POISON_RC not in ec.RETRYABLE_RCS  # poison means STOP
-
-
-class _FakeCompleted:
-    def __init__(self, rc, stdout="", stderr=""):
-        self.returncode = rc
-        self.stdout = stdout
-        self.stderr = stderr
-
-
-@pytest.fixture()
-def chip_recovery(monkeypatch):
-    import chip_recovery as cr
-
-    return cr
-
-
-def _patch_run(monkeypatch, cr, result):
-    monkeypatch.setattr(cr.subprocess, "run", lambda *a, **k: result)
-
-
-def test_liveness_rc_routes_to_wedge_without_marker(monkeypatch, chip_recovery):
-    """The dedicated rc alone is enough — no marker string in the output."""
-    _patch_run(monkeypatch, chip_recovery,
-               _FakeCompleted(ec.LIVENESS_RC, stdout="{\"value\": 0.0}"))
-    with pytest.raises(SystemExit) as ei:
-        chip_recovery._run(["bench"], timeout=1, label="t", scan_wedge=True)
-    assert ei.value.code == ec.WEDGE_RC
-
-
-def test_marker_scan_survives_as_legacy_fallback(monkeypatch, chip_recovery):
-    _patch_run(monkeypatch, chip_recovery,
-               _FakeCompleted(3, stdout="... unreachable/wedged ..."))
-    with pytest.raises(SystemExit) as ei:
-        chip_recovery._run(["bench"], timeout=1, label="t", scan_wedge=True)
-    assert ei.value.code == ec.WEDGE_RC
-
-
-def test_plain_failure_is_child_fail_not_wedge(monkeypatch, chip_recovery):
-    """rc=3 WITHOUT the marker is the regression gate — a persistent
-    failure, must NOT loop the watcher's probe path."""
-    _patch_run(monkeypatch, chip_recovery,
-               _FakeCompleted(3, stdout="regression on imdb_bilstm"))
-    with pytest.raises(SystemExit) as ei:
-        chip_recovery._run(["bench"], timeout=1, label="t", scan_wedge=True)
-    assert ei.value.code == ec.CHILD_FAIL_RC
-
-
-def test_measure_routes_liveness_rc_to_wedge(monkeypatch, chip_recovery):
-    _patch_run(monkeypatch, chip_recovery,
-               _FakeCompleted(ec.LIVENESS_RC, stdout="", stderr="dead"))
-    with pytest.raises(SystemExit) as ei:
-        chip_recovery._measure("ptb_char")
-    assert ei.value.code == ec.WEDGE_RC

@@ -56,14 +56,14 @@ def test_render_measured_and_pending_rows():
     # split vintages: both legs' dates appear when they differ
     assert "tpu 2026-08-01" in row1 and "cpu 2026-07-31" in row1
     row2 = next(l for l in lines if "IMDB" in l)
-    assert "pending chip recovery" in row2
+    assert "no TPU leg on the new task" in row2
     # pending CPU cell uses the TIGHTEST reached target of the banked leg
     assert "1062.6 s to accuracy ≥ 0.8" in row2
     assert "banked 2026-07-31" in row2
     row3 = next(l for l in lines if "WikiText-2" in l)
     assert "ppl ≤ 60" in row3 and "— / — / 78.3×" in row3
     row4 = next(l for l in lines if "UCI" in l)
-    assert "pending chip recovery" in row4 and "4.7×" not in row4
+    assert "no TPU leg on the new task" in row4 and "4.7×" not in row4
     # configs with no entry at all render a no-common-target row
     row5 = next(l for l in lines if "WT-103" in l)
     assert "no common target" in row5

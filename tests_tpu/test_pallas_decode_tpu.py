@@ -24,6 +24,7 @@ import numpy as np
 import pytest
 
 from lstm_tensorspark_tpu.models import LMConfig, init_lm, make_generate_fn
+from lstm_tensorspark_tpu.models.generate import judge_greedy_divergence
 from lstm_tensorspark_tpu.serve import ServeEngine
 from lstm_tensorspark_tpu.serve.engine import GREEDY, SamplingParams
 
@@ -63,20 +64,29 @@ def test_compiled_window_token_parity(vocab, hidden, layers, batch, k):
         first = e.prefill([(s, True, p) for s, p in zip(slots, prompts)])
         win = e.decode_window(slots, [int(t) for t in first],
                               [2 * k] * batch, window=k)
+        toks1 = ServeEngine.fetch_window(win)
         win = e.decode_window_next(win)
         toks, rem, alive = e.fetch_window_summary(win)
-        outs[name] = ([int(t) for t in first], toks.tolist(),
-                      rem.tolist(), alive.tolist())
+        outs[name] = ([int(t) for t in first], toks1.tolist(),
+                      toks.tolist(), rem.tolist(), alive.tolist())
+    # the two window programs take the same batch through the same
+    # matmuls: token-identical, no tolerance
     assert outs["pallas"] == outs["scan"]
     assert any(key[0] == "decode_window_pallas"
                for key in ep.compile_counts)
-    # and against the uninterrupted reference program for row 0
+    # and against the uninterrupted single-sequence reference program for
+    # row 0: a different program, so a near-tied pick may round the other
+    # way — judged at the first divergence against the float32 reference,
+    # never waved through (first run on a v5e, PR 22: 264 vs 532 at a tie)
     gen = make_generate_fn(cfg, max_new_tokens=2 * k + 1, greedy=True)
     ref = np.asarray(gen(params, prompts[0][None, :],
                          jax.random.PRNGKey(0)))[0, prompts[0].size:]
-    first, toks, _, _ = outs["pallas"]
-    # second window's row 0 = tokens k..2k of the continuation
-    np.testing.assert_array_equal(np.asarray(toks[0]), ref[k + 1:])
+    first, toks1, toks2, _, _ = outs["pallas"]
+    served = np.asarray([first[0], *toks1[0], *toks2[0]], np.int32)
+    verdict, detail = judge_greedy_divergence(
+        params, cfg, prompts[0], served, ref)
+    print(f"\nwindowed decode vs generate, row 0: {verdict} {detail}")
+    assert verdict in ("equal", "tie"), detail
 
 
 def test_compiled_window_sampled_parity():

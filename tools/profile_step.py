@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Per-kernel device-time breakdown of any BENCH_TABLE config's train step
+"""Per-kernel device-time breakdown of any bench.py config's train step
 on the real chip: trace a few K-step dispatches of EXACTLY the program
 `bench.py`'s measure_config times (make_multi_train_step over a staged
 synthetic batch at real model dims), parse the xplane with
@@ -17,7 +17,7 @@ gather and 28 us/step in the embedding-grad scatter vs 29 us/step for the
 fused Pallas recurrence pair — 48% of the step in indexing; after the fix
 the same trace reads ~78 us/step with both kernels gone. Rerun it whenever
 a config's measured step time drifts from its roofline bound
-(BENCH_TABLE.json:roofline) to see where the slack actually is.
+(bench.py's `roofline` record) to see where the slack actually is.
 """
 
 import collections

@@ -1,8 +1,7 @@
 #!/usr/bin/env python3
 """Regenerate README.md's wall-clock-to-quality table from
-BASELINE_MEASURED.json — the quality-section counterpart of
-tools/readme_table.py (the perf-prose staleness the r3/r4 verdicts
-flagged twice). Mechanical from here on:
+BASELINE_MEASURED.json, so the prose cannot go stale against the record.
+Mechanical:
 
     python3 tools/readme_quality.py          # rewrite README.md in place
     python3 tools/readme_quality.py --check  # exit 1 if README is stale
@@ -90,7 +89,7 @@ def render(results: dict) -> str:
                 when = entry.get("cpu_measured_at")
                 if when:
                     cpu_s += f" (banked {when})"
-            state = ("*TPU leg pending chip recovery*" if invalidated
+            state = ("*no TPU leg on the new task*" if invalidated
                      else "*no common target reached*")
             task = "(new task)" if invalidated else "—"
             rows.append(f"| {label} | {task} | {state} | {cpu_s} | — |")
