@@ -206,10 +206,16 @@ def build_parser() -> argparse.ArgumentParser:
                         "THIS lineage; mutually exclusive with --resume "
                         "(the supervisor converts it to --resume on "
                         "relaunch); single-process only")
-    p.add_argument("--profile-dir", type=str, default=None, help="jax.profiler trace output dir")
+    p.add_argument("--profile-dir", type=str, default=None,
+                   help="jax.profiler trace output dir: the device's "
+                        "operations and the program's spans in one file "
+                        "(Python tracer off; the chip's clock sits a "
+                        "millisecond or two off the host's, a constant per "
+                        "trace: docs/OPERATIONS.md says how to take it out)")
     p.add_argument("--trace", type=str, default=None,
-                   help="host-side span trace output (Chrome trace-event "
-                        "JSON; device-side profiling is --profile-dir)")
+                   help="the program's spans as a host-side timeline "
+                        "(Chrome trace-event JSON; with the device's "
+                        "operations beside them: --profile-dir)")
     p.add_argument("--backend", type=str, default="auto", choices=["auto", "single", "dp"],
                    help="auto: dp when >1 device/partition")
     # --- advanced parallelism (LM task; new capability beyond the reference) ---
@@ -820,7 +826,11 @@ def _make_logged_loop(args, state, train_step, batches, steps_per_epoch, logger,
             batches, start_step=int(state.step), steps_per_call=k
         )
     if args.profile_dir:
-        jax.profiler.start_trace(args.profile_dir)
+        # the program's own spans (utils/tracing.py) say what the host
+        # does; the Python tracer would hook every call of every thread
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(args.profile_dir, profiler_options=options)
     try:
         state = train_loop(
             state,

@@ -1,8 +1,10 @@
 """Unified telemetry plane: one process-wide registry of counters,
 gauges, and fixed-bucket streaming histograms, exposed two ways —
 ``GET /metrics`` Prometheus text exposition on the serve HTTP endpoint
-(serve/server.py) and histogram summaries inside the ``/stats`` JSON —
-plus per-request span timelines through utils/tracing.
+(serve/server.py) and histogram summaries inside the ``/stats`` JSON.
+(Spans are not this package's: ``utils/tracing.span`` writes them to the
+profiler's trace and the ``--trace`` file; where a span and a histogram
+cover the same lines the histogram reads the span's stamps.)
 
 Production TPU serving treats step-time/throughput telemetry and
 per-request latency breakdowns as first-class (PAPERS.md, "Scalable

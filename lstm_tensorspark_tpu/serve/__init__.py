@@ -84,9 +84,12 @@ Telemetry: every layer records into ONE registry (``ServeEngine(
 registry=...)``, default ``obs.REGISTRY``; ``obs.NULL_REGISTRY``
 disables) — queue depth/wait, scheduler-iteration time, server-side
 TTFT/ITL histograms, window-K and prefill-chunk counters, compile and
-cache events — and the batcher emits per-request
-admit→queue→prefill→decode→readback timelines into the installed
-``utils.tracing`` tracer (``--trace``).
+cache events. Spans: the scheduler (``serve:*``) and the engine
+(``engine:*``) open ``utils.tracing.span``s where the work happens; under a
+profiler session (``--profile-dir``, the benchmark's traced run) they land
+on the trace's host plane beside the device's operations, and with
+``--trace`` in the Chrome JSON, where the batcher also writes each request's
+admit→queue→prefill→decode→readback timeline.
 
 CLI: ``python -m lstm_tensorspark_tpu.cli serve --selftest`` (see cli.py).
 """

@@ -85,6 +85,16 @@ window-K / prefill-chunk / readback-latency counters, and per-request
 phase timelines (``Request.phases`` → the Chrome tracer under
 ``--trace`` + ``phases_ms`` in the HTTP reply). Instruments are resolved
 once at construction; each record site costs a lock + an add.
+
+Spans (``utils.tracing.span``; on under a profiler session or ``--trace``):
+``serve:iteration`` around one ``step()``, ``serve:wait_for_work`` around
+the idle wait of ``run``, and inside an iteration ``serve:admit``,
+``serve:prefill_dispatch``, ``serve:decode_dispatch`` (``rows`` = sessions
+owed tokens, ``k``, ``pipelined``) and ``serve:deliver``; the engine's
+pack/launch/fetch spans nest inside the dispatches. Where a histogram or a
+request phase covers a span's lines it reads the span's own stamps (the
+iteration histogram, the per-token ``decode`` phase); the ``prefill``
+phases start at the engine call and end with the dispatch span.
 """
 
 from __future__ import annotations
@@ -867,10 +877,11 @@ class Batcher:
         # scheduler thread (death the router must retire), or the wedge
         # blocks with the heartbeat stale (the /healthz wedge case)
         _faults.serve_step_hook(self.replica)
-        t0 = time.perf_counter()
-        did = self._admit()
-        did = self._prefill_step() or did
-        did = self._decode_all() or did
+        with tracing.span("serve:iteration") as iteration:
+            with tracing.span("serve:admit"):
+                did = self._admit()
+            did = self._prefill_step() or did
+            did = self._decode_all() or did
         with self._lock:
             queued, active = self._qlen_locked(), len(self._active)
             prefilling = len(self._prefilling)
@@ -881,7 +892,7 @@ class Batcher:
             # idle passes are excluded: the histogram answers "how long
             # does a WORKING iteration hold the scheduler", not "how often
             # does the idle loop spin"
-            self._m_iteration.observe(time.perf_counter() - t0)
+            self._m_iteration.observe(iteration.end - iteration.start)
         # beat AGAIN on completion: a step that spends its whole budget
         # inside one long dispatch (first-shape compile, big window)
         # must not leave the heartbeat aged by that dispatch — a fresh
@@ -1222,104 +1233,113 @@ class Batcher:
 
     def _dispatch_prefill(self, batch: list[_Prefilling], final: bool,
                           chunk: int | None = None) -> None:
-        prefix = self.engine.prefix
-        items = []
-        draft_items = []
-        computed = 0  # prompt tokens this dispatch runs through the model
-        # the draft is distilled against the DEFAULT model only — other
-        # residents' sessions never speculate, so their prefills are not
-        # mirrored either
-        mirror = self.speculative and (
-            batch[0].sess.req.model is None
-            or batch[0].sess.req.model == self.engine.model_id)
-        for p in batch:
-            stop = self._next_stop(p, chunk)
-            # stride-aligned insert point: the state after prompt[:pos]
-            # sits in the session's own slot — one O(1) device copy caches
-            # it for every future sharer (insert() dedups existing keys
-            # itself, refreshing their LRU recency; rows resuming FROM an
-            # entry this dispatch have p.entry set and skip)
-            if (prefix is not None and p.was_fresh and p.entry is None
-                    and p.sess.req.use_prefix
-                    and p.pos >= prefix.stride
-                    and p.pos % prefix.stride == 0):
-                prefix.insert(p.sess.req.prompt[: p.pos], p.sess.slot)
-            src_slot, fresh = p.src()
-            items.append((p.sess.slot, src_slot, fresh,
-                          p.sess.req.prompt[p.pos: stop]))
-            computed += stop - p.pos
-            if mirror:
-                # mirror every target dispatch so the draft's slot state
-                # tracks the consumed context. The draft's FIRST fragment
-                # always starts from zero — it has no prefix entries or
-                # tier copies to resume from (prefix-resumed and
-                # tier-restored rows rebuild draft context from the
-                # fragment alone: lossless, lower acceptance until the
-                # draft catches up)
-                draft_items.append((p.sess.slot, not p.draft_started,
-                                    p.sess.req.prompt[p.pos: stop]))
-        t0 = time.perf_counter()
-        try:
-            if final:
-                first = self.engine.prefill(items, batch[0].sess.req.sampling,
-                                            model=batch[0].sess.req.model)
-            else:
-                self.engine.prefill_chunk(items,
-                                          model=batch[0].sess.req.model)
-                self.prefill_chunks_dispatched += 1
-                self._m_chunks.inc()
-        except Exception as e:
+        # one span over building the items and the engine call (which, for
+        # a final chunk, also fetches the first token: the engine's own
+        # pack/launch/fetch spans split it); the rows' phase starts at the
+        # engine call (t0) and ends with the span
+        with tracing.span("serve:prefill_dispatch", rows=len(batch),
+                          final=int(final)) as dispatch:
+            prefix = self.engine.prefix
+            items = []
+            draft_items = []
+            computed = 0  # prompt tokens this dispatch runs through the model
+            # the draft is distilled against the DEFAULT model only — other
+            # residents' sessions never speculate, so their prefills are not
+            # mirrored either
+            mirror = self.speculative and (
+                batch[0].sess.req.model is None
+                or batch[0].sess.req.model == self.engine.model_id)
             for p in batch:
-                self._abort_prefilling(
-                    p, f"prefill failed: {type(e).__name__}: {e}")
-            return
-        # count AFTER the dispatch lands: the compute-savings gate
-        # (saved vs computed) must not credit work an aborted batch
-        # never did
-        self.prefill_tokens_computed += computed
-        if draft_items:
+                stop = self._next_stop(p, chunk)
+                # stride-aligned insert point: the state after prompt[:pos]
+                # sits in the session's own slot — one O(1) device copy caches
+                # it for every future sharer (insert() dedups existing keys
+                # itself, refreshing their LRU recency; rows resuming FROM an
+                # entry this dispatch have p.entry set and skip)
+                if (prefix is not None and p.was_fresh and p.entry is None
+                        and p.sess.req.use_prefix
+                        and p.pos >= prefix.stride
+                        and p.pos % prefix.stride == 0):
+                    prefix.insert(p.sess.req.prompt[: p.pos], p.sess.slot)
+                src_slot, fresh = p.src()
+                items.append((p.sess.slot, src_slot, fresh,
+                              p.sess.req.prompt[p.pos: stop]))
+                computed += stop - p.pos
+                if mirror:
+                    # mirror every target dispatch so the draft's slot state
+                    # tracks the consumed context. The draft's FIRST fragment
+                    # always starts from zero — it has no prefix entries or
+                    # tier copies to resume from (prefix-resumed and
+                    # tier-restored rows rebuild draft context from the
+                    # fragment alone: lossless, lower acceptance until the
+                    # draft catches up)
+                    draft_items.append((p.sess.slot, not p.draft_started,
+                                        p.sess.req.prompt[p.pos: stop]))
+            t0 = time.perf_counter()
             try:
-                self.engine.draft_prefill(draft_items)
-                self.draft_prefills_dispatched += 1
+                if final:
+                    first = self.engine.prefill(items, batch[0].sess.req.sampling,
+                                                model=batch[0].sess.req.model)
+                else:
+                    self.engine.prefill_chunk(items,
+                                              model=batch[0].sess.req.model)
+                    self.prefill_chunks_dispatched += 1
+                    self._m_chunks.inc()
+            except Exception as e:
                 for p in batch:
-                    p.draft_started = True
-            except Exception:
-                # draft state is acceptance-only — a failed mirror can
-                # never corrupt output (the verify window is teacher-
-                # forced by the TARGET), so the session proceeds with a
-                # stale draft instead of failing a healthy prefill; the
-                # counter is the failure's only surface (stats/bench)
-                self.draft_prefill_failures += 1
-        now = time.perf_counter()
+                    self._abort_prefilling(
+                        p, f"prefill failed: {type(e).__name__}: {e}")
+                return
+            # count AFTER the dispatch lands: the compute-savings gate
+            # (saved vs computed) must not credit work an aborted batch
+            # never did
+            self.prefill_tokens_computed += computed
+            if draft_items:
+                try:
+                    self.engine.draft_prefill(draft_items)
+                    self.draft_prefills_dispatched += 1
+                    for p in batch:
+                        p.draft_started = True
+                except Exception:
+                    # draft state is acceptance-only — a failed mirror can
+                    # never corrupt output (the verify window is teacher-
+                    # forced by the TARGET), so the session proceeds with a
+                    # stale draft instead of failing a healthy prefill; the
+                    # counter is the failure's only surface (stats/bench)
+                    self.draft_prefill_failures += 1
+        now = dispatch.end
         phase = "prefill" if final else "prefill_chunk"
         for p in batch:
             # final prefill syncs on the first token (np.asarray), so its
             # span covers device compute; a chunk's span is dispatch only
             p.sess.req.phases.append((phase, t0, now))
-        for i, p in enumerate(batch):
-            # the gather from a prefix slot is in flight and data-ordered:
-            # the ref can drop now — and only now did the resume actually
-            # happen (an aborted session must not count as savings)
-            if p.entry is not None:
-                self.prefix_resumed += 1
-                self.prefix_tokens_saved += p.pos
-                prefix.release(p.entry)
-                p.entry = None
-            if not final:
-                p.pos = self._next_stop(p, chunk)
-                continue
-            with self._lock:
-                self._prefilling.remove(p)
-            s = p.sess
-            s.req.t_first_token = now
-            if s.req.t_submit is not None:
-                self._m_ttft.observe(now - s.req.t_submit)
-            self._append_token(s, int(first[i]))
-            if s.remaining == 0:
-                self._finish(s)
-            else:
+        # a final dispatch hands each row its first token; a chunk's rows
+        # only move on
+        with tracing.span("serve:deliver"):
+            for i, p in enumerate(batch):
+                # the gather from a prefix slot is in flight and data-ordered:
+                # the ref can drop now — and only now did the resume actually
+                # happen (an aborted session must not count as savings)
+                if p.entry is not None:
+                    self.prefix_resumed += 1
+                    self.prefix_tokens_saved += p.pos
+                    prefix.release(p.entry)
+                    p.entry = None
+                if not final:
+                    p.pos = self._next_stop(p, chunk)
+                    continue
                 with self._lock:
-                    self._active.append(s)
+                    self._prefilling.remove(p)
+                s = p.sess
+                s.req.t_first_token = now
+                if s.req.t_submit is not None:
+                    self._m_ttft.observe(now - s.req.t_submit)
+                self._append_token(s, int(first[i]))
+                if s.remaining == 0:
+                    self._finish(s)
+                else:
+                    with self._lock:
+                        self._active.append(s)
 
     def _abort_prefilling(self, p: _Prefilling, error: str | None,
                           *, timeout: bool = False) -> None:
@@ -1403,22 +1423,25 @@ class Batcher:
                 chunk = group[i : i + self.engine.max_batch]
                 slots = [s.slot for s in chunk]
                 toks = [s.last_token for s in chunk]
-                t0 = time.perf_counter()
                 try:
-                    nxt = self.engine.decode(slots, toks,
-                                             chunk[0].req.sampling,
-                                             model=chunk[0].req.model)
+                    with tracing.span("serve:decode_dispatch",
+                                      rows=len(chunk), k=1,
+                                      pipelined=0) as dispatch:
+                        nxt = self.engine.decode(slots, toks,
+                                                 chunk[0].req.sampling,
+                                                 model=chunk[0].req.model)
                 except Exception as e:
                     self._fail_chunk(
                         chunk, f"decode failed: {type(e).__name__}: {e}")
                     continue
-                t1 = time.perf_counter()
-                for s, tok in zip(chunk, nxt):
-                    s.req.phases.append(("decode", t0, t1))
-                    self._append_token(s, int(tok), t1)
-                    if s.remaining == 0:
-                        self._retire(s)
-                        self._finish(s)
+                t0, t1 = dispatch.start, dispatch.end
+                with tracing.span("serve:deliver"):
+                    for s, tok in zip(chunk, nxt):
+                        s.req.phases.append(("decode", t0, t1))
+                        self._append_token(s, int(tok), t1)
+                        if s.remaining == 0:
+                            self._retire(s)
+                            self._finish(s)
         return True
 
     # ---- windowed decode (see module docstring) ------------------------
@@ -1469,14 +1492,16 @@ class Batcher:
         tokens, target verifies all of them plus one correction in ONE
         pass); handles park in ``_pending`` like a plain window."""
         try:
-            win = self.engine.spec_window(
-                [s.slot for s in sessions],
-                [s.last_token for s in sessions],
-                [s.remaining for s in sessions],
-                [-1 if s.req.eos_id is None else s.req.eos_id
-                 for s in sessions],
-                k_draft=kd, model=sessions[0].req.model,
-            )
+            with tracing.span("serve:decode_dispatch", rows=len(sessions),
+                              k=kd + 1, pipelined=0):
+                win = self.engine.spec_window(
+                    [s.slot for s in sessions],
+                    [s.last_token for s in sessions],
+                    [s.remaining for s in sessions],
+                    [-1 if s.req.eos_id is None else s.req.eos_id
+                     for s in sessions],
+                    k_draft=kd, model=sessions[0].req.model,
+                )
         except Exception as e:
             self._fail_chunk(sessions, f"decode failed: {type(e).__name__}: {e}")
             return
@@ -1488,15 +1513,17 @@ class Batcher:
         """Dispatch a K-token window for ``sessions`` from host state; the
         handles park in ``_pending`` for the next iteration's fetch."""
         try:
-            win = self.engine.decode_window(
-                [s.slot for s in sessions],
-                [s.last_token for s in sessions],
-                [s.remaining for s in sessions],
-                [-1 if s.req.eos_id is None else s.req.eos_id
-                 for s in sessions],
-                sessions[0].req.sampling, window=k,
-                model=sessions[0].req.model,
-            )
+            with tracing.span("serve:decode_dispatch", rows=len(sessions),
+                              k=k, pipelined=0):
+                win = self.engine.decode_window(
+                    [s.slot for s in sessions],
+                    [s.last_token for s in sessions],
+                    [s.remaining for s in sessions],
+                    [-1 if s.req.eos_id is None else s.req.eos_id
+                     for s in sessions],
+                    sessions[0].req.sampling, window=k,
+                    model=sessions[0].req.model,
+                )
         except Exception as e:
             self._fail_chunk(sessions, f"decode failed: {type(e).__name__}: {e}")
             return
@@ -1542,7 +1569,11 @@ class Batcher:
                 kd = self._spec_k_for(sessions, min(live))
                 if kd > 0:
                     try:
-                        nxt = self.engine.spec_window_next(win, k_draft=kd)
+                        with tracing.span("serve:decode_dispatch",
+                                          rows=len(live), k=kd + 1,
+                                          pipelined=1):
+                            nxt = self.engine.spec_window_next(
+                                win, k_draft=kd)
                     except Exception as e:
                         self._fail_chunk(
                             sessions,
@@ -1553,9 +1584,13 @@ class Batcher:
                     self.windows_pipelined += 1
                     self._pending = (nxt, list(sessions))
             elif live:
+                k = self._pick_window(min(live))
                 try:
-                    nxt = self.engine.decode_window_next(
-                        win, window=self._pick_window(min(live)))
+                    # rows: the sessions still owed tokens after the
+                    # unfetched window (the rest are latched dead on device)
+                    with tracing.span("serve:decode_dispatch",
+                                      rows=len(live), k=k, pipelined=1):
+                        nxt = self.engine.decode_window_next(win, window=k)
                 except Exception as e:
                     self._fail_chunk(
                         sessions, f"decode failed: {type(e).__name__}: {e}")
@@ -1582,59 +1617,60 @@ class Batcher:
         # reach the host after its program was dispatched (device compute
         # + readback, minus whatever the scheduler overlapped)
         self._m_readback.observe(now - win.t_dispatch)
-        for i, (s, row) in enumerate(zip(sessions, toks)):
-            if s.req.cancelled or s.req.done.is_set():
-                continue  # the cancel sweep / a prior window settled it
-            s.req.phases.append((
-                "spec_window" if win.spec else "decode_window",
-                win.t_dispatch, t_fetch))
-            s.req.phases.append(("readback", t_fetch, now))
-            if win.spec:
-                # accept accounting: a spec window emits accepted+1
-                # tokens per live row (the verify step that detects the
-                # first disagreement emits the target's own correction
-                # token). emitted == 0 means the row was dead at window
-                # entry — not a rejection, so it doesn't skew the
-                # histogram the autotuner steers by.
-                emitted = 0
+        with tracing.span("serve:deliver"):
+            for i, (s, row) in enumerate(zip(sessions, toks)):
+                if s.req.cancelled or s.req.done.is_set():
+                    continue  # the cancel sweep / a prior window settled it
+                s.req.phases.append((
+                    "spec_window" if win.spec else "decode_window",
+                    win.t_dispatch, t_fetch))
+                s.req.phases.append(("readback", t_fetch, now))
+                if win.spec:
+                    # accept accounting: a spec window emits accepted+1
+                    # tokens per live row (the verify step that detects the
+                    # first disagreement emits the target's own correction
+                    # token). emitted == 0 means the row was dead at window
+                    # entry — not a rejection, so it doesn't skew the
+                    # histogram the autotuner steers by.
+                    emitted = 0
+                    for tok in row:
+                        if tok == PAD_TOKEN:
+                            break
+                        emitted += 1
+                    if emitted > 0:
+                        accepted = emitted - 1
+                        self.spec_accepted_tokens += accepted
+                        self._m_spec_accept.observe(float(accepted))
+                        if accepted >= win.window - 1:
+                            outcome = "full"
+                        elif accepted > 0:
+                            outcome = "partial"
+                        else:
+                            outcome = "reject"
+                        self._m_spec_outcome[outcome].inc()
                 for tok in row:
                     if tok == PAD_TOKEN:
                         break
-                    emitted += 1
-                if emitted > 0:
-                    accepted = emitted - 1
-                    self.spec_accepted_tokens += accepted
-                    self._m_spec_accept.observe(float(accepted))
-                    if accepted >= win.window - 1:
-                        outcome = "full"
-                    elif accepted > 0:
-                        outcome = "partial"
-                    else:
-                        outcome = "reject"
-                    self._m_spec_outcome[outcome].inc()
-            for tok in row:
-                if tok == PAD_TOKEN:
-                    break
-                self._append_token(s, int(tok), now)
+                    self._append_token(s, int(tok), now)
+                    if s.remaining == 0:
+                        break
+                if not dev_alive[i] or dev_rem[i] <= 0:
+                    # the device latch is the liveness authority (EOS hit or
+                    # budget exhausted inside the window); the host token
+                    # walk above agrees by construction — _append_token's
+                    # bookkeeping mirrors the same latch rules
+                    s.remaining = 0
                 if s.remaining == 0:
-                    break
-            if not dev_alive[i] or dev_rem[i] <= 0:
-                # the device latch is the liveness authority (EOS hit or
-                # budget exhausted inside the window); the host token
-                # walk above agrees by construction — _append_token's
-                # bookkeeping mirrors the same latch rules
-                s.remaining = 0
-            if s.remaining == 0:
-                self._retire(s)
-                self._finish(s)
-            elif s.req.expired(now):
-                # window boundary = deadline boundary: this window's
-                # tokens were delivered above, the request settles now
-                # with that partial output (see the _decode_all sweep
-                # for why the session is never kept)
-                self._retire(s)
-                self._release_timed_out_session(s)
-                self._settle_timeout(s.req, "decode")
+                    self._retire(s)
+                    self._finish(s)
+                elif s.req.expired(now):
+                    # window boundary = deadline boundary: this window's
+                    # tokens were delivered above, the request settles now
+                    # with that partial output (see the _decode_all sweep
+                    # for why the session is never kept)
+                    self._retire(s)
+                    self._release_timed_out_session(s)
+                    self._settle_timeout(s.req, "decode")
 
     def _fail_chunk(self, sessions: list[_Session], error: str) -> None:
         for s in sessions:
@@ -1764,7 +1800,8 @@ class Batcher:
                 continue
             with self._work:
                 if not self._qlen_locked() and not self._active:
-                    self._work.wait(timeout=idle_wait)
+                    with tracing.span("serve:wait_for_work"):
+                        self._work.wait(timeout=idle_wait)
             # idle cycles beat the heartbeat too: "no traffic" and "thread
             # stuck" must look different to /healthz
             self.last_heartbeat = time.monotonic()

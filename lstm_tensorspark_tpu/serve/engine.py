@@ -93,6 +93,7 @@ from ..models.generate import decode_one, fuse_layers, sample_logits
 from ..models.lstm_lm import LMConfig, _head_kernel, lm_backbone
 from ..ops import pallas_decode
 from ..resilience import faults as _faults
+from ..utils.tracing import span
 from .state_cache import DetachedState, PrefixCache, SessionTiers, StateCache
 
 # Emitted by decode_window for a row that is no longer live (post-EOS /
@@ -167,6 +168,12 @@ class DecodeWindow:
     # decode_window_next — the two programs carry different device state
     # (the spec one also threads the draft model's carries)
     spec: bool = False
+
+
+def _fetch_span(win: DecodeWindow) -> span:
+    """The wait for a window's answer: the one readback span."""
+    return span("engine:fetch", program="spec_fn" if win.spec else "window_fn",
+                rows=win.n, k=win.window)
 
 
 def _bucket_for(value: int, buckets: tuple[int, ...], what: str) -> int:
@@ -1151,31 +1158,34 @@ class ServeEngine:
 
     def _pack_prefill(self, items):
         """Pad normalised items to (batch, length) buckets; returns the
-        padded host arrays + (n, batch_b, len_b). Final and intermediate
+        padded arrays, on the device, + (n, batch_b, len_b). Final and intermediate
         chunk programs share ONE length-bucket lattice (prefill_buckets) —
         Batcher.warmup's replay assumes this."""
-        n = len(items)
-        lengths = [int(np.asarray(p).size) for _, _, _, p in items]
-        for t in lengths:
-            if t < 1:
-                raise ValueError("empty prompt")
-        batch_b = _bucket_for(n, self.batch_buckets, "prefill batch")
-        len_b = _bucket_for(max(lengths), self.prefill_buckets,
-                            "prompt length")
-        scratch = self.cache.scratch_slot
-        src = np.full((batch_b,), scratch, np.int32)
-        dst = np.full((batch_b,), scratch, np.int32)
-        fresh = np.ones((batch_b,), bool)
-        prompts = np.zeros((batch_b, len_b), np.int32)
-        lens = np.ones((batch_b,), np.int32)
-        for i, (d, s, is_fresh, prompt) in enumerate(items):
-            p = np.asarray(prompt, np.int32).reshape(-1)
-            dst[i] = d
-            src[i] = s
-            fresh[i] = bool(is_fresh)
-            prompts[i, : p.size] = p
-            lens[i] = p.size
-        return src, dst, fresh, prompts, lens, n, batch_b, len_b
+        with span("engine:pack"):
+            n = len(items)
+            lengths = [int(np.asarray(p).size) for _, _, _, p in items]
+            for t in lengths:
+                if t < 1:
+                    raise ValueError("empty prompt")
+            batch_b = _bucket_for(n, self.batch_buckets, "prefill batch")
+            len_b = _bucket_for(max(lengths), self.prefill_buckets,
+                                "prompt length")
+            scratch = self.cache.scratch_slot
+            src = np.full((batch_b,), scratch, np.int32)
+            dst = np.full((batch_b,), scratch, np.int32)
+            fresh = np.ones((batch_b,), bool)
+            prompts = np.zeros((batch_b, len_b), np.int32)
+            lens = np.ones((batch_b,), np.int32)
+            for i, (d, s, is_fresh, prompt) in enumerate(items):
+                p = np.asarray(prompt, np.int32).reshape(-1)
+                dst[i] = d
+                src[i] = s
+                fresh[i] = bool(is_fresh)
+                prompts[i, : p.size] = p
+                lens[i] = p.size
+            arrays = [jnp.asarray(a)
+                      for a in (src, dst, fresh, prompts, lens)]
+            return (*arrays, n, batch_b, len_b)
 
     def prefill(self, items, sampling: SamplingParams = GREEDY, *,
                 model: str | None = None) -> np.ndarray:
@@ -1199,12 +1209,13 @@ class ServeEngine:
             _, params, _, mkey = self._resolve_model(model)
             fn = self._get_prefill_fn(batch_b, len_b, sampling, mkey)
             rng = self._next_rng(sampling)
-            h, c, tok = fn(params, self.cache.h, self.cache.c,
-                           jnp.asarray(src), jnp.asarray(dst),
-                           jnp.asarray(fresh), jnp.asarray(prompts),
-                           jnp.asarray(lens), rng)
+            with span("engine:launch", program="prefill_fn",
+                      batch_bucket=batch_b, len_bucket=len_b):
+                h, c, tok = fn(params, self.cache.h, self.cache.c,
+                               src, dst, fresh, prompts, lens, rng)
             self.cache.swap(h, c)
-        return np.asarray(tok)[:n]
+        with span("engine:fetch", program="prefill_fn", rows=n, k=1):
+            return np.asarray(tok)[:n]
 
     def prefill_chunk(self, items, *, model: str | None = None) -> None:
         """Dispatch one INTERMEDIATE prefill chunk batch: advance each
@@ -1219,9 +1230,10 @@ class ServeEngine:
         with self._lock:
             _, params, _, mkey = self._resolve_model(model)
             fn = self._get_prefill_chunk_fn(batch_b, len_b, mkey)
-            h, c = fn(params, self.cache.h, self.cache.c,
-                      jnp.asarray(src), jnp.asarray(dst), jnp.asarray(fresh),
-                      jnp.asarray(prompts), jnp.asarray(lens))
+            with span("engine:launch", program="chunk_fn",
+                      batch_bucket=batch_b, len_bucket=len_b):
+                h, c = fn(params, self.cache.h, self.cache.c,
+                          src, dst, fresh, prompts, lens)
             self.cache.swap(h, c)
 
     def draft_prefill(self, items) -> None:
@@ -1243,10 +1255,10 @@ class ServeEngine:
             self._pack_prefill(self._norm_prefill_items(items)))
         with self._lock:
             fn = self._get_draft_prefill_fn(batch_b, len_b)
-            dh, dc = fn(self.draft["params"], self._draft_h, self._draft_c,
-                        jnp.asarray(src), jnp.asarray(dst),
-                        jnp.asarray(fresh), jnp.asarray(prompts),
-                        jnp.asarray(lens))
+            with span("engine:launch", program="draft_fn",
+                      batch_bucket=batch_b, len_bucket=len_b):
+                dh, dc = fn(self.draft["params"], self._draft_h,
+                            self._draft_c, src, dst, fresh, prompts, lens)
             self._draft_h, self._draft_c = dh, dc
 
     def decode(self, slots, tokens, sampling: SamplingParams = GREEDY, *,
@@ -1267,20 +1279,45 @@ class ServeEngine:
             _faults.serve_decode_hook()
         self._admit_sampling(sampling)
         batch_b = _bucket_for(n, self.batch_buckets, "decode batch")
-        slots_p = np.full((batch_b,), self.cache.scratch_slot, np.int32)
-        slots_p[:n] = np.asarray(slots, np.int32)
-        tokens_p = np.zeros((batch_b,), np.int32)
-        tokens_p[:n] = np.asarray(tokens, np.int32)
+        with span("engine:pack"):
+            slots_p = np.full((batch_b,), self.cache.scratch_slot, np.int32)
+            slots_p[:n] = np.asarray(slots, np.int32)
+            tokens_p = np.zeros((batch_b,), np.int32)
+            tokens_p[:n] = np.asarray(tokens, np.int32)
+            slots_d, tokens_d = jnp.asarray(slots_p), jnp.asarray(tokens_p)
 
         with self._lock:
             _, params, fused, mkey = self._resolve_model(model)
             fn = self._get_decode_fn(batch_b, sampling, mkey)
             rng = self._next_rng(sampling)
-            h, c, tok = fn(params, fused, self.cache.h,
-                           self.cache.c, jnp.asarray(slots_p),
-                           jnp.asarray(tokens_p), rng)
+            with span("engine:launch", program="decode_fn",
+                      batch_bucket=batch_b):
+                h, c, tok = fn(params, fused, self.cache.h, self.cache.c,
+                               slots_d, tokens_d, rng)
             self.cache.swap(h, c)
-        return np.asarray(tok)[:n]
+        with span("engine:fetch", program="decode_fn", rows=n, k=1):
+            return np.asarray(tok)[:n]
+
+    def _pack_window(self, slots, tokens, remaining, eos_ids):
+        """A window's per-row host values padded to the batch bucket (dead
+        rows: scratch slot, alive=False) and put on the device:
+        ``(batch_b, slots, tokens, alive, remaining, eos_ids)``."""
+        n = len(slots)
+        with span("engine:pack"):
+            batch_b = _bucket_for(n, self.batch_buckets, "decode batch")
+            slots_p = np.full((batch_b,), self.cache.scratch_slot, np.int32)
+            slots_p[:n] = np.asarray(slots, np.int32)
+            tokens_p = np.zeros((batch_b,), np.int32)
+            tokens_p[:n] = np.asarray(tokens, np.int32)
+            rem_p = np.zeros((batch_b,), np.int32)
+            rem_p[:n] = np.asarray(remaining, np.int32)
+            eos_p = np.full((batch_b,), -1, np.int32)
+            if eos_ids is not None:
+                eos_p[:n] = np.asarray(eos_ids, np.int32)
+            alive_p = np.zeros((batch_b,), bool)
+            alive_p[:n] = rem_p[:n] > 0
+            return (batch_b, *(jnp.asarray(a) for a in (
+                slots_p, tokens_p, alive_p, rem_p, eos_p)))
 
     def decode_window(self, slots, tokens, remaining, eos_ids=None,
                       sampling: SamplingParams = GREEDY, *,
@@ -1302,30 +1339,19 @@ class ServeEngine:
         if not self._warming:
             _faults.serve_decode_hook()
         self._admit_sampling(sampling)
-        batch_b = _bucket_for(n, self.batch_buckets, "decode batch")
-        slots_p = np.full((batch_b,), self.cache.scratch_slot, np.int32)
-        slots_p[:n] = np.asarray(slots, np.int32)
-        tokens_p = np.zeros((batch_b,), np.int32)
-        tokens_p[:n] = np.asarray(tokens, np.int32)
-        rem_p = np.zeros((batch_b,), np.int32)
-        rem_p[:n] = np.asarray(remaining, np.int32)
-        eos_p = np.full((batch_b,), -1, np.int32)
-        if eos_ids is not None:
-            eos_p[:n] = np.asarray(eos_ids, np.int32)
-        alive_p = np.zeros((batch_b,), bool)
-        alive_p[:n] = rem_p[:n] > 0
+        batch_b, slots_d, tokens_d, alive_d, rem_d, eos_d = (
+            self._pack_window(slots, tokens, remaining, eos_ids))
 
         with self._lock:
             mid, params, fused, mkey = self._resolve_model(model)
             fn = self._window_fn_for(batch_b, window, sampling, mkey)
             rng = self._next_rng(sampling)
-            slots_d = jnp.asarray(slots_p)
-            eos_d = jnp.asarray(eos_p)
-            h, c, toks, next_tok, alive, rem = fn(
-                params, fused, self.cache.h, self.cache.c,
-                slots_d, jnp.asarray(tokens_p), jnp.asarray(alive_p),
-                jnp.asarray(rem_p), eos_d, rng,
-            )
+            with span("engine:launch", program="window_fn",
+                      batch_bucket=batch_b):
+                h, c, toks, next_tok, alive, rem = fn(
+                    params, fused, self.cache.h, self.cache.c,
+                    slots_d, tokens_d, alive_d, rem_d, eos_d, rng,
+                )
             self.cache.swap(h, c)
         return DecodeWindow(
             tokens=toks, next_tokens=next_tok, alive=alive, remaining=rem,
@@ -1352,11 +1378,13 @@ class ServeEngine:
             fn = self._window_fn_for(prev.batch_b, window, prev.sampling,
                                      mkey)
             rng = self._next_rng(prev.sampling)
-            h, c, toks, next_tok, alive, rem = fn(
-                params, fused, self.cache.h, self.cache.c,
-                prev.slots, prev.next_tokens, prev.alive, prev.remaining,
-                prev.eos_ids, rng,
-            )
+            with span("engine:launch", program="window_fn",
+                      batch_bucket=prev.batch_b):
+                h, c, toks, next_tok, alive, rem = fn(
+                    params, fused, self.cache.h, self.cache.c,
+                    prev.slots, prev.next_tokens, prev.alive,
+                    prev.remaining, prev.eos_ids, rng,
+                )
             self.cache.swap(h, c)
         return dataclasses.replace(
             prev, tokens=toks, next_tokens=next_tok, alive=alive,
@@ -1386,18 +1414,8 @@ class ServeEngine:
                              f"got {n} rows, k_draft {k_draft}")
         if not self._warming:
             _faults.serve_decode_hook()
-        batch_b = _bucket_for(n, self.batch_buckets, "decode batch")
-        slots_p = np.full((batch_b,), self.cache.scratch_slot, np.int32)
-        slots_p[:n] = np.asarray(slots, np.int32)
-        tokens_p = np.zeros((batch_b,), np.int32)
-        tokens_p[:n] = np.asarray(tokens, np.int32)
-        rem_p = np.zeros((batch_b,), np.int32)
-        rem_p[:n] = np.asarray(remaining, np.int32)
-        eos_p = np.full((batch_b,), -1, np.int32)
-        if eos_ids is not None:
-            eos_p[:n] = np.asarray(eos_ids, np.int32)
-        alive_p = np.zeros((batch_b,), bool)
-        alive_p[:n] = rem_p[:n] > 0
+        batch_b, slots_d, tokens_d, alive_d, rem_d, eos_d = (
+            self._pack_window(slots, tokens, remaining, eos_ids))
 
         with self._lock:
             mid, params, fused, _ = self._resolve_model(model)
@@ -1406,14 +1424,13 @@ class ServeEngine:
                     f"spec_window serves the DEFAULT model only (the "
                     f"draft is distilled against it); got model {mid!r}")
             fn = self._spec_window_fn_for(batch_b, k_draft)
-            slots_d = jnp.asarray(slots_p)
-            eos_d = jnp.asarray(eos_p)
-            h, c, dh, dc, toks, next_tok, alive, rem = fn(
-                params, fused, self.draft["params"], self.draft["fused"],
-                self.cache.h, self.cache.c, self._draft_h, self._draft_c,
-                slots_d, jnp.asarray(tokens_p), jnp.asarray(alive_p),
-                jnp.asarray(rem_p), eos_d,
-            )
+            with span("engine:launch", program="spec_fn",
+                      batch_bucket=batch_b):
+                h, c, dh, dc, toks, next_tok, alive, rem = fn(
+                    params, fused, self.draft["params"], self.draft["fused"],
+                    self.cache.h, self.cache.c, self._draft_h, self._draft_c,
+                    slots_d, tokens_d, alive_d, rem_d, eos_d,
+                )
             self.cache.swap(h, c)
             self._draft_h, self._draft_c = dh, dc
         return DecodeWindow(
@@ -1443,12 +1460,14 @@ class ServeEngine:
         with self._lock:
             _, params, fused, _ = self._resolve_model(prev.model)
             fn = self._spec_window_fn_for(prev.batch_b, k)
-            h, c, dh, dc, toks, next_tok, alive, rem = fn(
-                params, fused, self.draft["params"], self.draft["fused"],
-                self.cache.h, self.cache.c, self._draft_h, self._draft_c,
-                prev.slots, prev.next_tokens, prev.alive, prev.remaining,
-                prev.eos_ids,
-            )
+            with span("engine:launch", program="spec_fn",
+                      batch_bucket=prev.batch_b):
+                h, c, dh, dc, toks, next_tok, alive, rem = fn(
+                    params, fused, self.draft["params"], self.draft["fused"],
+                    self.cache.h, self.cache.c, self._draft_h, self._draft_c,
+                    prev.slots, prev.next_tokens, prev.alive, prev.remaining,
+                    prev.eos_ids,
+                )
             self.cache.swap(h, c)
             self._draft_h, self._draft_c = dh, dc
         return dataclasses.replace(
@@ -1461,7 +1480,8 @@ class ServeEngine:
         """Block until the window's tokens are on host; returns ``[n, K]``
         int32 (padding rows stripped; ``PAD_TOKEN`` after a row's EOS or
         budget end). The ONLY sync point of the windowed decode path."""
-        return np.asarray(jax.device_get(win.tokens))[: win.n]
+        with _fetch_span(win):
+            return np.asarray(jax.device_get(win.tokens))[: win.n]
 
     @staticmethod
     def fetch_window_summary(
@@ -1473,8 +1493,9 @@ class ServeEngine:
         of re-deriving liveness host-side per token — same single sync
         point as :meth:`fetch_window` (graftlint host-sync allow-list),
         one ``device_get`` for all three arrays."""
-        toks, rem, alive = jax.device_get(
-            (win.tokens, win.remaining, win.alive))
+        with _fetch_span(win):
+            toks, rem, alive = jax.device_get(
+                (win.tokens, win.remaining, win.alive))
         n = win.n
         return (np.asarray(toks)[:n], np.asarray(rem)[:n],
                 np.asarray(alive)[:n])

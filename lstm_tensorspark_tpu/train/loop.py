@@ -27,6 +27,7 @@ import optax
 from .. import obs
 from ..ops import parallel_scan as _pscan
 from ..resilience import faults as _faults
+from ..utils.tracing import span
 
 
 class AnomalousTrainingError(RuntimeError):
@@ -443,6 +444,17 @@ def train_loop(
     return state
 
 
+def _fed(batches: Iterable):
+    """``batches``, each pull from the feed under a ``train:feed`` span."""
+    feed, end = iter(batches), object()
+    while True:
+        with span("train:feed"):
+            batch = next(feed, end)
+        if batch is end:
+            return
+        yield batch
+
+
 def _run_train_loop(
     state, train_step, batches, *, num_steps, log_every, logger, eval_fn,
     eval_every, checkpoint_fn, checkpoint_every, tokens_per_batch,
@@ -456,18 +468,21 @@ def _run_train_loop(
     anomalous_total = 0
     anomalous_consec = 0
     best_val = best_init
-    for i, batch in enumerate(batches):
+    for i, batch in enumerate(_fed(batches)):
         if num_steps is not None and i >= num_steps:
             break
         step = i + 1
-        if fused_eval:
-            do_eval = bool(eval_every) and step % eval_every == 0
-            state, metrics = train_step(state, batch, np.bool_(do_eval))
-        else:
-            state, metrics = train_step(state, batch)
+        with span("train:dispatch", steps=steps_per_call):
+            if fused_eval:
+                do_eval = bool(eval_every) and step % eval_every == 0
+                state, metrics = train_step(state, batch, np.bool_(do_eval))
+            else:
+                state, metrics = train_step(state, batch)
         last_metrics = metrics
         if anomaly_limit and "anomalous" in metrics:
-            bad = int(float(metrics["anomalous"]))  # sync point (documented)
+            with span("train:sync"):
+                # sync point (documented)
+                bad = int(float(metrics["anomalous"]))
             anomalous_total += bad
             if bad:
                 _m_anomalous.inc(bad)
@@ -484,54 +499,57 @@ def _run_train_loop(
                 raise AnomalousTrainingError(
                     anomalous_consec, anomalous_total, int(state.step))
         if log_every and step % log_every == 0:
-            loss = float(metrics["loss"])  # sync point
-            now = time.perf_counter()
-            dt = now - window_start
-            window_start = now
-            window_steps = log_every * steps_per_call
-            _m_step.observe(dt / window_steps)
-            _m_steps.inc(window_steps)
-            record = {
-                "step": int(state.step),
-                "loss": loss,
-                "grad_norm": float(metrics["grad_norm"]),
-                "steps_per_sec": log_every * steps_per_call / dt,
-            }
-            if anomaly_limit:
-                # cumulative (exact: every step was fetched above)
-                if anomalous_total:
-                    record["anomalous_steps"] = anomalous_total
-            elif "anomalous" in metrics:
-                # watchdog off: report the logged step/window's own count
-                # (no per-step fetch, so no cumulative claim)
-                bad = float(metrics["anomalous"])
-                if bad:
-                    record["anomalous"] = bad
-                    _m_anomalous.inc(bad)
-            if tokens_per_batch:
-                tps = tokens_per_batch * log_every * steps_per_call / dt
-                record["tokens_per_sec"] = tps
-                _m_tps.set(tps)
-                if flops_per_token:
-                    # live MFU: achieved model TFLOP/s (train = 3x forward
-                    # matmul accounting, utils/flops.py). ``peak_tflops``
-                    # is the AGGREGATE peak of every participating chip —
-                    # tokens_per_sec is the global rate, so dividing by one
-                    # chip's peak would overstate MFU by the device count.
-                    record["model_tflops"] = tps * flops_per_token / 1e12
-                    if peak_tflops:
-                        record["mfu"] = round(
-                            record["model_tflops"] / peak_tflops, 4
-                        )
-            if logger is not None:
-                logger.log(record)
+            with span("train:sync") as sync:
+                loss = float(metrics["loss"])  # sync point
+            with span("train:log"):
+                now = sync.end
+                dt = now - window_start
+                window_start = now
+                window_steps = log_every * steps_per_call
+                _m_step.observe(dt / window_steps)
+                _m_steps.inc(window_steps)
+                record = {
+                    "step": int(state.step),
+                    "loss": loss,
+                    "grad_norm": float(metrics["grad_norm"]),
+                    "steps_per_sec": log_every * steps_per_call / dt,
+                }
+                if anomaly_limit:
+                    # cumulative (exact: every step was fetched above)
+                    if anomalous_total:
+                        record["anomalous_steps"] = anomalous_total
+                elif "anomalous" in metrics:
+                    # watchdog off: report the logged step/window's own count
+                    # (no per-step fetch, so no cumulative claim)
+                    bad = float(metrics["anomalous"])
+                    if bad:
+                        record["anomalous"] = bad
+                        _m_anomalous.inc(bad)
+                if tokens_per_batch:
+                    tps = tokens_per_batch * log_every * steps_per_call / dt
+                    record["tokens_per_sec"] = tps
+                    _m_tps.set(tps)
+                    if flops_per_token:
+                        # live MFU: achieved model TFLOP/s (train = 3x forward
+                        # matmul accounting, utils/flops.py). ``peak_tflops``
+                        # is the AGGREGATE peak of every participating chip —
+                        # tokens_per_sec is the global rate, so dividing by one
+                        # chip's peak would overstate MFU by the device count.
+                        record["model_tflops"] = tps * flops_per_token / 1e12
+                        if peak_tflops:
+                            record["mfu"] = round(
+                                record["model_tflops"] / peak_tflops, 4
+                            )
+                if logger is not None:
+                    logger.log(record)
         if eval_every and step % eval_every == 0:
-            if fused_eval is not None:
-                ev = fused_eval(metrics)
-            elif eval_fn is not None:
-                ev = eval_fn(state.params)
-            else:
-                ev = None
+            with span("train:eval"):
+                if fused_eval is not None:
+                    ev = fused_eval(metrics)
+                elif eval_fn is not None:
+                    ev = eval_fn(state.params)
+                else:
+                    ev = None
             if ev is not None and logger is not None:
                 logger.log({"step": int(state.step), **ev})
             if best_fn is not None and ev is not None and best_metric in ev:
@@ -553,7 +571,8 @@ def _run_train_loop(
                                     "note": f"new best {best_metric}",
                                     best_metric: v})
         if checkpoint_fn is not None and checkpoint_every and step % checkpoint_every == 0:
-            checkpoint_fn(state)
+            with span("train:checkpoint"):
+                checkpoint_fn(state)
     if last_metrics is not None:
         jax.block_until_ready(last_metrics["loss"])
     return state

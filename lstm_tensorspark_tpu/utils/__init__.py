@@ -1,4 +1,4 @@
-from .tracing import Tracer, get_tracer, set_tracer, span, instant
+from .tracing import Tracer, get_tracer, set_tracer, span
 from .flops import (
     PEAK_BF16_TFLOPS,
     TRAIN_FLOPS_MULTIPLIER,
@@ -8,7 +8,7 @@ from .flops import (
 )
 
 __all__ = [
-    "Tracer", "get_tracer", "set_tracer", "span", "instant",
+    "Tracer", "get_tracer", "set_tracer", "span",
     "PEAK_BF16_TFLOPS", "TRAIN_FLOPS_MULTIPLIER",
     "classifier_fwd_flops_per_token", "lm_fwd_flops_per_token",
     "seq2seq_fwd_flops_per_seq",

@@ -1,19 +1,33 @@
-"""Host-side structured tracing: named spans → Chrome trace JSON.
+"""The program's one span API: ``span(name, **args)``, two sinks.
+
+Every ``with span("serve:admit"):`` enters a ``jax.profiler.TraceAnnotation``
+of the same name and arguments, so under a profiler session (``--profile-dir``,
+the benchmark's traced run) the span lands on the host plane of the
+profiler's own trace, in the same file and on the same time axis as the
+device's operations: an idle gap of the chip can be put down to what the
+host was doing in it. The axis is shared, the clocks are two: on the v5e
+the chip's events sat 0.3-2.4 ms EARLY against the host's, constant within
+a trace and different in each (PERF.md section 6, PR 26). Before reading a
+gap to the millisecond, find that trace's offset from the runtime's own
+``DoEnqueueProgram`` / ``CompleteCallbacks`` host events, which carry the
+``run_id`` of the chip's ``XLA Modules`` event (docs/OPERATIONS.md;
+``benchmark/program_spans.clock_offset`` does it). When a :class:`Tracer`
+is installed (``--trace``) the same call also
+records a Chrome trace-event (load the file in chrome://tracing or
+https://ui.perfetto.dev). With neither it costs the annotation's own no-op,
+about a microsecond. There is no switch: a span is on when a sink is.
 
 Reference parity: SURVEY.md §5 "Tracing / profiling" — the reference's only
 observability was the Spark web UI's per-stage/task timing, external to the
-repo. This module supplies the in-framework equivalent for the host side of
-a run (data load, compile, train loop, eval, checkpoint, generation, and —
-via serve/batcher.py — per-request admit→queue→prefill→decode→readback
-timelines), saved in the Chrome trace-event format (load in
-chrome://tracing or https://ui.perfetto.dev). Device-side profiling is
-separate and richer: ``--profile-dir`` streams XLA/TPU traces via
-``jax.profiler`` (see cli.py).
+repo.
 
-Zero overhead when disabled: the module-level ``span``/``instant`` helpers
-no-op unless a Tracer is installed with ``set_tracer``.
+Names are ``<layer>:<what>``, fixed strings; ids and sizes go in ``args``
+(ints and short strings that are already at hand). A span keeps its two
+``time.perf_counter()`` stamps (``start``, ``end``): a histogram or a
+request's phase that covers the same lines reads them from the span, so the
+two cannot drift apart.
 
-Bounded memory when enabled: events live in a RING buffer
+The Tracer's memory is bounded: events live in a RING buffer
 (``max_events``, default 200k) — a long serving run keeps the newest
 events instead of growing without limit; ``dropped`` counts what the ring
 displaced, and ``save`` records it in the trace.
@@ -29,12 +43,13 @@ how cross-iteration request phases are traced after the fact.
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import threading
 import time
 from collections import deque
+
+from jax.profiler import TraceAnnotation
 
 
 class Tracer:
@@ -50,9 +65,6 @@ class Tracer:
         self._t0 = time.perf_counter()
         self._tid_names: dict[int, str] = {}
         self.dropped = 0
-
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
 
     def _record(self, ev: dict) -> None:
         tid = ev["tid"]
@@ -72,38 +84,17 @@ class Tracer:
         with self._lock:
             self._tid_names[int(tid)] = name
 
-    @contextlib.contextmanager
-    def span(self, name: str, **args):
-        """Complete-event span ("ph": "X") around the with-block."""
-        ts = self._now_us()
-        try:
-            yield self
-        finally:
-            dur = self._now_us() - ts
-            ev = {"name": name, "ph": "X", "ts": ts, "dur": dur,
-                  "pid": os.getpid(), "tid": threading.get_ident()}
-            if args:
-                ev["args"] = args
-            self._record(ev)
-
     def complete(self, name: str, start_s: float, end_s: float, *,
                  tid: int | None = None, **args) -> None:
-        """Record a complete event from explicit ``time.perf_counter()``
-        stamps (taken while the phase ran, recorded later) — the serve
-        batcher emits each finished request's phase timeline this way,
-        one synthetic ``tid`` row per request."""
+        """Record a complete event ("ph": "X") from explicit
+        ``time.perf_counter()`` stamps (taken while the phase ran, recorded
+        later) — the serve batcher emits each finished request's phase
+        timeline this way, one synthetic ``tid`` row per request."""
         ev = {"name": name, "ph": "X",
               "ts": (start_s - self._t0) * 1e6,
               "dur": max((end_s - start_s) * 1e6, 0.0),
               "pid": os.getpid(),
               "tid": threading.get_ident() if tid is None else int(tid)}
-        if args:
-            ev["args"] = args
-        self._record(ev)
-
-    def instant(self, name: str, **args) -> None:
-        ev = {"name": name, "ph": "i", "ts": self._now_us(), "s": "g",
-              "pid": os.getpid(), "tid": threading.get_ident()}
         if args:
             ev["args"] = args
         self._record(ev)
@@ -147,18 +138,23 @@ def get_tracer() -> Tracer | None:
     return _tracer
 
 
-@contextlib.contextmanager
-def span(name: str, **args):
-    """Module-level span: records on the installed tracer, no-op otherwise."""
-    t = _tracer
-    if t is None:
-        yield None
-    else:
-        with t.span(name, **args):
-            yield t
+class span:
+    """``with span("engine:launch", program="window_fn"):`` — see the module
+    docstring. ``start``/``end`` are the block's ``perf_counter`` stamps."""
 
+    __slots__ = ("name", "args", "start", "end", "_annotation")
 
-def instant(name: str, **args) -> None:
-    t = _tracer
-    if t is not None:
-        t.instant(name, **args)
+    def __init__(self, name: str, **args) -> None:
+        self.name, self.args = name, args
+        self._annotation = TraceAnnotation(name, **args)
+
+    def __enter__(self) -> "span":
+        self._annotation.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        if _tracer is not None:
+            _tracer.complete(self.name, self.start, self.end, **self.args)

@@ -8,6 +8,7 @@ default) so every assertion reads exactly this stack's telemetry.
 
 import json
 import threading
+import time
 import urllib.request
 
 import jax
@@ -210,3 +211,144 @@ def test_registry_counters_track_stats_counters():
     assert fam.labels(event="insert").value == st["inserts"] >= 1
     swaps = reg.counter("serve_state_cache_swaps_total").value
     assert swaps == engine.cache.stats()["generation"] > 0
+
+
+# ---- the program's spans on the profiler's host plane ----------------------
+
+
+@pytest.fixture(scope="module")
+def served_events(record_spans):
+    """A few concurrent requests through `ServeServer.generate` on a warmed
+    stack of its own, under a profiler session with the Python tracer off:
+    the trace's host events, and the batcher's counters over the session."""
+    server = _build(MetricsRegistry())
+    with server:
+        server.warmup(prompt_lens=(4, 8))
+        batcher = server.batcher
+        before = batcher.stats()
+
+        def serve():
+            threads = [threading.Thread(target=server.generate, args=(p,),
+                                        kwargs={"max_new_tokens": n})
+                       for p, n in (([1, 2, 3], 12), ([4, 5], 9),
+                                    ([6, 7, 8, 9, 10], 7))]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+            server.generate([3, 1, 4], max_new_tokens=11)  # alone: windows
+            # the reply is set free inside the iteration that delivered it:
+            # let the scheduler close that iteration before the session ends
+            replied = time.monotonic()
+            while batcher.last_heartbeat <= replied:
+                time.sleep(0.001)
+
+        events = record_spans(serve)
+        after = batcher.stats()
+    return events, before, after
+
+
+def _spans(events, name):
+    return [e for e in events if e["name"] == name]
+
+
+def _scheduler_line(events):
+    """The thread of THIS stack's scheduler: the only one that dispatched
+    (the module's shared `stack`, when a worker holds it, idles on a line
+    of its own: iterations, waits and admits, and nothing else)."""
+    (line,) = {e["line"] for e in _spans(events, "serve:prefill_dispatch")}
+    return line
+
+
+@pytest.mark.parametrize("name", [
+    "serve:iteration", "serve:wait_for_work", "serve:admit",
+    "serve:prefill_dispatch", "serve:decode_dispatch", "serve:deliver",
+    "engine:pack", "engine:launch", "engine:fetch"])
+def test_scheduler_thread_leaves_span(served_events, name):
+    events, _, _ = served_events
+    scheduler = _scheduler_line(events)
+    assert [e for e in _spans(events, name) if e["line"] == scheduler], name
+    if name not in ("serve:iteration", "serve:wait_for_work", "serve:admit"):
+        assert {e["line"] for e in _spans(events, name)} == {scheduler}
+
+
+def test_client_threads_open_no_span(served_events):
+    """Every span of the program lies on a scheduler's line (one that holds
+    `serve:iteration`): the four clients' threads, which only hand a
+    request over and wait, leave none."""
+    events, before, after = served_events
+    assert after["submitted"] - before["submitted"] == 4
+    schedulers = {e["line"] for e in _spans(events, "serve:iteration")}
+    assert _scheduler_line(events) in schedulers
+    assert {e["line"] for e in events if e["args"] is not None} <= schedulers
+
+
+def test_dispatch_spans_agree_with_the_batchers_counters(served_events):
+    events, before, after = served_events
+    decodes = [e["args"] for e in _spans(events, "serve:decode_dispatch")]
+    # every window the batcher counted is a span of that k (the per-token
+    # path, k=1 and not pipelined, has no counter of its own)
+    for k in {a["k"] for a in decodes} | set(after["windows_dispatched"]):
+        counted = (after["windows_dispatched"].get(k, 0)
+                   - before["windows_dispatched"].get(k, 0))
+        windows = [a for a in decodes
+                   if a["k"] == k and (k > 1 or a["pipelined"])]
+        assert len(windows) == counted, k
+    assert sum(a["pipelined"] for a in decodes) == (
+        after["windows_pipelined"] - before["windows_pipelined"])
+    # rows are live sessions: every generated token is one row of one step
+    # of a decode dispatch, or a final prefill's first token
+    prefills = [e["args"] for e in _spans(events, "serve:prefill_dispatch")]
+    first_tokens = sum(a["rows"] for a in prefills if a["final"])
+    generated = after["tokens_generated"] - before["tokens_generated"]
+    assert generated == 12 + 9 + 7 + 11
+    assert sum(a["rows"] * a["k"] for a in decodes) + first_tokens == generated
+    assert first_tokens == 4
+    # a deliver follows every fetch (a window's, a final prefill's first
+    # token) and every prefill chunk
+    chunks = sum(1 for a in prefills if not a["final"])
+    assert len(_spans(events, "serve:deliver")) == (
+        len(_spans(events, "engine:fetch")) + chunks)
+
+
+def test_spans_nest_as_the_work_does(served_events):
+    events, _, _ = served_events
+    # the session starts and stops while the schedulers loop: a span whose
+    # iteration was open at either end has no recorded parent, so look
+    # between each line's first and last whole iteration
+    whole = {}
+    for e in _spans(events, "serve:iteration"):
+        lo, hi = whole.get(e["line"], (e["start"], e["end"]))
+        whole[e["line"]] = (min(lo, e["start"]), max(hi, e["end"]))
+    events = [e for e in events if e["line"] in whole
+              and whole[e["line"]][0] <= e["start"]
+              and e["end"] <= whole[e["line"]][1]]
+
+    def inside(inner, outer):
+        return [i for i in _spans(events, inner) if any(
+            o["line"] == i["line"] and o["start"] <= i["start"]
+            and i["end"] <= o["end"] for o in _spans(events, outer))]
+
+    launches = _spans(events, "engine:launch")
+    programs = {e["args"]["program"] for e in launches}
+    assert {"prefill_fn", "window_fn"} <= programs <= {
+        "prefill_fn", "window_fn", "decode_fn"}
+    in_decode = inside("engine:launch", "serve:decode_dispatch")
+    in_prefill = inside("engine:launch", "serve:prefill_dispatch")
+    assert {e["args"]["program"] for e in in_prefill} == {"prefill_fn"}
+    assert {e["args"]["program"] for e in in_decode} == programs - {"prefill_fn"}
+    assert len(in_decode) + len(in_prefill) == len(launches)
+    # every dispatch, admit, deliver and engine span lies in an iteration;
+    # the idle wait lies between iterations
+    for name in ("serve:admit", "serve:prefill_dispatch", "serve:deliver",
+                 "serve:decode_dispatch", "engine:pack", "engine:fetch"):
+        assert len(inside(name, "serve:iteration")) == len(_spans(events, name)), name
+    assert not inside("serve:wait_for_work", "serve:iteration")
+    # the window's fetch names the program it waits for, its rows and k
+    window_fetches = [e["args"] for e in _spans(events, "engine:fetch")
+                      if e["args"]["program"] == "window_fn"]
+    window_launches = [e for e in launches if e["args"]["program"] == "window_fn"]
+    assert len(window_fetches) == len(window_launches)
+    assert all(a["rows"] >= 1 and a["k"] >= 1 for a in window_fetches)
+
+
