@@ -17,18 +17,21 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from ..ops.embedding import embed_lookup, selected_logits
+from ..ops.embedding import embed_lookup
 from ..ops.lstm_cell import LSTMParams, init_lstm_params, zero_carry
 from ..ops.scan import stacked_lstm_scan
+from ..ops.xent import chunked_xent_mean, dense_xent_mean
 
-# Above this vocab size lm_loss switches to the vocab-chunked cross-entropy
-# (ops/xent.py), which bounds loss memory at O(N·Vc) instead of O(N·V).
+# Above this vocab size lm_loss switches from the dense head + loss
+# (ops/xent.py dense_xent_mean: the whole [N,V] logits array in HBM) to the
+# vocab-chunked cross-entropy (chunked_xent_mean), which bounds loss memory
+# at O(N·Vc) instead of O(N·V).
 # MEASURED on v5e: at V=33k/50k the chunked path is 16-18% SLOWER than the
-# plain logsumexp loss (XLA already fuses the head matmul + reduction well;
-# the scan serializes chunk matmuls and doubles the exp work), so the
-# threshold sits ABOVE those configs — the chunked path is a memory
-# capability for vocabularies whose [B,T,V] logits would not fit HBM,
-# not a throughput optimisation.
+# dense one (XLA already fuses the head matmul + reduction well; the scan
+# serializes chunk matmuls and doubles the exp work), so the threshold sits
+# ABOVE those configs — the chunked path is a memory capability for
+# vocabularies whose [N,V] logits would not fit HBM, not a throughput
+# optimisation.
 _CHUNKED_XENT_MIN_V = 2**17
 
 
@@ -51,12 +54,17 @@ class LMConfig:
     # plan fits and T is long enough). Library default stays sequential;
     # `cli train --bptt-mode` defaults to auto.
     bptt: str = "sequential"
-    # dtype of the materialized [B,T,V] logits array. At the word-LM vocab
-    # sizes every pass over that array is an HBM-bandwidth cost (fwd write,
-    # logsumexp read, dlogits write + three backward reads — ~300 MB each
-    # at V=33k); "bfloat16" halves all of them (+25% measured on config 3)
-    # while the logsumexp/NLL itself still runs in f32 over the upcast
-    # values. Default float32 — opt-in numerics trade. No effect on the
+    # dtype of the materialized [N,V] logits array (N = B·T). At the
+    # word-LM vocab sizes every pass over that array is an HBM-bandwidth
+    # cost — the head matmul writes it, the logsumexp reads it, and the
+    # backward (ops/xent.py dense_xent_mean) reads it three more times: one
+    # pass for the bias gradient and once as the operand of each backward
+    # matmul, which form dlogits on the fly — no dlogits array is stored,
+    # and none is copied into a second layout (819 MB each at config 5).
+    # "bfloat16" halves all of them (+25% measured on config 3 with the
+    # autodiff backward) while the logsumexp/NLL itself still runs in f32
+    # over the upcast values; it is also the dtype dlogits is rounded to.
+    # Default float32 — opt-in numerics trade. No effect on the
     # chunked-xent path (V >= _CHUNKED_XENT_MIN_V), which never
     # materializes the array this flag exists to shrink.
     logits_dtype: str = "float32"
@@ -185,42 +193,24 @@ def lm_loss(
     batch: dict with "inputs" [B,T] and "targets" [B,T] int32.
     Returns (loss, aux) with aux = {"loss", "tokens", "carries"}.
     """
+    finals, ys = lm_backbone(
+        params, batch["inputs"], cfg, carries=carries,
+        dropout_rng=dropout_rng, deterministic=deterministic,
+    )
+    kernel, bias = _head_kernel(params, cfg)
     if cfg.vocab_size >= _CHUNKED_XENT_MIN_V:
         # big-vocab path: vocab-chunked cross-entropy (ops/xent.py) — the
-        # [B,T,V] logits/dlogits arrays (~300-400 MB at V=33k/50k) never
-        # exist in HBM; head matmul recomputed chunk-wise in the backward
-        finals, ys = lm_backbone(
-            params, batch["inputs"], cfg, carries=carries,
-            dropout_rng=dropout_rng, deterministic=deterministic,
-        )
-        kernel, bias = _head_kernel(params, cfg)
-        from ..ops.xent import chunked_xent_mean
-
+        # [B,T,V] logits/dlogits arrays never exist in HBM; head matmul
+        # recomputed chunk-wise in the backward
         loss = chunked_xent_mean(ys.astype(jnp.float32), kernel, bias,
                                  batch["targets"])
-        nll_size = batch["targets"].size
     else:
-        logits, finals = lm_forward(
-            params,
-            batch["inputs"],
-            cfg,
-            carries=carries,
-            dropout_rng=dropout_rng,
-            deterministic=deterministic,
-        )
-        # nll via logsumexp, NOT log_softmax: identical math
-        # (nll = lse - z_t) without the full [B,T,V] log-prob array.
-        # selected_logits: one-hot multiply-reduce at small V (bit-equal to
-        # the gather — the sum has one nonzero term — but fused and
-        # scatter-free in the backward; 43 us/step at the config-1 shape)
-        logits_f = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits_f, axis=-1)
-        tgt = selected_logits(logits_f, batch["targets"])
-        loss = jnp.mean(lse - tgt)
-        nll_size = batch["targets"].size
+        # dense path: lm_forward's head, then nll = lse - z_t, with a
+        # backward that needs dlogits in one layout only (ops/xent.py)
+        loss = dense_xent_mean(ys, kernel, bias, batch["targets"], cfg.ldtype)
     aux = {
         "loss": loss,
-        "tokens": jnp.array(nll_size, jnp.float32),
+        "tokens": jnp.array(batch["targets"].size, jnp.float32),
         "carries": finals,
     }
     return loss, aux

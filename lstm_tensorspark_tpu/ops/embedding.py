@@ -97,9 +97,11 @@ def selected_logits(logits: jax.Array, targets: jax.Array) -> jax.Array:
     into the surrounding loss reduction at ANY vocab size and keeps the
     backward elementwise — measured at V=33k it is neutral with f32
     logits and +20% with bf16 logits, where the gather's backward scatter
-    forces an f32 dlogits materialization. On CPU the fused one-hot pass
-    costs real work at large V while the gather is a cheap row lookup, so
-    large-V CPU keeps the gather (identical values either way)."""
+    forces an f32 dlogits materialization (the dense LM head's backward
+    is hand-written since, ops/xent.py: there only the forward half
+    applies). On CPU the fused one-hot pass costs real work at large V
+    while the gather is a cheap row lookup, so large-V CPU keeps the
+    gather (identical values either way)."""
     V = logits.shape[-1]
     if V <= _SELECT_MAX_V or jax.default_backend() == "tpu":
         oh = jax.nn.one_hot(targets, V, dtype=logits.dtype)

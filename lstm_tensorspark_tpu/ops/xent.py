@@ -1,11 +1,23 @@
-"""Vocab-chunked cross-entropy: the [N, V] logits never exist in HBM.
+"""The LM head and its loss, two ways: dense and vocab-chunked.
 
-Motivation (BASELINE.md configs 3/5): at V = 33k/50k the LM softmax head's
-logits array is 300–400 MB; a train step writes it (head matmul), reads it
-(logsumexp + target gather), writes the same-sized dlogits in the backward
-and reads it twice more (dW and dys matmuls) — ~1.5–2 GB of HBM traffic per
-step that dwarfs the head's actual FLOPs. This module computes the exact
-same mean-NLL with the vocabulary processed in `chunk`-column tiles:
+Both compute the mean next-token NLL ``mean(logsumexp(ys·W + b) - z_target)``
+(the reference's plain softmax cross-entropy, SURVEY.md §3.2
+``xent(softmax(h·W_out), y)``) with a hand-written backward, and differ in
+what they keep in HBM. Exactness tests: tests/test_xent.py.
+
+``dense_xent_mean`` — the path every configuration below
+`models/lstm_lm._CHUNKED_XENT_MIN_V` runs (configs 1, 3 and 5). The
+``[N, V]`` logits array exists (819 MB in bf16 at config 5's N = 8192,
+V = 50,000). A train step writes it once (head matmul) and reads it four
+times: logsumexp + target logit, the bias gradient, and once inside each of
+the two backward matmuls, which form their dlogits operand from
+``(logits, lse, targets)`` as they read — XLA fuses that element-wise
+producer into the dot, so no dlogits array is stored and none is copied
+into a second layout (the autodiff backward wrote dlogits, and XLA then
+wrote it again in the layout the other matmul preferred: PERF.md §6, PR 27).
+
+``chunked_xent_mean`` — above that threshold: the ``[N, V]`` logits never
+exist in HBM. The vocabulary is processed in ``chunk``-column tiles:
 
 - forward: one pass of ONLINE logsumexp (flash-attention-style running
   (m, s) accumulators) + in-chunk target-logit gather — the only [N, Vc]
@@ -14,15 +26,12 @@ same mean-NLL with the vocabulary processed in `chunk`-column tiles:
   tile, and immediately contract it into dys / dW / db accumulators.
 
 The trade is the standard recompute-vs-traffic one: head matmul FLOPs ×2
-(the backward re-projects each chunk) against deleting ~5 full-logits HBM
-round-trips. XLA's job remains the matmuls; this is pure jax-level
-restructuring (lax.scan over weight column tiles), no Pallas needed —
-the tiles are large MXU-friendly matmuls already.
-
-Reference parity note: the reference computes a plain softmax cross-entropy
-(SURVEY.md §3.2 ``xent(softmax(h·W_out), y)``); this is the same math to
-float rounding (exactness tests in tests/test_xent.py), restructured for
-HBM economics.
+(the backward re-projects each chunk) against the passes over the logits.
+Measured on v5e at V=33k/50k it is 16-18% SLOWER than the dense path, so
+it is a memory capability for vocabularies whose logits would not fit, not
+a throughput optimisation. XLA's job remains the matmuls; this is pure
+jax-level restructuring (lax.scan over weight column tiles), no Pallas
+needed — the tiles are large MXU-friendly matmuls already.
 """
 
 from __future__ import annotations
@@ -33,6 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from .embedding import selected_logits
 
 
 def _pad_vocab(kernel, bias, chunk):
@@ -157,3 +168,91 @@ def _xent_bwd(chunk, residuals, g):
 
 
 chunked_xent_mean.defvjp(_xent_fwd, _xent_bwd)
+
+
+# ---- the dense head + loss: logits in HBM, dlogits in one layout ---------
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def dense_xent_mean(ys, kernel, bias, targets, logits_dtype):
+    """Mean next-token NLL with the whole ``[N, V]`` logits array in HBM
+    (the fast path below `_CHUNKED_XENT_MIN_V`), and a hand-written
+    backward in which dlogits is ONE ``[N, V]`` expression that both
+    backward matmuls take in the logits' own layout.
+
+    ``ys`` [B, T, H] (float), ``kernel`` [H, V], ``bias`` [V], ``targets``
+    [B, T] int. ``logits_dtype`` is the dtype the logits are stored in and
+    dlogits is rounded to (`LMConfig.ldtype`); logsumexp and the loss are
+    float32 over the upcast values. Returns a scalar.
+
+    Why not autodiff: it hands the two transposed matmuls one ``[B, T, V]``
+    cotangent array, XLA's layout assignment gives each the layout it
+    likes best, and the 819 MB array (config 5) is written a second time
+    by a relayout ``copy`` that computes nothing — 2.5 ms of a 43.9 ms
+    step on v5e. Here N = T·B is the flattened TIME-MAJOR row axis (the
+    recurrence kernels' own order, so flattening ``ys`` moves no data; the
+    int targets are transposed instead), dys contracts dlogits' V axis and
+    dW its N axis, and dlogits is element-wise in (logits, lse, targets),
+    so XLA fuses it into the operand of each matmul: neither the copy nor
+    a dlogits array is left in the step (40.0 ms; PERF.md §6, PR 27;
+    tests_tpu/test_head_layout_tpu.py holds the compiled step to it).
+    """
+    loss, _ = _dense_fwd(ys, kernel, bias, targets, logits_dtype)
+    return loss
+
+
+def _time_major_rows(ys, targets):
+    """``ys`` [B, T, H] → [T·B, H] and ``targets`` [B, T] → [T·B], rows in
+    time-major order."""
+    B, T, H = ys.shape
+    return jnp.swapaxes(ys, 0, 1).reshape(T * B, H), targets.T.reshape(T * B)
+
+
+def _dense_fwd(ys, kernel, bias, targets, logits_dtype):
+    ys2d, tgt = _time_major_rows(ys, targets)
+    logits = (
+        jnp.dot(ys2d.astype(kernel.dtype), kernel,
+                preferred_element_type=logits_dtype)
+        + bias.astype(logits_dtype)
+    )
+    # nll via logsumexp, NOT log_softmax: identical math (nll = lse - z_t)
+    # without an [N, V] log-prob array
+    logits_f = logits.astype(jnp.float32)
+    lse = jax.nn.logsumexp(logits_f, axis=-1)
+    nll = lse - selected_logits(logits_f, tgt)
+    # the mean sums in [B, T] order, so the value is bit-for-bit the plain
+    # formula's (32 KB transposed, against 819 MB left alone)
+    loss = jnp.mean(nll.reshape(targets.shape[::-1]).T)
+    return loss, (logits, lse, ys, kernel, bias, targets)
+
+
+def _dense_bwd(logits_dtype, residuals, g):
+    logits, lse, ys, kernel, bias, targets = residuals
+    B, T, H = ys.shape
+    N, V = logits.shape
+    ys2d, tgt = _time_major_rows(ys, targets)
+    # dlogits = (softmax - onehot) * g/N, element-wise over the logits and
+    # rounded once to the stored dtype (what autodiff handed the matmuls
+    # too); db is summed in float32, before the rounding
+    p = jnp.exp(logits.astype(jnp.float32) - lse[:, None])
+    onehot = lax.broadcasted_iota(tgt.dtype, (N, V), 1) == tgt[:, None]
+    dlog = (p - onehot.astype(jnp.float32)) * (g / N).astype(jnp.float32)
+    dbias = jnp.sum(dlog, axis=0)
+    dlog = dlog.astype(logits_dtype)
+    # both products take dlogits as [N, V]: dys contracts V, dW contracts
+    # N. Output dtypes as autodiff's transposes had them
+    # (preferred_element_type rides along)
+    dys = lax.dot_general(dlog, kernel, (((1,), (1,)), ((), ())),
+                          preferred_element_type=logits_dtype)
+    dkernel = lax.dot_general(ys2d.astype(kernel.dtype), dlog,
+                              (((0,), (0,)), ((), ())),
+                              preferred_element_type=logits_dtype)
+    return (
+        jnp.swapaxes(dys.reshape(T, B, H), 0, 1).astype(ys.dtype),
+        dkernel.astype(kernel.dtype),
+        dbias.astype(bias.dtype),
+        np.zeros(targets.shape, dtype=jax.dtypes.float0),  # int targets
+    )
+
+
+dense_xent_mean.defvjp(_dense_fwd, _dense_bwd)

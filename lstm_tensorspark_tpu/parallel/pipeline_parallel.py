@@ -39,9 +39,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from ..models.lstm_lm import LMConfig
-from ..ops.embedding import embed_lookup, selected_logits
+from ..ops.embedding import embed_lookup
 from ..ops.lstm_cell import LSTMParams
 from ..ops.scan import auto_lstm_scan, lstm_scan
+from ..ops.xent import dense_xent_mean
 from ..train.loop import TrainState, step_body
 
 
@@ -264,17 +265,9 @@ def pp_lm_loss(
         return ys  # [b, T, Dmax]
 
     def mb_loss(ys, tgt):
-        logits = (
-            jnp.dot(ys[..., :H].astype(kernel.dtype), kernel,
-                    preferred_element_type=cfg.ldtype)
-            + head["bias"].astype(cfg.ldtype)
-        )
-        # logsumexp form — keep identical to lm_loss (parity tests compare
-        # the two bit-for-bit) and skip the [b,T,V] log-prob array
-        lg = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(lg, axis=-1)
-        t_ = selected_logits(lg, tgt)
-        return jnp.mean(lse - t_)
+        # lm_loss's dense head + loss (ops/xent.py) on one microbatch
+        return dense_xent_mean(ys[..., :H], kernel, head["bias"], tgt,
+                               cfg.ldtype)
 
     x_in = jnp.zeros((b, T, Dmax), jnp.float32)
     loss_acc = jnp.zeros((), jnp.float32)

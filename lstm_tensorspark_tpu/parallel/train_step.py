@@ -23,7 +23,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from ..models.lstm_lm import LMConfig
-from ..ops.embedding import embed_lookup, selected_logits
+from ..ops.embedding import embed_lookup
+from ..ops.xent import dense_xent_mean
 from ..train.loop import TrainState, step_body
 from .sequence_parallel import sp_lstm_scan
 from .tensor_parallel import lm_param_specs
@@ -72,17 +73,10 @@ def sp_lm_loss(params, batch, cfg: LMConfig, *, seq_axis: str = "seq",
             )
     head = params["head"]
     kernel = params["embedding"].T if cfg.tie_embeddings else head["kernel"]
-    logits = (
-        jnp.dot(xs.astype(kernel.dtype), kernel,
-                preferred_element_type=cfg.ldtype)
-        + head["bias"].astype(cfg.ldtype)
-    )
-    # logsumexp form — keep identical to lm_loss (parity tests compare
-    # the two bit-for-bit) and skip the [b,C,V] log-prob array
-    lg = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(lg, axis=-1)
-    tgt = selected_logits(lg, batch["targets"])
-    loss = jnp.mean(lse - tgt)  # local mean; caller pmeans over data+seq
+    # lm_loss's dense head + loss (ops/xent.py), on this shard's rows:
+    # local mean; caller pmeans over data+seq
+    loss = dense_xent_mean(xs, kernel, head["bias"], batch["targets"],
+                           cfg.ldtype)
     return loss, {"loss": loss}
 
 
