@@ -1316,6 +1316,41 @@ def build_serve_parser() -> argparse.ArgumentParser:
                    help="restore trained params from a training run's "
                         "checkpoints (the model flags must match it; its "
                         "optimizer does not matter); random init otherwise")
+    # --- the decoder family (models/decoder.py, serve/decoder_engine.py) ---
+    p.add_argument("--model-file", type=str, default=None,
+                   help="serve a DECODER (latent attention over a paged "
+                        "latent cache, expert layers) described by this "
+                        "JSON file: the published config.json keys, the "
+                        "share this chip holds, assumed.weights_seed (e.g. "
+                        "benchmark/configs/deepseek-v2-ep4.json). The same "
+                        "server, router, batcher and session API; the "
+                        "LSTM model flags are then unused, and the "
+                        "LSTM-only features (--prefix-cache/--prefix-fabric "
+                        "on, --tiered-cache on, --session-dir, "
+                        "--speculative, --mesh-shards, --replicas > 1, "
+                        "--checkpoint-dir, --registry-dir, --autotune on, "
+                        "--loadgen, sampled decoding) are REFUSED")
+    p.add_argument("--weights-dtype", type=str, default="bfloat16",
+                   choices=["bfloat16", "float32"],
+                   help="decoder: dtype of the seeded weights and of the "
+                        "latent cache (XLA:CPU has no bf16 x bf16 -> f32 "
+                        "dot, so CPU rehearsals pass float32)")
+    p.add_argument("--interpret-kernels", action="store_true",
+                   help="decoder: run its Pallas kernels in interpret mode "
+                        "(a CPU rehearsal; without it a host with no TPU "
+                        "fails at the first program instead of carrying on "
+                        "in the interpreter)")
+    p.add_argument("--latent-pool-gib", type=float, default=0.25,
+                   help="decoder: device memory of the paged latent cache, "
+                        "all layers together (pages are taken as sessions "
+                        "grow and freed when they end; nothing is evicted)")
+    p.add_argument("--page-size", type=int, default=256,
+                   help="decoder: tokens per page of the latent cache")
+    p.add_argument("--max-context", type=int, default=4096,
+                   help="decoder: the most tokens one session may hold")
+    p.add_argument("--prefill-rows", type=int, default=4,
+                   help="decoder: rows one prefill dispatch may pack onto "
+                        "its flat token axis")
     # --- engine / batcher (docs/OPERATIONS.md "Serving") ---
     p.add_argument("--replicas", type=str, default="1",
                    help="data-parallel serving replicas (serve/router.py): "
@@ -1429,7 +1464,7 @@ def build_serve_parser() -> argparse.ArgumentParser:
                         "ITL deltas + greedy parity "
                         "(BENCH_serve_r05.json). See docs/OPERATIONS.md "
                         "for when to pin 'scan'.")
-    p.add_argument("--prefix-cache", type=str, default="on",
+    p.add_argument("--prefix-cache", type=str, default=None,
                    choices=["on", "off"],
                    help="shared-prompt prefix-state cache: fresh prompts "
                         "resume prefill from the longest cached prefix "
@@ -1465,7 +1500,7 @@ def build_serve_parser() -> argparse.ArgumentParser:
                         "(a spilled node is one (h, c) pair per layer "
                         "held by the tiers); the coldest zero-ref "
                         "spilled nodes are dropped past this")
-    p.add_argument("--tiered-cache", type=str, default="on",
+    p.add_argument("--tiered-cache", type=str, default=None,
                    choices=["on", "off"],
                    help="tiered session-state cache (serve/state_cache.py "
                         "SessionTiers): LRU-evicted session states spill "
@@ -1885,6 +1920,11 @@ def _build_serve_stack(args, n_replicas: int = 1, registry=None):
     from .models import LMConfig, init_lm
     from .serve import ServeEngine, ServeServer
 
+    if getattr(args, "model_file", None):
+        return _build_decoder_stack(args, n_replicas, registry)
+    # the two caches default ON for this family (None = not given)
+    args.prefix_cache = args.prefix_cache or "on"
+    args.tiered_cache = args.tiered_cache or "on"
     chunk = args.prefill_chunk or None
     if (chunk is not None and chunk > 0 and args.prefix_cache == "on"
             and chunk % args.prefix_stride != 0
@@ -2018,18 +2058,7 @@ def _build_serve_stack(args, n_replicas: int = 1, registry=None):
         }
         if getattr(args, "spec_k", None) is not None:
             spec_kw["spec_k"] = args.spec_k
-    try:
-        wp, wb = (int(x) for x in args.class_weights.split(","))
-    except ValueError:
-        raise SystemExit(
-            f"--class-weights: expected 'P,B' positive ints, got "
-            f"{args.class_weights!r}")
-    if wp < 1 or wb < 1:
-        # fail in ms with the flag's own message — not a Batcher
-        # traceback mid-stack-build
-        raise SystemExit(
-            f"--class-weights: weights must be >= 1, got "
-            f"{args.class_weights!r}")
+    wp, wb = _parse_class_weights(args)
     autotune_cfg = None
     chunk_choices = None
     if getattr(args, "autotune", "off") == "on":
@@ -2089,6 +2118,103 @@ def _build_serve_stack(args, n_replicas: int = 1, registry=None):
     return params, cfg, server
 
 
+def _parse_class_weights(args) -> tuple[int, int]:
+    """--class-weights 'P,B': fail in ms with the flag's own message, not a
+    Batcher traceback mid-stack-build."""
+    try:
+        wp, wb = (int(x) for x in args.class_weights.split(","))
+    except ValueError:
+        raise SystemExit(
+            f"--class-weights: expected 'P,B' positive ints, got "
+            f"{args.class_weights!r}")
+    if wp < 1 or wb < 1:
+        raise SystemExit(
+            f"--class-weights: weights must be >= 1, got "
+            f"{args.class_weights!r}")
+    return wp, wb
+
+
+def _refuse_for_decoder(args, n_replicas: int) -> None:
+    """The LSTM-only features, refused by name when a decoder is served:
+    a flag that would be silently ignored is a wrong answer waiting."""
+    asked = {
+        "--prefix-cache on": args.prefix_cache == "on",
+        "--prefix-fabric on": getattr(args, "prefix_fabric", "off") == "on",
+        "--tiered-cache on": args.tiered_cache == "on",
+        "--session-dir": bool(args.session_dir),
+        "--speculative": bool(getattr(args, "speculative", False)),
+        "--mesh-shards > 1": int(getattr(args, "mesh_shards", 1) or 1) > 1,
+        "--replicas > 1": n_replicas > 1,
+        "--remote-replica": bool(getattr(args, "remote_replica", None)),
+        "--checkpoint-dir": bool(args.checkpoint_dir),
+        "--registry-dir": bool(getattr(args, "registry_dir", None)),
+        "--autotune on": getattr(args, "autotune", "off") == "on",
+        "--loadgen": bool(args.loadgen),
+        "--decode-kernel pallas/scan": args.decode_kernel != "auto",
+        "sampled decoding (pass --greedy)": not args.greedy,
+    }
+    bad = [flag for flag, on in asked.items() if on]
+    if bad:
+        raise SystemExit(
+            "--model-file serves a decoder; LSTM-only for now and refused "
+            f"with it: {', '.join(bad)}. (A decoder's sessions live in a "
+            "paged latent cache: no prefix sharing over pages, no spill "
+            "tier, no draft model yet — ROADMAP.md.)")
+
+
+def _build_decoder_stack(args, n_replicas: int = 1, registry=None):
+    """(params, cfg, server) for ``--model-file``: the same `ServeServer`
+    -> router -> `Batcher` stack over a `DecoderEngine` and its paged
+    latent cache. Weights are random from the FILE's seed
+    (``assumed.weights_seed``), never from --seed: one file is one model."""
+    from .models import decoder
+    from .obs import NULL_REGISTRY, REGISTRY
+    from .serve import ServeServer
+    from .serve.engine import build_engine
+
+    _refuse_for_decoder(args, n_replicas)
+    cfg, doc = decoder.load_model_file(args.model_file)
+    if registry is None:
+        registry = (NULL_REGISTRY
+                    if getattr(args, "telemetry", "on") == "off"
+                    else REGISTRY)
+    page = args.page_size
+    token_bytes = cfg.latent_width * 2 * cfg.num_hidden_layers
+    num_pages = int(args.latent_pool_gib * 2 ** 30) // (page * token_bytes)
+    if num_pages < 1:
+        raise SystemExit(
+            f"--latent-pool-gib {args.latent_pool_gib} holds no page of "
+            f"{page} tokens ({page * token_bytes} bytes)")
+    params = decoder.init_decoder(
+        int(doc.get("assumed", {}).get("weights_seed", 0)), cfg,
+        dtype=np.dtype(args.weights_dtype))
+    engine = build_engine(
+        params, cfg, num_slots=args.num_slots, num_pages=num_pages,
+        page=page, max_context=args.max_context,
+        prefill_buckets=_parse_buckets(args.prefill_buckets,
+                                       "--prefill-buckets"),
+        batch_buckets=_parse_buckets(args.batch_buckets, "--batch-buckets"),
+        max_prefill_rows=args.prefill_rows, registry=registry,
+        model_id=getattr(args, "model_id", "default"),
+        interpret=args.interpret_kernels)
+    wp, wb = _parse_class_weights(args)
+    server = ServeServer(
+        engine, max_active=args.max_active, queue_size=args.queue_size,
+        window_ladder=_parse_window_ladder(args.decode_window),
+        # a prompt longer than the largest bucket is prefilled in chunks
+        # between decode steps: always on for this family
+        prefill_chunk=args.prefill_chunk or engine.max_prompt_len,
+        tenant_rate=getattr(args, "tenant_rate", 0) or None,
+        tenant_burst=getattr(args, "tenant_burst", 5.0),
+        class_weights=(wp, wb),
+        health_stale_after=args.replica_stale_s,
+        best_effort_queue_frac=args.best_effort_queue_frac,
+        sweep_interval=args.replica_sweep_s or None,
+        deadline_defaults={"priority": args.deadline_priority_s or None,
+                           "best_effort": args.deadline_best_effort_s or None})
+    return params, cfg, server
+
+
 def _serve_sampling(args):
     from .serve import SamplingParams
 
@@ -2107,6 +2233,8 @@ def _serve_selftest(args) -> int:
     from .models.generate import judge_greedy_divergence
     from .serve import InprocessClient
 
+    if getattr(args, "model_file", None):
+        return _serve_selftest_decoder(args)
     params, cfg, server = _build_serve_stack(
         args, _single_replica_count(args, "--selftest"))
     rng = np.random.RandomState(args.seed)
@@ -2181,6 +2309,80 @@ def _serve_selftest(args) -> int:
     ok = bad == ties
     print(f"serve selftest: {'PASS' if ok else 'FAIL'}"
           + (f" ({ties} rounding tie(s), judged above)" if ties else ""))
+    return 0 if ok else 1
+
+
+def _serve_selftest_decoder(args) -> int:
+    """``--selftest`` with ``--model-file``: concurrent sessions of two
+    turns each through the full server path (packed and chunked prefill,
+    decode windows, kept sessions continuing from their pages), then every
+    conversation again ALONE, one token a step — other programs, other
+    batch shapes. The greedy tokens must be the same; where they part, the
+    two picks' logits must be a rounding tie (2^-6 of the logit: bf16
+    inputs), as the LSTM selftest judges its two paths."""
+    import json
+    import threading
+
+    from .serve import ServeServer
+
+    _, cfg, server = _build_serve_stack(args, 1)
+    sampling = _serve_sampling(args)
+    rng = np.random.RandomState(args.seed)
+    long = server.engine.max_prompt_len + 9       # one chunked prompt
+    lengths = ([3, long, 8, 13, 21, 5] * args.sessions)[: max(args.sessions, 2)]
+    n_new = args.max_new_tokens
+    draw = lambda n: rng.randint(2, cfg.vocab_size, size=n).astype(np.int32)  # noqa: E731
+    turns = [(draw(t), draw(4)) for t in lengths]
+
+    def converse(srv, i, out):
+        try:
+            a = srv.generate(turns[i][0], max_new_tokens=n_new,
+                             sampling=sampling, keep_session=True)
+            b = srv.generate(
+                np.concatenate([[a.tokens[-1]], turns[i][1]]),
+                max_new_tokens=n_new, sampling=sampling,
+                session_id=a.session_id)
+            out[i] = (a.tokens + b.tokens,
+                      a.token_logits + b.token_logits)
+        except Exception as e:  # surface, don't hang the join
+            out[i] = f"session {i}: {type(e).__name__}: {e}"
+
+    together: list = [None] * len(turns)
+    with server:
+        threads = [threading.Thread(target=converse, args=(server, i, together))
+                   for i in range(len(turns))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    alone: list = [None] * len(turns)
+    solo = ServeServer(server.engine, max_active=1, window_ladder=(1,),
+                       prefill_chunk=server.engine.max_prompt_len)
+    with solo:
+        for i in range(len(turns)):
+            converse(solo, i, alone)
+    errors = [x for x in together + alone if isinstance(x, str)]
+    bad = ties = 0
+    for i, (a, b) in enumerate(zip(together, alone)):
+        if errors or a[0] == b[0]:
+            continue
+        j = next(k for k, (x, y) in enumerate(zip(a[0], b[0])) if x != y)
+        la, lb = a[1][j][0], b[1][j][0]
+        tie = abs(la - lb) <= 2.0 ** -6 * max(abs(la), abs(lb))
+        ties += tie
+        bad += not tie
+        print(f"session {i}: token {j} {a[0][j]} vs {b[0][j]}, logits "
+              f"{la:.5f} vs {lb:.5f}: {'a rounding tie' if tie else 'REAL'}")
+    cache = server.engine.cache.stats()
+    leaked = cache["latent_pages_in_use"] or cache["live_sessions"]
+    print(json.dumps({
+        "note": "serve_selftest", "family": "decoder",
+        "sessions": len(turns), "tokens_per_turn": n_new,
+        "mismatches": bad, "mismatches_tied": ties, "errors": errors,
+        "cache": cache, **server.engine.stats()["decoder"]}))
+    ok = not errors and not bad and not leaked
+    print(f"serve selftest: {'PASS' if ok else 'FAIL'}"
+          + (" (pages or sessions left behind)" if leaked else ""))
     return 0 if ok else 1
 
 

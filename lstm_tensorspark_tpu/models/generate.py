@@ -62,6 +62,16 @@ def sample_logits(
     return jax.random.categorical(rng, logits, axis=-1).astype(jnp.int32)
 
 
+def family_of(cfg) -> str:
+    """The serving family of a model configuration: ``"lstm"`` (state is a
+    fixed-size carry: this module's `decode_one`, `serve.ServeEngine`) or
+    ``"decoder"`` (state grows a latent row per token: `models.decoder`,
+    `serve.DecoderEngine`). The seam the serve stack dispatches on: an
+    engine of either family answers the same calls, and
+    `serve.engine.build_engine` picks it here."""
+    return getattr(cfg, "family", "lstm")
+
+
 def fuse_layers(params, cfg: LMConfig):
     """Fuse every layer's gate matrices ONCE (outside the decode scan) — per
     lstm_cell.py's contract that fusing happens once per forward pass.

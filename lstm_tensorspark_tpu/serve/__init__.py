@@ -6,6 +6,10 @@ State Space Duality and Portable O(1) Autoregressive Caching"). This
 package turns the repo's training LM + one-shot sampler (models/generate.py)
 into a serving engine:
 
+- ``decoder_engine`` (+ ``state_cache.PagedLatentCache``): the second
+  FAMILY — a decoder with latent attention whose session state is pages of
+  latents that grow with the session; it answers the same calls as
+  ``engine`` (``engine.build_engine`` picks by the configuration's family)
 - ``state_cache``: slot-based device-resident cache of per-session carries
   (LRU eviction, explicit detach/restore), plus ``PrefixCache`` — a
   shared-prompt prefix store (state after ``prompt[:k]`` is ONE (h, c)
@@ -94,7 +98,8 @@ admit→queue→prefill→decode→readback timeline.
 CLI: ``python -m lstm_tensorspark_tpu.cli serve --selftest`` (see cli.py).
 """
 
-from .state_cache import CacheFullError, PrefixCache, SessionTiers, StateCache
+from .state_cache import (CacheFullError, PagedLatentCache, PrefixCache,
+                          SessionTiers, StateCache)
 from .prefix_trie import PrefixPropagator, PrefixTrie
 from .autotune import AutoTuneConfig, AutoTuner
 from .engine import (
@@ -103,7 +108,9 @@ from .engine import (
     SamplingParams,
     ServeEngine,
     UnknownModelError,
+    build_engine,
 )
+from .decoder_engine import DecoderEngine
 from .batcher import (
     CLASSES,
     Batcher,
@@ -133,9 +140,11 @@ __all__ = [
     "CacheFullError",
     "DeadlineExceededError",
     "DecodeWindow",
+    "DecoderEngine",
     "InprocessClient",
     "ModelRegistry",
     "PAD_TOKEN",
+    "PagedLatentCache",
     "PrefixCache",
     "PrefixPropagator",
     "PrefixTrie",
@@ -154,6 +163,7 @@ __all__ = [
     "SessionTiers",
     "StateCache",
     "UnknownModelError",
+    "build_engine",
     "config_fingerprint",
     "mesh_sweep",
     "replica_sweep",

@@ -277,6 +277,10 @@ class Request:
         self.tokens: list[int] = []
         self.error: str | None = None
         self.cancelled = False  # set by an abandoning client (timeout)
+        # decoder family: per generated token, (its logit, the step's
+        # largest) as the program computed them — what a judge compares
+        # with a reference's logits (stays empty for the LSTM family)
+        self.token_logits: list[tuple[float, float]] = []
         self.done = threading.Event()
         self.t_submit: float | None = None
         self.t_admit: float | None = None
@@ -905,6 +909,7 @@ class Batcher:
 
     def _admit(self) -> bool:
         admit: list[Request] = []
+        unfit: list[Request] = []
         dropped: list[Request] = []
         reaped: list[Request] = []
         now = time.perf_counter()
@@ -964,11 +969,26 @@ class Batcher:
                 if admit and (head.sampling.key(), head.model) != (
                         admit[0].sampling.key(), admit[0].model):
                     break
+                # admission by what the state can hold, not by free slots
+                # alone: a family whose state grows (a decoder's pages)
+                # leaves the head queued until sessions end and free what
+                # it needs — or fails it, when nothing is running that
+                # ever could
+                if not self.engine.admits(head, admit):
+                    if not (admit or self._active or self._prefilling):
+                        self._queues[cls].popleft()
+                        unfit.append(head)
+                        continue
+                    break
                 self._queues[cls].popleft()
                 self._wrr_idx = (jpos + 1) % nwrr
                 admit.append(head)
         for r in dropped:
             self._fail(r, "cancelled before admission")
+        for r in unfit:
+            self._fail(r, "the session state this request needs does not "
+                          "fit: nothing running will free it (release kept "
+                          "sessions or shorten the request)")
         for r in reaped:
             # queue-only lifetime: the phase timeline records exactly the
             # submit→reap span, nothing else (tests pin this)
@@ -976,7 +996,7 @@ class Batcher:
                 r.phases.append(("queue", r.t_submit, now))
             self._settle_timeout(r, "queue")
         if not admit:
-            return bool(dropped or reaped)
+            return bool(dropped or reaped or unfit)
 
         now = time.perf_counter()
         # admitted requests that need a tier fill (continuation whose
@@ -1018,7 +1038,7 @@ class Batcher:
                 # just-acquired slot — neither mid-restore nor in the
                 # window before a separate pin() call (release() on the
                 # failure paths clears the pin along with the slot)
-                slot, fresh = self.engine.cache.acquire_pinned(sid)
+                slot, fresh = self.engine.admit_session(sid, req)
             except Exception as e:  # cache exhausted by pinned slots
                 self._fail(req, f"{type(e).__name__}: {e}")
                 continue
@@ -1188,7 +1208,7 @@ class Batcher:
         mdl = head.sess.req.model
         batch = []
         for p in self._prefilling:
-            if len(batch) >= self.engine.max_batch:
+            if len(batch) >= self.engine.max_prefill_batch:
                 break
             if (self._next_stop(p, chunk)
                     >= p.sess.req.prompt.size) != final:
@@ -1278,8 +1298,9 @@ class Batcher:
             t0 = time.perf_counter()
             try:
                 if final:
-                    first = self.engine.prefill(items, batch[0].sess.req.sampling,
-                                                model=batch[0].sess.req.model)
+                    first, logits = self.engine.prefill(
+                        items, batch[0].sess.req.sampling,
+                        model=batch[0].sess.req.model)
                 else:
                     self.engine.prefill_chunk(items,
                                               model=batch[0].sess.req.model)
@@ -1334,7 +1355,9 @@ class Batcher:
                 s.req.t_first_token = now
                 if s.req.t_submit is not None:
                     self._m_ttft.observe(now - s.req.t_submit)
-                self._append_token(s, int(first[i]))
+                self._append_token(
+                    s, int(first[i]),
+                    logit=None if logits is None else logits[i])
                 if s.remaining == 0:
                     self._finish(s)
                 else:
@@ -1427,18 +1450,20 @@ class Batcher:
                     with tracing.span("serve:decode_dispatch",
                                       rows=len(chunk), k=1,
                                       pipelined=0) as dispatch:
-                        nxt = self.engine.decode(slots, toks,
-                                                 chunk[0].req.sampling,
-                                                 model=chunk[0].req.model)
+                        nxt, logits = self.engine.decode(
+                            slots, toks, chunk[0].req.sampling,
+                            model=chunk[0].req.model)
                 except Exception as e:
                     self._fail_chunk(
                         chunk, f"decode failed: {type(e).__name__}: {e}")
                     continue
                 t0, t1 = dispatch.start, dispatch.end
                 with tracing.span("serve:deliver"):
-                    for s, tok in zip(chunk, nxt):
+                    for i, (s, tok) in enumerate(zip(chunk, nxt)):
                         s.req.phases.append(("decode", t0, t1))
-                        self._append_token(s, int(tok), t1)
+                        self._append_token(
+                            s, int(tok), t1,
+                            logit=None if logits is None else logits[i])
                         if s.remaining == 0:
                             self._retire(s)
                             self._finish(s)
@@ -1611,7 +1636,8 @@ class Batcher:
         # the scheduler tick trusts the device latches instead of
         # re-deriving them per token host-side — with the fused Pallas
         # kernel those latches lived in VMEM for the whole window
-        toks, dev_rem, dev_alive = self.engine.fetch_window_summary(win)
+        toks, dev_rem, dev_alive, logits = (
+            self.engine.fetch_window_summary(win))
         now = time.perf_counter()
         # dispatch→fetch-complete: how long the window's tokens took to
         # reach the host after its program was dispatched (device compute
@@ -1648,10 +1674,12 @@ class Batcher:
                         else:
                             outcome = "reject"
                         self._m_spec_outcome[outcome].inc()
-                for tok in row:
+                for j, tok in enumerate(row):
                     if tok == PAD_TOKEN:
                         break
-                    self._append_token(s, int(tok), now)
+                    self._append_token(
+                        s, int(tok), now,
+                        logit=None if logits is None else logits[i, j])
                     if s.remaining == 0:
                         break
                 if not dev_alive[i] or dev_rem[i] <= 0:
@@ -1679,9 +1707,11 @@ class Batcher:
             self._fail(s.req, error)
 
     def _append_token(self, s: _Session, tok: int,
-                      t: float | None = None) -> None:
+                      t: float | None = None, logit=None) -> None:
         if t is None:
             t = time.perf_counter()
+        if logit is not None:
+            s.req.token_logits.append((float(logit[0]), float(logit[1])))
         if s.req.t_tokens:
             # server-side inter-token latency: same gap definition as
             # Request.itl_gaps()/loadgen (host arrival deltas; a window's

@@ -436,6 +436,25 @@ class ServeEngine:
     def max_batch(self) -> int:
         return self.batch_buckets[-1]
 
+    # ---- the family seam (what a scheduler asks of ANY engine) ----------
+    # An LSTM session's state is O(1): a free slot is all it needs, a
+    # prefill batch is as wide as a decode batch, and the logits handed
+    # back with the tokens (`prefill`, `decode`, `fetch_window_summary`)
+    # are None. `DecoderEngine` answers the same questions by pages, by
+    # its flat token axis and with logits.
+
+    family = "lstm"
+
+    @property
+    def max_prefill_batch(self) -> int:
+        return self.batch_buckets[-1]
+
+    def admits(self, req, ahead=()) -> bool:
+        return True
+
+    def admit_session(self, sid: str, req) -> tuple[int, bool]:
+        return self.cache.acquire_pinned(sid)
+
     # ---- resident models ----------------------------------------------
 
     # The ``self._residents`` reads below are DELIBERATELY lock-free:
@@ -1188,7 +1207,7 @@ class ServeEngine:
             return (*arrays, n, batch_b, len_b)
 
     def prefill(self, items, sampling: SamplingParams = GREEDY, *,
-                model: str | None = None) -> np.ndarray:
+                model: str | None = None) -> tuple[np.ndarray, None]:
         """Run one bucketed prefill batch (the FINAL — or only — chunk of
         each row's prompt: ends with the head + sampler).
 
@@ -1197,11 +1216,12 @@ class ServeEngine:
         ``prompt`` a 1-D int array (1 <= len <= max_prompt_len). Rows are
         padded up to the batch bucket (dead rows target the scratch slot)
         and prompts are right-padded to the length bucket (carry-freeze
-        mask). Returns the first sampled token per item, ``[len(items)]``
-        int32.
+        mask). Returns ``(tokens, logits)``: the first sampled token per
+        item, ``[len(items)]`` int32, and None (the decoder family hands
+        each token's logits back there).
         """
         if len(items) == 0:
-            return np.zeros((0,), np.int32)
+            return np.zeros((0,), np.int32), None
         self._admit_sampling(sampling)
         src, dst, fresh, prompts, lens, n, batch_b, len_b = (
             self._pack_prefill(self._norm_prefill_items(items)))
@@ -1215,7 +1235,7 @@ class ServeEngine:
                                src, dst, fresh, prompts, lens, rng)
             self.cache.swap(h, c)
         with span("engine:fetch", program="prefill_fn", rows=n, k=1):
-            return np.asarray(tok)[:n]
+            return np.asarray(tok)[:n], None
 
     def prefill_chunk(self, items, *, model: str | None = None) -> None:
         """Dispatch one INTERMEDIATE prefill chunk batch: advance each
@@ -1262,13 +1282,14 @@ class ServeEngine:
             self._draft_h, self._draft_c = dh, dc
 
     def decode(self, slots, tokens, sampling: SamplingParams = GREEDY, *,
-               model: str | None = None) -> np.ndarray:
+               model: str | None = None) -> tuple[np.ndarray, None]:
         """Advance each session one token: gather carries by ``slots`` [B],
-        feed ``tokens`` [B], return the next token per row ``[B]`` int32.
-        Pads to the batch bucket (dead rows at the scratch slot)."""
+        feed ``tokens`` [B], return ``(tokens, logits)``: the next token
+        per row ``[B]`` int32, and None (as `prefill`). Pads to the batch
+        bucket (dead rows at the scratch slot)."""
         n = len(slots)
         if n == 0:
-            return np.zeros((0,), np.int32)
+            return np.zeros((0,), np.int32), None
         # chaos drills: an armed serve_error fault raises InjectedFault out
         # of the Nth decode call — the batcher must fail ONLY that chunk's
         # requests and keep serving (tests/test_serve_health.py). Warmup's
@@ -1296,7 +1317,7 @@ class ServeEngine:
                                slots_d, tokens_d, rng)
             self.cache.swap(h, c)
         with span("engine:fetch", program="decode_fn", rows=n, k=1):
-            return np.asarray(tok)[:n]
+            return np.asarray(tok)[:n], None
 
     def _pack_window(self, slots, tokens, remaining, eos_ids):
         """A window's per-row host values padded to the batch bucket (dead
@@ -1485,10 +1506,12 @@ class ServeEngine:
 
     @staticmethod
     def fetch_window_summary(
-            win: DecodeWindow) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+            win: DecodeWindow) -> tuple[np.ndarray, np.ndarray, np.ndarray,
+                                        None]:
         """Fetch the token block AND the per-row on-device scheduler
         summary in ONE transfer: ``(tokens [n, K], remaining [n],
-        alive [n])``. The window program already latched EOS/budget per
+        alive [n], logits)``, ``logits`` None as in `prefill`. The window
+        program already latched EOS/budget per
         row on device, so the scheduler tick reads this summary instead
         of re-deriving liveness host-side per token — same single sync
         point as :meth:`fetch_window` (graftlint host-sync allow-list),
@@ -1498,7 +1521,7 @@ class ServeEngine:
                 (win.tokens, win.remaining, win.alive))
         n = win.n
         return (np.asarray(toks)[:n], np.asarray(rem)[:n],
-                np.asarray(alive)[:n])
+                np.asarray(alive)[:n], None)
 
     def warmup(self, sampling: SamplingParams = GREEDY,
                prompt_lens: tuple[int, ...] = (1,),
@@ -1669,3 +1692,16 @@ class ServeEngine:
             "prefill_buckets": self.prefill_buckets,
             "batch_buckets": self.batch_buckets,
         }
+
+
+def build_engine(params, cfg, **kw):
+    """The serve engine of ``cfg``'s family (`models.generate.family_of`):
+    `ServeEngine` for an LSTM LM, `DecoderEngine` for a decoder. ``kw`` are
+    the engine's own constructor arguments."""
+    from ..models.generate import family_of
+
+    if family_of(cfg) == "decoder":
+        from .decoder_engine import DecoderEngine
+
+        return DecoderEngine(params, cfg, **kw)
+    return ServeEngine(params, cfg, **kw)

@@ -35,6 +35,11 @@ receives the handle and is therefore data-ordered after it on device.
 Host-side bookkeeping is lock-protected; device reads/writes are plain
 jnp gather/scatter ops (one compile each per batch-shape, amortised).
 
+:class:`PagedLatentCache` (same file) is the second KIND of state: a cache
+that grows with the session (pages of latent rows for a decoder with latent
+attention), behind the same session table; nothing of it is evicted, and
+admission is by free pages (see its docstring).
+
 :class:`PrefixCache` (same file) layers shared-prompt reuse on top: a
 store of "state after token-prefix P" entries, each backed by a
 state-cache slot under the reserved ``prefix/`` session namespace —
@@ -59,6 +64,7 @@ import numpy as np
 from .. import obs
 from ..resilience import faults as _faults
 from ..train.checkpoint import CorruptCheckpointError, atomic_write, read_verified
+from ..utils.tracing import span as _span
 
 
 class CacheFullError(RuntimeError):
@@ -109,71 +115,34 @@ class DetachedState(NamedTuple):
     c: np.ndarray
 
 
-class StateCache:
-    def __init__(self, num_layers: int, num_slots: int, hidden_size: int,
-                 registry=None, device=None, sharding=None):
+class SessionTable:
+    """Which session holds which slot, and which slots are pinned by active
+    work: the host-side table BOTH kinds of session state keep
+    (`StateCache`: a slot is a row of carries; `PagedLatentCache`: a slot is
+    a row of the page bookkeeping). One reentrant lock guards it and
+    whatever a subclass keeps per slot; the subclass creates it and hands
+    it in (graftlint's lock model is per class: a lock made here would be
+    unknown to the classes that share ``cache._lock``). A subclass says
+    what happens when no slot is free (`_no_free_slot_locked`: evict one,
+    or raise `CacheFullError`)."""
+
+    def __init__(self, num_slots: int, lock):
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
-        if device is not None and sharding is not None:
-            raise ValueError("pass device OR sharding, not both")
-        self.num_layers = num_layers
         self.num_slots = num_slots
-        self.hidden_size = hidden_size
-        # remembered for resize(): a reallocated array pair must land
-        # exactly where the originals did (committed device / mesh
-        # sharding), or the engine's programs would recompile against a
-        # different placement
-        self._placement = device if device is not None else sharding
-        # +1: the scratch slot for padded batch rows (index == num_slots)
-        self.h = jnp.zeros((num_layers, num_slots + 1, hidden_size), jnp.float32)
-        self.c = jnp.zeros((num_layers, num_slots + 1, hidden_size), jnp.float32)
-        if device is not None:
-            # device-per-replica serving: commit the cache arrays so every
-            # program touching them (and their uncommitted host inputs)
-            # runs on this replica's device
-            self.h = jax.device_put(self.h, device)
-            self.c = jax.device_put(self.c, device)
-        elif sharding is not None:
-            # mesh-per-replica serving (ServeEngine mesh_shards > 1): the
-            # cache slots shard over the hidden axis like the params —
-            # every gather/scatter/step program then runs sharded with
-            # XLA deriving the collectives, and detach/device_get
-            # assemble the full rows host-side
-            self.h = jax.device_put(self.h, sharding)
-            self.c = jax.device_put(self.c, sharding)
-        self._lock = threading.RLock()
+        self._lock = lock
         self._slots: OrderedDict[str, int] = OrderedDict()  # LRU: oldest first
         self._free: list[int] = list(range(num_slots))
         self._pinned: set[str] = set()
         self.evictions = 0
-        self.generation = 0  # device programs applied via swap()
-        # registry counters feed /metrics; the per-instance ints above stay
-        # the source for this instance's stats() (the registry aggregates
-        # across every cache in the process — Prometheus semantics)
-        reg = obs.REGISTRY if registry is None else registry
-        self._m_evictions = reg.counter(
-            "serve_state_cache_evictions_total",
-            "LRU evictions of unpinned session slots")
-        self._m_swaps = reg.counter(
-            "serve_state_cache_swaps_total",
-            "device programs applied to the cache arrays (generation)")
-        # eviction listeners: called (under the cache lock) with the
-        # ``(sid, slot)`` of every LRU-evicted session — the prefix cache
-        # registers here so a slot eviction INVALIDATES (or, tiered,
-        # SPILLS) the dependent prefix entry instead of leaving it
-        # pointing at a slot another session now owns; SessionTiers
-        # registers here to capture the evicted state's device handles
-        # for the async host-tier spill
-        self.evict_listeners: list = []
 
     @property
     def scratch_slot(self) -> int:
+        """The slot of padded batch rows (index == num_slots)."""
         # lock-free on the hot dispatch path: resize() only rebinds
         # num_slots with the cache drained (no sessions, no dispatches),
         # and a plain int rebind cannot tear
         return self.num_slots  # graftlint: disable=cross-thread-state
-
-    # ---- session table -------------------------------------------------
 
     def lookup(self, session_id: str) -> int | None:
         """Slot for a live session (refreshes LRU recency), else None."""
@@ -198,31 +167,24 @@ class StateCache:
             if self._free:
                 slot = self._free.pop()
             else:
-                slot = self._evict_lru_locked()
+                slot = self._no_free_slot_locked()
             self._slots[session_id] = slot
             return slot, True
 
-    def _evict_lru_locked(self) -> int:
-        for sid in self._slots:  # oldest-recency first
-            if sid not in self._pinned:
-                slot = self._slots.pop(sid)
-                self.evictions += 1
-                self._m_evictions.inc()
-                for listener in self.evict_listeners:
-                    listener(sid, slot)
-                return slot
+    def _no_free_slot_locked(self) -> int:
         raise CacheFullError(
-            f"all {self.num_slots} slots pinned by active sessions"
-        )
+            f"all {self.num_slots} session slots hold a session")
 
-    def release(self, session_id: str) -> None:
-        """Drop the session (its slot returns to the free list). No-op for
-        unknown sessions — release after eviction must be safe."""
+    def release(self, session_id: str) -> int | None:
+        """Drop the session (its slot returns to the free list) and return
+        the slot it held. No-op (None) for unknown sessions — release after
+        eviction must be safe."""
         with self._lock:
             self._pinned.discard(session_id)
             slot = self._slots.pop(session_id, None)
             if slot is not None:
                 self._free.append(slot)
+            return slot
 
     def acquire_pinned(self, session_id: str) -> tuple[int, bool]:
         """:meth:`acquire` + :meth:`pin` under ONE lock hold — with
@@ -267,6 +229,82 @@ class StateCache:
     def __len__(self) -> int:
         with self._lock:
             return len(self._slots)
+
+    def stats(self) -> dict:
+        with self._lock:
+            return {
+                "slots": self.num_slots,
+                "live_sessions": len(self._slots),
+                "pinned": len(self._pinned),
+                "free": len(self._free),
+                "evictions": self.evictions,
+            }
+
+
+class StateCache(SessionTable):
+    def __init__(self, num_layers: int, num_slots: int, hidden_size: int,
+                 registry=None, device=None, sharding=None):
+        self._lock = threading.RLock()
+        super().__init__(num_slots, self._lock)
+        if device is not None and sharding is not None:
+            raise ValueError("pass device OR sharding, not both")
+        self.num_layers = num_layers
+        self.hidden_size = hidden_size
+        # remembered for resize(): a reallocated array pair must land
+        # exactly where the originals did (committed device / mesh
+        # sharding), or the engine's programs would recompile against a
+        # different placement
+        self._placement = device if device is not None else sharding
+        # +1: the scratch slot for padded batch rows (index == num_slots)
+        self.h = jnp.zeros((num_layers, num_slots + 1, hidden_size), jnp.float32)
+        self.c = jnp.zeros((num_layers, num_slots + 1, hidden_size), jnp.float32)
+        if device is not None:
+            # device-per-replica serving: commit the cache arrays so every
+            # program touching them (and their uncommitted host inputs)
+            # runs on this replica's device
+            self.h = jax.device_put(self.h, device)
+            self.c = jax.device_put(self.c, device)
+        elif sharding is not None:
+            # mesh-per-replica serving (ServeEngine mesh_shards > 1): the
+            # cache slots shard over the hidden axis like the params —
+            # every gather/scatter/step program then runs sharded with
+            # XLA deriving the collectives, and detach/device_get
+            # assemble the full rows host-side
+            self.h = jax.device_put(self.h, sharding)
+            self.c = jax.device_put(self.c, sharding)
+        self.generation = 0  # device programs applied via swap()
+        # registry counters feed /metrics; the per-instance ints above stay
+        # the source for this instance's stats() (the registry aggregates
+        # across every cache in the process — Prometheus semantics)
+        reg = obs.REGISTRY if registry is None else registry
+        self._m_evictions = reg.counter(
+            "serve_state_cache_evictions_total",
+            "LRU evictions of unpinned session slots")
+        self._m_swaps = reg.counter(
+            "serve_state_cache_swaps_total",
+            "device programs applied to the cache arrays (generation)")
+        # eviction listeners: called (under the cache lock) with the
+        # ``(sid, slot)`` of every LRU-evicted session — the prefix cache
+        # registers here so a slot eviction INVALIDATES (or, tiered,
+        # SPILLS) the dependent prefix entry instead of leaving it
+        # pointing at a slot another session now owns; SessionTiers
+        # registers here to capture the evicted state's device handles
+        # for the async host-tier spill
+        self.evict_listeners: list = []
+
+    def _no_free_slot_locked(self) -> int:
+        """Evict the least recently used unpinned session."""
+        for sid in self._slots:  # oldest-recency first
+            if sid not in self._pinned:
+                slot = self._slots.pop(sid)
+                self.evictions += 1
+                self._m_evictions.inc()
+                for listener in self.evict_listeners:
+                    listener(sid, slot)
+                return slot
+        raise CacheFullError(
+            f"all {self.num_slots} slots pinned by active sessions"
+        )
 
     # ---- device state --------------------------------------------------
 
@@ -425,14 +463,7 @@ class StateCache:
 
     def stats(self) -> dict:
         with self._lock:
-            return {
-                "slots": self.num_slots,
-                "live_sessions": len(self._slots),
-                "pinned": len(self._pinned),
-                "free": len(self._free),
-                "evictions": self.evictions,
-                "generation": self.generation,
-            }
+            return {**super().stats(), "generation": self.generation}
 
 
 class PrefixEntry:
@@ -1879,4 +1910,186 @@ class SessionTiers:
                 "corrupt": self.corrupt,
                 "lost": self.lost,
                 "disk_errors": self.disk_errors,
+            }
+
+
+class PagedLatentCache(SessionTable):
+    """The second kind of session state: a cache that GROWS with the
+    session. A decoder's state is one latent row per token and layer
+    (`ops/mla_attention.py`), kept in pages of ``page`` rows; the device
+    holds one pool ``[num_pages + 1, page, width]`` per layer (page
+    ``num_pages`` is scratch: dead rows write there) and the host holds the
+    bookkeeping — which session has which slot, which pages a slot owns in
+    order, how many tokens it holds.
+
+    The session table IS `StateCache`'s (`SessionTable`: ``acquire_pinned``,
+    ``unpin``, ``release``, ``in``, ``session_ids``, ``stats``), so the
+    batcher, the router and the server drive it unchanged. What differs:
+
+    - nothing is evicted: a session's pages are its conversation, and
+      there is no spill tier for them yet (ROADMAP: preemption by
+      snapshot). A kept session holds its pages until it is released;
+      when slots or pages run out, `acquire`/`commit` raise
+      `CacheFullError` and admission waits or fails loudly;
+    - admission is by PAGES: `commit` promises a session the pages its
+      request can grow into (prompt + new tokens) before any are taken,
+      `ensure` takes them as the session grows, `unpin`/`release` return
+      what was promised and not used; ``pages_free - pages_promised`` is
+      what a new request may count on (`can_commit`);
+    - the pools are updated IN PLACE: every program that writes them takes
+      them donated and hands them back (`swap`); a copy of a multi-GiB
+      pool per dispatch would not fit beside the weights.
+
+    One page id indexes the same page of every layer's pool. Spans
+    ``cache:pages_alloc`` / ``cache:pages_free`` (on the caller's thread:
+    the scheduler's) carry the number of pages moved."""
+
+    def __init__(self, num_layers: int, num_slots: int, num_pages: int,
+                 page: int, width: int, dtype=jnp.bfloat16, device=None):
+        self._lock = threading.RLock()
+        super().__init__(num_slots, self._lock)
+        if num_pages < 1 or page < 1:
+            raise ValueError("num_pages and page must be >= 1")
+        self.num_layers = num_layers
+        self.num_pages, self.page, self.width = num_pages, page, width
+        make = jax.jit(lambda: jnp.zeros((num_pages + 1, page, width), dtype))
+        self.pools = tuple(make() for _ in range(num_layers))
+        if device is not None:
+            self.pools = jax.device_put(self.pools, device)
+        self._free_pages: list[int] = list(range(num_pages - 1, -1, -1))
+        self._pages: list[list[int]] = [[] for _ in range(num_slots + 1)]
+        self._promised = np.zeros((num_slots + 1,), np.int64)  # pages
+        #: tokens each slot holds (the scratch slot stays 0), and the most
+        #: its admitted request may bring it to (`commit`)
+        self.length = np.zeros((num_slots + 1,), np.int64)
+        self.limit = np.zeros((num_slots + 1,), np.int64)
+        self.generation = 0
+        self.pages_allocated = 0   # running totals, for the counters
+        self.pages_freed = 0
+
+    @property
+    def scratch_page(self) -> int:
+        return self.num_pages
+
+    def pages_for(self, tokens: int) -> int:
+        return -(-int(tokens) // self.page)
+
+    # ---- session table: `SessionTable`'s, plus the pages a slot owns -----
+
+    def _no_free_slot_locked(self) -> int:
+        raise CacheFullError(
+            f"all {self.num_slots} session slots hold a session "
+            "(a decoder's sessions are not evicted)")
+
+    def acquire(self, session_id: str) -> tuple[int, bool]:
+        with self._lock:
+            slot, fresh = super().acquire(session_id)
+            if fresh:
+                self.length[slot] = 0
+            return slot, fresh
+
+    def unpin(self, session_id: str) -> None:
+        """The session goes idle and keeps its pages; what it was promised
+        and did not grow into is returned."""
+        with self._lock:
+            super().unpin(session_id)
+            slot = self._slots.get(session_id)
+            if slot is not None:
+                self._promised[slot] = 0
+
+    def release(self, session_id: str) -> int | None:
+        """Drop the session: its pages and its slot are free again."""
+        with self._lock:
+            slot = super().release(session_id)
+            if slot is None:
+                return None
+            pages, self._pages[slot] = self._pages[slot], []
+            self._promised[slot] = 0
+            self.length[slot] = 0
+            if pages:
+                with _span("cache:pages_free", pages=len(pages)):
+                    self._free_pages.extend(reversed(pages))
+                    self.pages_freed += len(pages)
+            return slot
+
+    # ---- pages ----------------------------------------------------------
+
+    def _uncommitted_locked(self) -> int:
+        return len(self._free_pages) - int(self._promised.sum())
+
+    def can_commit(self, slot_tokens) -> bool:
+        """Could sessions growing to these ``(slot or None, tokens)`` totals
+        all be promised their pages now? (``tokens`` is what the request
+        adds; a slot's present length and pages count for it.)"""
+        with self._lock:
+            need = sum(self._need_locked(slot, tokens)
+                       for slot, tokens in slot_tokens)
+            return need <= self._uncommitted_locked()
+
+    def _need_locked(self, slot, tokens: int) -> int:
+        if slot is None:
+            return self.pages_for(tokens)
+        total = self.pages_for(int(self.length[slot]) + int(tokens))
+        return max(total - len(self._pages[slot])
+                   - int(self._promised[slot]), 0)
+
+    def commit(self, slot: int, tokens: int) -> None:
+        """Promise ``slot`` the pages to grow by ``tokens`` more tokens."""
+        with self._lock:
+            need = self._need_locked(slot, tokens)
+            if need > self._uncommitted_locked():
+                raise CacheFullError(
+                    f"{need} pages needed, {self._uncommitted_locked()} of "
+                    f"{self.num_pages} neither held nor promised")
+            self._promised[slot] += need
+            self.limit[slot] = int(self.length[slot]) + int(tokens)
+
+    def ensure(self, slot: int, tokens: int) -> list[int]:
+        """Give ``slot`` pages for ``tokens`` tokens in all (first out of its
+        promise, then out of the free pages) and return its page list."""
+        with self._lock:
+            pages = self._pages[slot]
+            need = self.pages_for(tokens) - len(pages)
+            if need > 0:
+                spare = self._uncommitted_locked() + int(self._promised[slot])
+                if need > spare:
+                    raise CacheFullError(
+                        f"slot {slot} needs {need} more pages, {spare} free")
+                with _span("cache:pages_alloc", pages=need):
+                    pages.extend(self._free_pages.pop() for _ in range(need))
+                    self._promised[slot] = max(
+                        int(self._promised[slot]) - need, 0)
+                    self.pages_allocated += need
+            return pages
+
+    def pages_of(self, slot: int) -> list[int]:
+        with self._lock:
+            return self._pages[slot]
+
+    @property
+    def pages_in_use(self) -> int:
+        with self._lock:
+            return self.num_pages - len(self._free_pages)
+
+    # ---- device state ---------------------------------------------------
+
+    def swap(self, pools) -> None:
+        """Install the pools a program handed back (the ones it was given
+        are donated: they no longer exist)."""
+        with self._lock:
+            self.pools = tuple(pools)
+            self.generation += 1
+
+    def stats(self) -> dict:
+        with self._lock:
+            return {
+                **super().stats(),
+                "generation": self.generation,
+                "latent_pages_total": self.num_pages,
+                "latent_pages_in_use": self.num_pages - len(self._free_pages),
+                "latent_pages_promised": int(self._promised.sum()),
+                "latent_tokens": int(self.length.sum()),
+                "page": self.page,
+                "pages_allocated": self.pages_allocated,
+                "pages_freed": self.pages_freed,
             }

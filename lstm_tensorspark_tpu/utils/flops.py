@@ -55,3 +55,35 @@ def seq2seq_fwd_flops_per_seq(F: int, H: int, L: int, T: int,
         enc += 8.0 * H * (din + H)
         dec += 8.0 * H * (din + H)
     return T * enc + horizon * (dec + 2.0 * H * F)
+
+
+def decoder_fwd_flops_per_token(cfg, *, context: int = 0,
+                                pairs_here: float | None = None,
+                                head: bool = True) -> float:
+    """Matmul-only forward FLOPs of ONE token through a decoder
+    (`models.decoder.DecoderConfig`) as computed on this chip: every layer's
+    attention projections, the dense or shared MLP and the router, the
+    routed experts for ``pairs_here`` (token, expert) pairs a layer that
+    land on the experts held (default: the held share of top-k, as an even
+    router gives), the absorbed attention over ``context`` cached tokens,
+    and the head over the vocabulary slice."""
+    d, h = cfg.hidden_size, cfg.num_attention_heads
+    qd = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    kv, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    attention = (d * cfg.q_lora_rank + cfg.q_lora_rank * h * qd
+                 + d * (kv + rope)
+                 + kv * h * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+                 + h * cfg.v_head_dim * d)
+    dense = cfg.first_k_dense_replace
+    moe = cfg.num_hidden_layers - dense
+    if pairs_here is None:
+        pairs_here = (cfg.num_experts_per_tok * cfg.experts_held
+                      / cfg.n_routed_experts)
+    expert = 3 * d * cfg.moe_intermediate_size
+    params = (cfg.num_hidden_layers * attention
+              + dense * 3 * d * cfg.intermediate_size
+              + moe * (cfg.n_shared_experts * expert
+                       + d * cfg.n_routed_experts + pairs_here * expert)
+              + (d * cfg.vocab_size if head else 0))
+    scores = cfg.num_hidden_layers * context * h * (kv + rope + kv)
+    return 2.0 * (params + scores)

@@ -356,3 +356,122 @@ def test_config5_dp4_train_step_compiles():
     assert text.count("tpu_custom_call") >= 2 * cfg.num_layers
     assert "all-reduce" in text
     _fits_hbm(compiled)
+
+
+# ---- the decoder family: paged latent attention, grouped experts -------
+# deepseek-v2-ep4's widths (benchmark/configs/deepseek-v2-ep4.json), bf16
+
+
+def _decoder_cfg():
+    import json
+    import os
+
+    from lstm_tensorspark_tpu.models import decoder
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "deepseek-v2-ep4.json")
+    with open(path) as f:
+        return decoder, decoder.DecoderConfig.from_model(json.load(f))
+
+
+def _int(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+def _attention_items(tiles, capacity):
+    return {"tile": _int(capacity), "page": _int(capacity),
+            "start": _int(capacity), "n": _int(1),
+            "qpos": _int(tiles), "klen": _int(tiles)}
+
+
+@pytest.mark.parametrize("name,tq,tiles", [("mla_decode", 1, 32),
+                                           ("mla_prefill", 16, 128)])
+def test_paged_latent_attention_compiles(chip, name, tq, tiles):
+    from lstm_tensorspark_tpu.ops import mla_attention
+
+    _, cfg = _decoder_cfg()
+    h, width = cfg.num_attention_heads, cfg.latent_width
+    q = jax.ShapeDtypeStruct((tiles, tq * h, width), jnp.bfloat16)
+    pool = jax.ShapeDtypeStruct((2294, 256, width), jnp.bfloat16)
+
+    def attend(q, pool, items):
+        return mla_attention.paged_attention(
+            q, pool, items, scale=cfg.softmax_scale, heads=h,
+            kv_rank=cfg.kv_lora_rank, name=name, interpret=False)
+
+    compiled = _compile(attend, *_on(chip, (q, pool, _attention_items(
+        tiles, tiles * 96))))
+    assert _kernel_calls(compiled) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20  # no pool copy
+
+
+@pytest.mark.parametrize("tokens", [32, 2048])
+def test_routed_experts_compile(chip, tokens):
+    from lstm_tensorspark_tpu.ops import moe
+
+    decoder, cfg = _decoder_cfg()
+    d, inter = cfg.hidden_size, cfg.moe_intermediate_size
+    args = (jax.ShapeDtypeStruct((tokens, d), jnp.bfloat16),
+            jax.ShapeDtypeStruct((tokens,), jnp.bool_),
+            jax.ShapeDtypeStruct((d, cfg.n_routed_experts), jnp.bfloat16),
+            jax.ShapeDtypeStruct((cfg.experts_held, d, 2 * inter), jnp.bfloat16),
+            jax.ShapeDtypeStruct((cfg.experts_held, inter, d), jnp.bfloat16))
+
+    def routed(x, live, w_router, w_gate_up, w_down):
+        return moe.routed_experts(
+            x, live, w_router, w_gate_up, w_down, first=cfg.experts_first,
+            n_group=cfg.n_group, topk_group=cfg.topk_group,
+            top_k=cfg.num_experts_per_tok, scale=cfg.routed_scaling_factor,
+            tm=decoder.moe_tile_rows(tokens), interpret=False)
+
+    compiled = _compile(routed, *_on(chip, args))
+    assert _kernel_calls(compiled) == 2        # gate/up and down
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("program", ["window", "prefill"])
+def test_decoder_serve_programs_fit_the_chip(chip, program):
+    """The decode window (32 rows x 4 steps) and the widest prefill (2,048
+    tokens) at the published widths beside a 3.5 GiB pool: they compile, the
+    pools are aliased in place (no copy of one), and arguments plus
+    temporaries stay inside the chip's memory."""
+    import threading
+    from collections import defaultdict
+
+    from lstm_tensorspark_tpu.serve.decoder_engine import DecoderEngine
+
+    decoder, cfg = _decoder_cfg()
+    pages, page = 2293, 256
+    params = jax.eval_shape(lambda: decoder.init_decoder(0, cfg))
+    absorbed = jax.eval_shape(lambda p: decoder.absorb(p, cfg), params)
+    pools = tuple(jax.ShapeDtypeStruct((pages + 1, page, cfg.latent_width),
+                                       jnp.bfloat16)
+                  for _ in range(cfg.num_hidden_layers))
+    engine = object.__new__(DecoderEngine)     # the programs, not the arrays
+    engine.cfg, engine._interpret, engine._fns = cfg, False, {}
+    engine._counts_lock, engine.compile_counts = threading.Lock(), defaultdict(int)
+    engine.pages_per_row = 96
+    engine.cache = type("Pages", (), {"page": page, "scratch_page": pages})()
+    acc = _int(2, 3)
+    if program == "window":
+        b = 32
+        fn = engine._window_fn(b, 4)
+        args = (params, absorbed, pools, acc, _int(b), _int(b),
+                jax.ShapeDtypeStruct((b,), jnp.bool_), _int(b), _int(b),
+                _int(b, 96), _attention_items(b, b * 96))
+    else:
+        t = 2048
+        fn = engine._prefill_fn(t, True)
+        args = (params, absorbed, pools, acc, _int(t), _int(t),
+                jax.ShapeDtypeStruct((t,), jnp.bool_), _int(t), _int(t),
+                _attention_items(t // 16, t // 16 * 96), _int(4))
+    compiled = fn.lower(*_on(chip, args)).compile()
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(p.size * 2 for p in pools)
+    assert memory.alias_size_in_bytes >= pool_bytes          # updated in place
+    import re
+
+    assert not re.search(r"= bf16\[2294,256,640\]\S* copy\(", compiled.as_text())
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 16 * 2 ** 30)

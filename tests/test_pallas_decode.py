@@ -60,7 +60,7 @@ def _window_stream(engine, prompt, sampling, *, budget, window, eos_id=None):
     returns (tokens incl. the prefill token, last summary)."""
     sid = f"s{engine.decode_kernel}{np.random.randint(1 << 30)}"
     slot, _ = engine.cache.acquire(sid)
-    first = engine.prefill([(slot, True, prompt)], sampling)
+    first, _ = engine.prefill([(slot, True, prompt)], sampling)
     out = [int(first[0])]
     remaining = budget
     last = int(first[0])
@@ -70,7 +70,7 @@ def _window_stream(engine, prompt, sampling, *, budget, window, eos_id=None):
             [slot], [last], [remaining],
             eos_ids=None if eos_id is None else [eos_id],
             sampling=sampling, window=window)
-        toks, rem, alive = engine.fetch_window_summary(win)
+        toks, rem, alive, _ = engine.fetch_window_summary(win)
         summary = (rem.copy(), alive.copy())
         emitted = [int(t) for t in toks[0] if t != PAD_TOKEN]
         out.extend(emitted)
@@ -129,7 +129,7 @@ def test_greedy_parity_across_batch_buckets(params):
         for i, p in enumerate(prompts):
             slot, _ = e.cache.acquire(f"b{i}")
             slots.append(slot)
-        first = e.prefill([(s, True, p) for s, p in zip(slots, prompts)])
+        first, _ = e.prefill([(s, True, p) for s, p in zip(slots, prompts)])
         win = e.decode_window(slots, [int(t) for t in first],
                               [6] * 3, window=8)
         toks = e.fetch_window(win)
@@ -191,9 +191,9 @@ def test_budget_latch_edges(params, budget):
     p = _prompt(5, 40)
     for e in (ep, es):
         slot, _ = e.cache.acquire("s")
-        first = e.prefill([(slot, True, p)])
+        first, _ = e.prefill([(slot, True, p)])
         win = e.decode_window([slot], [int(first[0])], [budget], window=8)
-        toks, rem, alive = e.fetch_window_summary(win)
+        toks, rem, alive, _ = e.fetch_window_summary(win)
         row = [int(t) for t in toks[0]]
         assert all(t != PAD_TOKEN for t in row[:budget])
         assert all(t == PAD_TOKEN for t in row[budget:])
@@ -206,12 +206,12 @@ def test_pipelined_followup_window_stays_frozen(params):
     ahead, pre-fetch): the latched row stays frozen — all PAD."""
     e = _engine(params)
     slot, _ = e.cache.acquire("s")
-    first = e.prefill([(slot, True, _prompt(3, 7))])
+    first, _ = e.prefill([(slot, True, _prompt(3, 7))])
     probe = e.decode_window([slot], [int(first[0])], [8], window=8)
     stream = [int(t) for t in ServeEngine.fetch_window(probe)[0]]
     eos = stream[2]
     slot2, _ = e.cache.acquire("s2")
-    f2 = e.prefill([(slot2, True, _prompt(3, 7))])
+    f2, _ = e.prefill([(slot2, True, _prompt(3, 7))])
     win = e.decode_window([slot2], [int(f2[0])], [8], eos_ids=[eos],
                           window=8)
     nxt = e.decode_window_next(win)  # dispatch-ahead, pre-fetch
@@ -327,11 +327,11 @@ def test_window_readback_contract_both_kernels(params, kernel):
     # engine-level: the raw window rows carry PAD after the latch and
     # the summary matches, for this kernel
     slot, _ = e.cache.acquire("pin")
-    first = e.prefill([(slot, True, _prompt(4, 6))])
+    first, _ = e.prefill([(slot, True, _prompt(4, 6))])
     win = e.decode_window([slot], [int(first[0])], [12],
                           eos_ids=[int(eos)], window=8)
     row = ServeEngine.fetch_window(win)[0]
-    toks, rem, alive = e.fetch_window_summary(win)
+    toks, rem, alive, _ = e.fetch_window_summary(win)
     np.testing.assert_array_equal(row, toks[0])
     pad_idx = [i for i, t in enumerate(row) if t == PAD_TOKEN]
     if pad_idx:  # eos landed inside this window

@@ -61,10 +61,10 @@ def test_prefill_pads_to_length_bucket(engine):
 def test_batch_pads_to_batch_bucket(engine):
     scratch = engine.cache.scratch_slot
     items = [(scratch, True, _prompt(2, s)) for s in range(3)]
-    out = engine.prefill(items)  # 3 rows → batch bucket 4
+    out, _ = engine.prefill(items)  # 3 rows → batch bucket 4
     assert out.shape == (3,)  # padding rows are stripped from the result
     assert any(k[0] == "prefill" and k[1] == 4 for k in engine.compile_counts)
-    nxt = engine.decode([scratch] * 3, [1, 2, 3])
+    nxt, _ = engine.decode([scratch] * 3, [1, 2, 3])
     assert nxt.shape == (3,)
     assert any(k[0] == "decode" and k[1] == 4 for k in engine.compile_counts)
 

@@ -111,7 +111,7 @@ def test_mesh_sampled_parity(params):
         for i, p in enumerate(prompts):
             sid = f"x{i}"
             slot, fresh = engine.cache.acquire_pinned(sid)
-            first = int(engine.prefill([(slot, fresh, p)], sa)[0])
+            first = int(engine.prefill([(slot, fresh, p)], sa)[0][0])
             win = engine.decode_window([slot], [first], [5],
                                        sampling=sa, window=4)
             row = engine.fetch_window(win)[0]

@@ -113,7 +113,7 @@ def test_spec_engine_greedy_matches_generate(params, draft_params):
     p = _prompt(4, 1)
     n_new = 12
     slot, _ = engine.cache.acquire("s")
-    first = engine.prefill([(slot, True, p)])
+    first, _ = engine.prefill([(slot, True, p)])
     got, _ = _spec_stream(engine, slot, first[0], n_new, k_draft=2)
     assert got == _ref(params, p, n_new)
 
@@ -126,7 +126,7 @@ def test_spec_window_next_pipelined_parity(params, draft_params):
     engine.attach_draft(draft_params, _DCFG, version=1)
     p = _prompt(5, 2)
     slot, _ = engine.cache.acquire("s")
-    first = engine.prefill([(slot, True, p)])
+    first, _ = engine.prefill([(slot, True, p)])
     out = [int(first[0])]
     win = engine.spec_window([slot], [out[0]], [32], k_draft=2)
     nxt = engine.spec_window_next(win, k_draft=4)  # knob move mid-chain
@@ -168,7 +168,7 @@ def test_spec_pallas_window_matches_scan(params, draft_params):
     streams = {}
     for name, engine in (("scan", scan_eng), ("pallas", pallas_eng)):
         slot, _ = engine.cache.acquire("s")
-        first = engine.prefill([(slot, True, p)])
+        first, _ = engine.prefill([(slot, True, p)])
         streams[name], _ = _spec_stream(engine, slot, first[0], n_new,
                                         k_draft=2)
     assert streams["pallas"] == streams["scan"] == _ref(params, p, n_new)
@@ -198,8 +198,8 @@ def test_all_reject_spec_state_bitwise_identical(params):
 
     sslot, _ = spec_eng.cache.acquire("s")
     pslot, _ = plain_eng.cache.acquire("s")
-    sfirst = spec_eng.prefill([(sslot, True, p)])
-    pfirst = plain_eng.prefill([(pslot, True, p)])
+    sfirst, _ = spec_eng.prefill([(sslot, True, p)])
+    pfirst, _ = plain_eng.prefill([(pslot, True, p)])
     assert int(sfirst[0]) == int(pfirst[0]) == ref[0]
 
     spec_got, per_window = _spec_stream(spec_eng, sslot, sfirst[0], n_new,
@@ -254,7 +254,7 @@ def test_all_reject_rollback_bitwise_across_tiers(params):
             if slot is None:
                 slot, _ = engine.cache.acquire(sid)
                 if sid not in toks:
-                    first = engine.prefill([(slot, True, prompts[sid])])
+                    first, _ = engine.prefill([(slot, True, prompts[sid])])
                     toks[sid] = [int(first[0])]
                 else:
                     assert engine.tiers.fill(sid, slot)

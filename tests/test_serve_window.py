@@ -144,7 +144,7 @@ def test_window_program_pads_after_eos(params):
     fetch) leaves the latched row frozen."""
     engine = _engine(params)
     slot, _ = engine.cache.acquire("s")
-    first = engine.prefill([(slot, True, _prompt(3, 7))])
+    first, _ = engine.prefill([(slot, True, _prompt(3, 7))])
     # probe the continuation to find a mid-window token to use as EOS
     probe_win = engine.decode_window([slot], [int(first[0])], [8], window=8)
     stream = [int(t) for t in ServeEngine.fetch_window(probe_win)[0]]
@@ -154,7 +154,7 @@ def test_window_program_pads_after_eos(params):
     # fresh session, same engine (the compiled programs replay): rerun
     # the same continuation WITH the eos armed
     slot2, _ = engine.cache.acquire("s2")
-    f2 = engine.prefill([(slot2, True, _prompt(3, 7))])
+    f2, _ = engine.prefill([(slot2, True, _prompt(3, 7))])
     win = engine.decode_window([slot2], [int(f2[0])], [8],
                                eos_ids=[eos], window=8)
     nxt = engine.decode_window_next(win)  # dispatch-ahead, pre-fetch

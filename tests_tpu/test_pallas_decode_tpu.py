@@ -61,12 +61,12 @@ def test_compiled_window_token_parity(vocab, hidden, layers, batch, k):
         for i, p in enumerate(prompts):
             slot, _ = e.cache.acquire(f"s{i}")
             slots.append(slot)
-        first = e.prefill([(s, True, p) for s, p in zip(slots, prompts)])
+        first, _ = e.prefill([(s, True, p) for s, p in zip(slots, prompts)])
         win = e.decode_window(slots, [int(t) for t in first],
                               [2 * k] * batch, window=k)
         toks1 = ServeEngine.fetch_window(win)
         win = e.decode_window_next(win)
-        toks, rem, alive = e.fetch_window_summary(win)
+        toks, rem, alive, _ = e.fetch_window_summary(win)
         outs[name] = ([int(t) for t in first], toks1.tolist(),
                       toks.tolist(), rem.tolist(), alive.tolist())
     # the two window programs take the same batch through the same
@@ -97,8 +97,8 @@ def test_compiled_window_sampled_parity():
     outs = {}
     for name, e in (("pallas", ep), ("scan", es)):
         slot, _ = e.cache.acquire("s")
-        first = e.prefill([(slot, True, np.arange(1, 7, dtype=np.int32))],
-                          samp)
+        first, _ = e.prefill([(slot, True, np.arange(1, 7, dtype=np.int32))],
+                             samp)
         win = e.decode_window([slot], [int(first[0])], [8], sampling=samp,
                               window=8)
         outs[name] = ([int(first[0])],
