@@ -687,8 +687,7 @@ def measure_pp_config5(*, steps: int = 48, warmup: int = 8) -> dict:
         mesh = make_mesh(dp=1, pp=1)
         stacked = stack_lm_params(params)
         placed = place_pp_lm_params(stacked, mesh)
-        step = make_pp_lm_train_step(cfg, opt, mesh, stacked,
-                                     microbatches=2, donate=False)
+        step = make_pp_lm_train_step(cfg, opt, mesh, stacked, microbatches=2)
         state = init_train_state(placed, opt, jax.random.PRNGKey(1))
         toks = jax.random.randint(jax.random.PRNGKey(2), (B_, T_ + 1), 0,
                                   c["V"], jnp.int32)

@@ -90,8 +90,8 @@ def init_lstm_params(
     kW = jax.random.split(key, 8)
     Ws = [_glorot(kW[j], (input_size, hidden_size), dtype) for j in range(4)]
     Us = [_orthogonal(kW[4 + j], (hidden_size, hidden_size), dtype) for j in range(4)]
-    zeros = jnp.zeros((hidden_size,), dtype)
-    biases = [zeros, jnp.full((hidden_size,), forget_bias, dtype), zeros, zeros]
+    # an array per bias: leaves that share a buffer cannot be donated
+    biases = [jnp.full((hidden_size,), b, dtype) for b in (0.0, forget_bias, 0.0, 0.0)]
     return LSTMParams(*Ws, *Us, *biases)
 
 

@@ -60,7 +60,7 @@ def make_dp_train_step(
     *,
     axis: str = "data",
     jit: bool = True,
-    donate: bool | None = None,
+    donate: bool = True,
     stateful: bool = False,
     grad_accum: int = 1,
 ):
@@ -103,10 +103,6 @@ def make_dp_train_step(
         check_vma=False,
     )
     if jit:
-        from ..train.loop import _donation_supported
-
-        if donate is None:
-            donate = _donation_supported()
         sharded = jax.jit(sharded, donate_argnums=(0,) if donate else ())
     return sharded
 

@@ -374,7 +374,7 @@ def make_pp_lm_train_step(
     params_stacked,
     *,
     microbatches: int | None = None,
-    donate: bool | None = None,
+    donate: bool = True,
     tp: bool = False,
     zero1: bool = False,
 ):
@@ -469,10 +469,6 @@ def make_pp_lm_train_step(
         "targets": NamedSharding(mesh, P("data")),
     }
 
-    from ..train.loop import _donation_supported
-
-    if donate is None:
-        donate = _donation_supported()
     return jax.jit(
         step,
         in_shardings=(state_shardings, batch_shardings),

@@ -124,7 +124,7 @@ def make_tp_train_step(
     dp_axis: str = "data",
     tp_axis: str = "model",
     stateful: bool = False,
-    donate: bool | None = None,
+    donate: bool = True,
     param_specs=None,
     opt_state_specs=None,
     metric_fn: Callable | None = None,
@@ -174,11 +174,6 @@ def make_tp_train_step(
         rng=NamedSharding(mesh, P()),
         carries=NamedSharding(mesh, P(dp_axis)) if stateful else None,
     )
-
-    from ..train.loop import _donation_supported
-
-    if donate is None:
-        donate = _donation_supported()
 
     if metric_fn is None:
 

@@ -135,7 +135,7 @@ def make_sharded_lm_train_step(
     params_template,
     *,
     microbatches: int = 1,
-    donate: bool | None = None,
+    donate: bool = True,
 ):
     """Build the DP x TP x SP train step. Batch: {"inputs","targets"} [B, T]
     with B % (data axis) == 0 and T % (seq axis) == 0."""
@@ -195,10 +195,6 @@ def make_sharded_lm_train_step(
         "targets": NamedSharding(mesh, P("data", "seq")),
     }
 
-    from ..train.loop import _donation_supported
-
-    if donate is None:
-        donate = _donation_supported()
     return jax.jit(
         sharded,
         in_shardings=(state_shardings, batch_shardings),

@@ -116,7 +116,7 @@ def make_zero1_train_step(
     axis: str = "data",
     clip_norm: float | None = None,
     jit: bool = True,
-    donate: bool | None = None,
+    donate: bool = True,
     steps_per_call: int = 1,
 ):
     """Build the ZeRO-1 DP train step.
@@ -125,9 +125,9 @@ def make_zero1_train_step(
     per-shard body as every other step builder. ``optimizer`` must NOT
     include a global-norm clip stage; pass ``clip_norm`` here instead
     (module docstring: clipping needs the GLOBAL norm, computed by psum
-    before the sliced update). ``donate`` follows the repo's step-builder
-    contract (default: platform-gated buffer donation of the state — the
-    memory-saving step must not hold a second copy of params + moments).
+    before the sliced update). The state is donated into the step, as in
+    every step builder: the memory-saving step must not hold a second copy
+    of params + moments.
 
     ``steps_per_call=K`` scans the per-shard step over K stacked batches
     ([K, b_local, ...]) INSIDE the shard_map — K optimizer steps per host
@@ -234,10 +234,6 @@ def make_zero1_train_step(
 
     if not jit:
         return step
-    from ..train.loop import _donation_supported
-
-    if donate is None:
-        donate = _donation_supported()
     return jax.jit(step, donate_argnums=(0,) if donate else ())
 
 

@@ -47,7 +47,6 @@ from jax import shard_map
 from ..data.device_dataset import DeviceLMData, slice_window
 from .loop import (
     TrainState,
-    _donation_supported,
     call_loss,
     dp_reduce_fn,
     dp_rng_transform,
@@ -72,12 +71,10 @@ def _scan_indexed(loss_fn, optimizer, state, arrays, idxs, *, window_fn,
     return state, summarize_scan_metrics(ms)
 
 
-def _jit_step(step, jit: bool, donate: bool | None):
+def _jit_step(step, jit: bool, donate: bool):
     """The ONE jit/donation wrapper shared by every builder here."""
     if not jit:
         return step
-    if donate is None:
-        donate = _donation_supported()
     return jax.jit(step, donate_argnums=(0,) if donate else ())
 
 
@@ -173,7 +170,7 @@ def make_device_train_step(
     stateful: bool = False,
     grad_accum: int = 1,
     jit: bool = True,
-    donate: bool | None = None,
+    donate: bool = True,
 ):
     """Generic single-chip device-data step: ``step(state, arrays, idxs)``
     with ``idxs`` carrying a leading K axis (one entry per optimizer step).
@@ -217,7 +214,7 @@ def make_device_dp_train_step(
     stateful: bool = False,
     grad_accum: int = 1,
     jit: bool = True,
-    donate: bool | None = None,
+    donate: bool = True,
 ):
     """Generic data-parallel device-data step. ``arrays_spec`` gives the
     staged arrays' shardings (LM streams shard their batch rows; example/
@@ -287,7 +284,7 @@ def make_device_lm_train_step(
     stateful: bool = False,
     grad_accum: int = 1,
     jit: bool = True,
-    donate: bool | None = None,
+    donate: bool = True,
 ):
     """Single-chip LM device-data step: ``step(state, data.arrays, w0)``.
 
@@ -356,6 +353,8 @@ class TrainStepCompileCache:
                     self.compile_counts.get(count_key, 0) + 1)
                 return _raw(state, batch)
 
+            # not donated: warmup() dispatches states its caller goes on
+            # to train from (tools/bench_train_scan.py pairs runs on them)
             self._fns[key] = jax.jit(counted)
         return self._fns[key]
 
@@ -380,7 +379,7 @@ def make_device_dp_lm_train_step(
     stateful: bool = False,
     grad_accum: int = 1,
     jit: bool = True,
-    donate: bool | None = None,
+    donate: bool = True,
 ):
     """Data-parallel LM device-data step: streams live sharded
     ``P(axis, None)`` (each chip's HBM holds only its batch rows — a cached

@@ -68,17 +68,6 @@ def init_train_state(params, optimizer, rng, *, carries=None) -> TrainState:
     )
 
 
-def _donation_supported() -> bool:
-    # Buffer donation (in-place param/opt-state update) stays OFF: with it
-    # on, the train steps fail on every backend with "Attempt to donate the
-    # same buffer twice in Execute()" — two leaves of the train state are
-    # one buffer (first seen on the CPU: tests/test_train_e2e.py and
-    # tests/test_multistep.py fail their first five tests, then the
-    # interpreter aborts). A bug of this program, queued in ROADMAP C12;
-    # until the aliasing is found, donating is not an option to offer.
-    return False
-
-
 def call_loss(loss_fn, params, batch, rng, carries, *, stateful: bool):
     """Uniform invocation of the (stateless|stateful) loss_fn signature."""
     if stateful:
@@ -224,7 +213,7 @@ def make_train_step(
     optimizer: optax.GradientTransformation,
     *,
     jit: bool = True,
-    donate: bool | None = None,
+    donate: bool = True,
     stateful: bool = False,
     grad_accum: int = 1,
 ):
@@ -244,8 +233,6 @@ def make_train_step(
         )
 
     if jit:
-        if donate is None:
-            donate = _donation_supported()
         train_step = jax.jit(train_step, donate_argnums=(0,) if donate else ())
     return train_step
 
@@ -414,6 +401,9 @@ def train_loop(
     _m_bptt_tr = obs.REGISTRY.counter(
         "train_bptt_assoc_traces_total",
         "scans traced with the associative-scan backward")
+    _m_donated = obs.REGISTRY.gauge(
+        "train_state_donated",
+        "1 when the first dispatch consumed the state handed to it")
     _bptt0 = _pscan.assoc_stats()
     if num_steps is not None and num_steps <= 0:
         return state  # eval-only budget: never pull a batch from the feed
@@ -429,7 +419,7 @@ def train_loop(
             best_metric=best_metric, best_mode=best_mode, best_init=best_init,
             anomaly_limit=anomaly_limit, window_start=window_start,
             _m_step=_m_step, _m_tps=_m_tps, _m_steps=_m_steps,
-            _m_anomalous=_m_anomalous,
+            _m_anomalous=_m_anomalous, _m_donated=_m_donated,
         )
     finally:
         # counted on every exit path — an anomaly abort's final
@@ -460,7 +450,7 @@ def _run_train_loop(
     eval_every, checkpoint_fn, checkpoint_every, tokens_per_batch,
     steps_per_call, fused_eval, flops_per_token, peak_tflops, best_fn,
     best_metric, best_mode, best_init, anomaly_limit, window_start,
-    _m_step, _m_tps, _m_steps, _m_anomalous,
+    _m_step, _m_tps, _m_steps, _m_anomalous, _m_donated,
 ):
     """The drive loop proper (split from `train_loop` so the bptt trace
     accounting above wraps every exit path in one place)."""
@@ -468,16 +458,26 @@ def _run_train_loop(
     anomalous_total = 0
     anomalous_consec = 0
     best_val = best_init
+    donated = None
     for i, batch in enumerate(_fed(batches)):
         if num_steps is not None and i >= num_steps:
             break
         step = i + 1
+        # the first device-resident state handed in (a restored one starts
+        # as host arrays): did its dispatch take the buffers?
+        handed = None
+        if donated is None:
+            handed = [x for x in jax.tree.leaves(state)
+                      if isinstance(x, jax.Array)]
         with span("train:dispatch", steps=steps_per_call):
             if fused_eval:
                 do_eval = bool(eval_every) and step % eval_every == 0
                 state, metrics = train_step(state, batch, np.bool_(do_eval))
             else:
                 state, metrics = train_step(state, batch)
+        if handed:
+            donated = any(x.is_deleted() for x in handed)
+            _m_donated.set(int(donated))
         last_metrics = metrics
         if anomaly_limit and "anomalous" in metrics:
             with span("train:sync"):

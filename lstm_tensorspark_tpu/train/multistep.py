@@ -100,7 +100,7 @@ def make_multi_train_step(
     metric_fn: Callable | None = None,
     metric_keys=(),
     jit: bool = True,
-    donate: bool | None = None,
+    donate: bool = True,
     stateful: bool = False,
     grad_accum: int = 1,
 ):
@@ -147,7 +147,7 @@ def make_dp_multi_train_step(
     metric_keys=(),
     axis: str = "data",
     jit: bool = True,
-    donate: bool | None = None,
+    donate: bool = True,
     stateful: bool = False,
     grad_accum: int = 1,
 ):

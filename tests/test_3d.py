@@ -21,7 +21,8 @@ def test_dp_tp_sp_matches_single_device():
         return lm_loss(p, b, cfg)
 
     opt = make_optimizer("sgd", 0.3)
-    params = init_lm(jax.random.PRNGKey(0), cfg)
+    # a host copy: both runs start from it, and the first step donates its state
+    params = jax.device_get(init_lm(jax.random.PRNGKey(0), cfg))
     rngb = np.random.RandomState(0)
     batches = [
         {
@@ -72,7 +73,7 @@ def test_dp_tp_sp_matches_single_device_bf16_logits():
         return lm_loss(p, b, cfg)
 
     opt = make_optimizer("sgd", 0.3)
-    params = init_lm(jax.random.PRNGKey(2), cfg)
+    params = jax.device_get(init_lm(jax.random.PRNGKey(2), cfg))
     rngb = np.random.RandomState(1)
     batches = [
         {

@@ -335,7 +335,8 @@ def test_best_artifact_kinds_never_shadow(tmp_path):
     sync barriers no-op, pid 0 writes everything), standing in for the
     multi-process writer."""
     loss_fn, opt, state, batch = _setup()
-    step = make_train_step(loss_fn, opt)
+    # donate=False: the test keeps every step's state to save it later
+    step = make_train_step(loss_fn, opt, donate=False)
     state1, _ = step(state, batch)    # step 1
     state2, _ = step(state1, batch)   # step 2
     state3, _ = step(state2, batch)   # step 3

@@ -266,6 +266,61 @@ def test_config5_train_step_compiles(chip):
     _fits_hbm(compiled)
 
 
+def _state_updated_in_place(compiled, state):
+    """The donated train state is the program's to write into: no copy of
+    the embedding, the head kernel or one of their Adam moments (205 MB
+    each) anywhere in the compiled text, and at least the parameters and
+    the optimizer's state aliased to outputs."""
+    import re
+
+    copies = re.findall(r"= f32\[(?:50000,1024|1024,50000)\]\S* copy\(",
+                        compiled.as_text())
+    assert not copies, copies
+    held = sum(x.size * x.dtype.itemsize
+               for x in jax.tree.leaves((state.params, state.opt_state)))
+    assert held > 1.6e9
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
+
+
+@pytest.mark.slow
+def test_config5_cell_train_step_donates_its_state(chip):
+    """`c5-train-1chip`'s program (benchmark/configs: Adam, clip 1.0,
+    dropout 0.2, stateful, device data, B=64 T=128, K=4 steps a dispatch)
+    for the described chip: both kernels of every layer, inside HBM, and
+    the state updated in place."""
+    from lstm_tensorspark_tpu.data.device_dataset import DeviceLMData
+    from lstm_tensorspark_tpu.models import lm_loss
+    from lstm_tensorspark_tpu.models.lstm_lm import init_carries
+    from lstm_tensorspark_tpu.train import (
+        make_device_lm_train_step, make_optimizer)
+    from lstm_tensorspark_tpu.train.loop import init_train_state
+
+    B, T, n_windows = 64, 128, 100
+    cfg = LMConfig(**CONFIG5, logits_dtype="bfloat16", use_pallas=True,
+                   dropout=0.2)
+    optimizer = make_optimizer("adam", 1e-3, clip_norm=1.0)
+
+    def loss_fn(params, batch, rng, carries):
+        return lm_loss(params, batch, cfg, carries=carries, dropout_rng=rng,
+                       deterministic=False)
+
+    state = _on(chip, jax.eval_shape(lambda: init_train_state(
+        init_lm(jax.random.PRNGKey(0), cfg), optimizer,
+        jax.random.PRNGKey(1), carries=init_carries(cfg, B))))
+    stream = jax.ShapeDtypeStruct((B, n_windows * T), jnp.int32)
+    arrays = _on(chip, {"streams": stream, "shifted": stream})
+    data = DeviceLMData(arrays=arrays, batch_size=B, seq_len=T,
+                        n_windows=n_windows)
+    step = make_device_lm_train_step(
+        loss_fn, optimizer, data, steps_per_call=4, stateful=True)
+    w0 = _on(chip, jax.ShapeDtypeStruct((), jnp.int32))
+    with _kernels_selectable():
+        compiled = step.lower(state, arrays, w0).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2 * cfg.num_layers
+    _fits_hbm(compiled)
+    _state_updated_in_place(compiled, state)
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("program", ["prefill", "decode", "decode_window"])
 def test_config5_serve_programs_compile(chip, program):
@@ -312,7 +367,8 @@ def test_config5_dp4_train_step_compiles():
     described chips before a four-chip call is paid for: the shard_map
     device-data step over a ("data",) mesh of 4, B=32 global (8 rows and
     both fused kernels per chip), the gradient all-reduce in the compiled
-    text, and the per-device memory inside one chip's HBM."""
+    text, the per-device memory inside one chip's HBM, and the donated
+    state updated in place."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -356,6 +412,7 @@ def test_config5_dp4_train_step_compiles():
     assert text.count("tpu_custom_call") >= 2 * cfg.num_layers
     assert "all-reduce" in text
     _fits_hbm(compiled)
+    _state_updated_in_place(compiled, state)
 
 
 # ---- the decoder family: paged latent attention, grouped experts -------

@@ -57,7 +57,8 @@ def test_device_data_matches_host_fed_training():
         return lm_loss(p, b, cfg)
 
     opt = make_optimizer("sgd", 0.3)
-    params = init_lm(jax.random.PRNGKey(0), cfg)
+    # a host copy: both runs start from it, and each step donates its state
+    params = jax.device_get(init_lm(jax.random.PRNGKey(0), cfg))
 
     host_step = make_multi_train_step(loss_fn, opt)
     s_host = init_train_state(params, opt, jax.random.PRNGKey(1))
@@ -98,7 +99,8 @@ def test_device_data_dp_matches_single():
         return lm_loss(p, b, cfg)
 
     opt = make_optimizer("sgd", 0.3)
-    params = init_lm(jax.random.PRNGKey(0), cfg)
+    # a host copy: both runs start from it, and each step donates its state
+    params = jax.device_get(init_lm(jax.random.PRNGKey(0), cfg))
 
     data1 = stage_lm_data(tokens, B, T)
     step1 = make_device_lm_train_step(loss_fn, opt, data1, steps_per_call=K)
@@ -134,8 +136,9 @@ def test_device_data_stateful_matches_host():
         return lm_loss(p, b, cfg, carries=carries)
 
     opt = make_optimizer("sgd", 0.3)
-    params = init_lm(jax.random.PRNGKey(0), cfg)
-    carries0 = init_carries(cfg, B)
+    # a host copy: both runs start from it, and each step donates its state
+    params = jax.device_get(init_lm(jax.random.PRNGKey(0), cfg))
+    carries0 = jax.device_get(init_carries(cfg, B))
 
     host_step = make_multi_train_step(loss_fn, opt, stateful=True)
     s_host = init_train_state(params, opt, jax.random.PRNGKey(1), carries=carries0)

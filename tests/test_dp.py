@@ -28,7 +28,8 @@ def _setup():
         return lm_loss(params, batch, cfg)
 
     opt = make_optimizer("sgd", 0.3)
-    params = init_lm(jax.random.PRNGKey(0), cfg)
+    # a host copy: the runs of one test start from it, each donating its own
+    params = jax.device_get(init_lm(jax.random.PRNGKey(0), cfg))
     rng = np.random.RandomState(0)
     batches = [
         {
@@ -100,7 +101,8 @@ def test_stateful_dp_matches_single():
         return lm_loss(params, batch, cfg, carries=carries)
 
     opt = make_optimizer("sgd", 0.3)
-    params = init_lm(jax.random.PRNGKey(0), cfg)
+    # a host copy: the runs of one test start from it, each donating its own
+    params = jax.device_get(init_lm(jax.random.PRNGKey(0), cfg))
     rng = np.random.RandomState(0)
     batches = [
         {

@@ -49,7 +49,9 @@ def _setup(stateful=False):
     train_tokens = _tokens(B * T * 8 + 1)
     valid_tokens = _tokens(B * T * 3 + 1, seed=1)
     carries0 = init_carries(cfg, B) if stateful else None
-    state = init_train_state(params, opt, jax.random.PRNGKey(1), carries=carries0)
+    # a host copy: tests hand it to two steps, and each donates what it gets
+    state = jax.device_get(
+        init_train_state(params, opt, jax.random.PRNGKey(1), carries=carries0))
     return cfg, loss_fn, opt, state, train_tokens, valid_tokens
 
 

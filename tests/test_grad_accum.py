@@ -15,7 +15,8 @@ from lstm_tensorspark_tpu.train.loop import init_train_state
 
 def _setup(B=8, T=12, V=23, H=16):
     cfg = LMConfig(vocab_size=V, hidden_size=H, num_layers=2)
-    params = init_lm(jax.random.PRNGKey(0), cfg)
+    # a host copy: each test builds two states from it, and each step donates its own
+    params = jax.device_get(init_lm(jax.random.PRNGKey(0), cfg))
     opt = make_optimizer("sgd", 0.5)
 
     def loss_fn(p, batch, rng):

@@ -37,7 +37,8 @@ def _setup(stateful=False):
             return lm_loss(params, batch, cfg)
 
     opt = make_optimizer("momentum", 0.3, momentum=0.9)
-    params = init_lm(jax.random.PRNGKey(0), cfg)
+    # a host copy: every run below places its own, and its step donates it
+    params = jax.device_get(init_lm(jax.random.PRNGKey(0), cfg))
     rng = np.random.RandomState(0)
     batches = [
         {

@@ -34,7 +34,9 @@ def _single_device_run(cfg, params, batches, opt):
         return lm_loss(p, b, cfg)
 
     step = make_train_step(loss_fn, opt)
-    s = init_train_state(params, opt, jax.random.PRNGKey(1))
+    # a host copy: the step donates its state, and the caller's ``params``
+    # also start the pipeline run this one is compared with
+    s = init_train_state(jax.device_get(params), opt, jax.random.PRNGKey(1))
     losses = []
     for b in batches:
         s, m = step(s, b)

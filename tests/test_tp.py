@@ -39,7 +39,9 @@ def _setup(num_layers=2):
 
 def _single_losses(loss_fn, opt, params, batches):
     step = make_train_step(loss_fn, opt)
-    s = init_train_state(params, opt, jax.random.PRNGKey(1))
+    # a host copy: the step donates its state, and the caller's ``params``
+    # also start the tensor-parallel run this one is compared with
+    s = init_train_state(jax.device_get(params), opt, jax.random.PRNGKey(1))
     out = []
     for b in batches:
         s, m = step(s, b)

@@ -19,7 +19,9 @@ from lstm_tensorspark_tpu.train.loop import init_train_state
 def _run(loss_fn, params, batches, opt, *, tp_specs=None, mesh=None):
     if tp_specs is None:
         step = make_train_step(loss_fn, opt)
-        s = init_train_state(params, opt, jax.random.PRNGKey(1))
+        # a host copy: the step donates its state, and the caller's
+        # ``params`` also start the sharded run this one is compared with
+        s = init_train_state(jax.device_get(params), opt, jax.random.PRNGKey(1))
     else:
         step = make_tp_train_step(loss_fn, opt, mesh, params,
                                   param_specs=tp_specs, donate=False)

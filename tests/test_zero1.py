@@ -27,7 +27,9 @@ V, H, B, T = 23, 16, 16, 12
 
 def _setup(opt_name, lr, **opt_kw):
     cfg = LMConfig(vocab_size=V, hidden_size=H, num_layers=2)
-    params = init_lm(jax.random.PRNGKey(0), cfg)
+    # a host copy: the DP and the ZeRO-1 run both start from it, and each
+    # step donates its own state
+    params = jax.device_get(init_lm(jax.random.PRNGKey(0), cfg))
 
     def loss_fn(p, b, r):
         return lm_loss(p, b, cfg)
@@ -141,8 +143,9 @@ def test_zero1_padding_edges(n_extra):
     # base 16*dp params + n_extra => pad = (-n_extra) % dp
     sizes = [16 * dp, n_extra] if n_extra else [16 * dp]
     keys = jax.random.split(jax.random.PRNGKey(7), len(sizes))
-    params = {f"w{i}": jax.random.normal(k, (s,), jnp.float32)
-              for i, (s, k) in enumerate(zip(sizes, keys))}
+    params = jax.device_get(
+        {f"w{i}": jax.random.normal(k, (s,), jnp.float32)
+         for i, (s, k) in enumerate(zip(sizes, keys))})
 
     xs = jax.random.normal(jax.random.PRNGKey(8), (B, sum(sizes)), jnp.float32)
 

@@ -9,6 +9,11 @@ by name (run with ``-s`` to see the table).
 What it guards (PERF.md section 6, PR 27): with autodiff's backward the two
 transposed head matmuls each asked for dlogits in a layout of their own and
 XLA wrote the 819 MB array twice (``copy.804``, 2.5 ms of a 43.8 ms step).
+
+And of the same executable, after it ran (PERF.md section 6, PR 31): the
+train state is donated into it, so it copies none of the six 205 MB arrays
+of the embedding and the head at entry, and it aliases the parameters and
+the optimizer's state to its outputs.
 """
 
 import glob
@@ -72,9 +77,8 @@ def vocab_relayouts(hlo_text: str, min_bytes: int = BIG) -> list[str]:
     """``copy``/``transpose`` instructions of a compiled program whose
     result (the operand's dims, permuted at most) is a ``[...,V,...]``
     array of ``min_bytes`` or more. Copies of the program's own arguments
-    are left out: without donation the entry copies each parameter and
-    optimizer moment once a dispatch (ROADMAP C12), which is not the
-    step's doing."""
+    are left out: they are `test_config5_step_updates_its_state_in_place`'s
+    to find."""
     arguments = set(re.findall(r"%([\w.\-]+) = \S+ parameter\(", hlo_text))
     found = []
     for line in hlo_text.splitlines():
@@ -111,17 +115,28 @@ def device_op_ms(run, steps: int) -> dict[str, float]:
     return out
 
 
-def test_config5_step_holds_no_vocab_relayout():
+@pytest.fixture(scope="module")
+def cell():
+    """The compiled step and the state it is on: every dispatch donates
+    the state it is handed, so the tests thread one through."""
     step, state, arrays, w0 = build_step()
     compiled = step.lower(state, arrays, w0).compile()
-    relayouts = vocab_relayouts(compiled.as_text())
+    on = {"state": state, "calls": 0}
 
     def dispatches(n):
-        s = state
-        for i in range(n):
-            s, metrics = compiled(s, arrays, w0 + i * K)
-        return float(metrics["loss"])  # the sync: the work is finished
+        for _ in range(n):
+            handed = on["state"]
+            on["state"], metrics = compiled(handed, arrays, w0 + on["calls"] * K)
+            on["calls"] += 1
+        loss = float(metrics["loss"])  # the sync: the work is finished
+        return loss, handed
 
+    return compiled, dispatches
+
+
+def test_config5_step_holds_no_vocab_relayout(cell):
+    compiled, dispatches = cell
+    relayouts = vocab_relayouts(compiled.as_text())
     dispatches(2)  # warm
     ops = device_op_ms(lambda: dispatches(3), steps=3 * K)
     total = sum(ops.values())
@@ -131,3 +146,25 @@ def test_config5_step_holds_no_vocab_relayout():
         if str(V) in name and ms >= 0.2:
             print(f"  {ms:6.2f} ms  {re.sub(r'{[^{}]*}', '', name)[:150]}")
     assert not relayouts, "\n".join(relayouts)
+
+
+def test_config5_step_updates_its_state_in_place(cell):
+    compiled, dispatches = cell
+    loss, handed = dispatches(3)
+    assert math.isfinite(loss)
+    leaves = jax.tree.leaves(handed)
+    assert all(x.is_deleted() for x in leaves), sum(
+        not x.is_deleted() for x in leaves)
+    copies = re.findall(r"= f32\[(?:50000,1024|1024,50000)\]\S* copy\(",
+                        compiled.as_text())
+    assert not copies, copies
+    held = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(
+        (handed.params, handed.opt_state)))
+    memory = compiled.memory_analysis()
+    print(f"\nconfig-5 step: {memory.alias_size_in_bytes / 1e9:.3f} GB aliased "
+          f"of {held / 1e9:.3f} GB of parameters and moments; arguments "
+          f"{memory.argument_size_in_bytes / 1e9:.3f}, outputs "
+          f"{memory.output_size_in_bytes / 1e9:.3f}, temporaries "
+          f"{memory.temp_size_in_bytes / 1e9:.3f} GB")
+    assert held > 1.6e9
+    assert memory.alias_size_in_bytes >= held
