@@ -234,10 +234,10 @@ def last_json(text: str, note: str) -> dict:
 
 def train_argv(workdir: str, sizes: Sizes, seed: int, *, jsonl: str,
                extra: list[str]) -> list[str]:
-    """configs/wikitext103_dp.sh, at the one-chip shape of bench.py
-    (B=32, T=64), without --remat-chunk so the fused backward kernel runs
-    as well as the forward, in the dispatch form README.md and bench.py
-    use (--device-data --steps-per-call)."""
+    """configs/wikitext103_dp.sh, at a one-chip shape (B=32, T=64),
+    without --remat-chunk so the fused backward kernel runs as well as
+    the forward, in the dispatch form README.md uses (--device-data
+    --steps-per-call)."""
     return [
         "--dataset", "wikitext103", *sizes.model_flags,
         "--batch-size", str(sizes.batch), "--seq-len", str(sizes.seq_len),

@@ -31,7 +31,7 @@ def build_parser() -> argparse.ArgumentParser:
         prog="lstm_tensorspark_tpu",
         description="TPU-native LSTM training (LSTM-TensorSpark capabilities, no Spark)",
         epilog="Inference serving is a subcommand with its own flags: "
-               "`... serve {--selftest | --loadgen | --http}` — run "
+               "`... serve {--selftest | --http}` — run "
                "`... serve --help` (dispatched before this parser, so "
                "`serve` must be the first argument).",
     )
@@ -159,7 +159,7 @@ def build_parser() -> argparse.ArgumentParser:
                    help="add live model-TFLOP/s and MFU (vs the device's "
                         "bf16 peak, utils/flops.py) to every throughput log "
                         "record — matmul-only accounting, train = 3x "
-                        "forward, same formulas as bench.py")
+                        "forward")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--anomaly-limit", type=int, default=0,
                    help="abort with the dedicated anomaly exit code "
@@ -1288,20 +1288,18 @@ def _run_lm_advanced(args, logger, cfg, data, seq_len) -> int:
 
 def build_serve_parser() -> argparse.ArgumentParser:
     """``serve`` subcommand: the inference engine's CLI surface (serve/)."""
+    from .serve.engine import DECODE_KERNELS
+
     p = argparse.ArgumentParser(
         prog="lstm_tensorspark_tpu serve",
         description="continuous-batching LM inference (serve/): HTTP "
-                    "endpoint, --selftest parity check, --loadgen "
-                    "latency/throughput report",
+                    "endpoint, --selftest parity check",
     )
     mode = p.add_mutually_exclusive_group()
     mode.add_argument("--selftest", action="store_true",
                       help="decode a batch of concurrent sessions and "
                            "verify greedy output is token-identical to "
                            "models/generate.py; rc 0 on PASS")
-    mode.add_argument("--loadgen", action="store_true",
-                      help="offline load generation: p50/p99 latency, "
-                           "tokens/sec, concurrency sweep (--compare)")
     mode.add_argument("--http", action="store_true",
                       help="run the JSON HTTP endpoint (default mode)")
     # --- model (must match the producing training run) ---
@@ -1329,7 +1327,7 @@ def build_serve_parser() -> argparse.ArgumentParser:
                         "on, --tiered-cache on, --session-dir, "
                         "--speculative, --mesh-shards, --replicas > 1, "
                         "--checkpoint-dir, --registry-dir, --autotune on, "
-                        "--loadgen, sampled decoding) are REFUSED")
+                        "sampled decoding) are REFUSED")
     p.add_argument("--weights-dtype", type=str, default="bfloat16",
                    choices=["bfloat16", "float32"],
                    help="decoder: dtype of the seeded weights and of the "
@@ -1352,17 +1350,14 @@ def build_serve_parser() -> argparse.ArgumentParser:
                    help="decoder: rows one prefill dispatch may pack onto "
                         "its flat token axis")
     # --- engine / batcher (docs/OPERATIONS.md "Serving") ---
-    p.add_argument("--replicas", type=str, default="1",
+    p.add_argument("--replicas", type=_positive_int, default=1,
                    help="data-parallel serving replicas (serve/router.py): "
                         "N engine+scheduler replicas behind one admission "
                         "router with session→replica affinity — thread-per-"
                         "replica on CPU, device-per-replica when multiple "
                         "accelerators exist. --num-slots/--max-active are "
                         "PER REPLICA; --queue-size is the global admission "
-                        "bound. With --loadgen a comma list (e.g. '1,2') "
-                        "runs the replica-scaling comparison instead: the "
-                        "same workload at each level, aggregate tokens/s + "
-                        "greedy parity reported (BENCH_serve_r02.json)")
+                        "bound")
     p.add_argument("--num-slots", type=int, default=64,
                    help="state-cache slots (= max resident sessions)")
     p.add_argument("--prefill-buckets", type=str, default="8,16,32,64,128",
@@ -1453,17 +1448,14 @@ def build_serve_parser() -> argparse.ArgumentParser:
                         "latency; see docs/OPERATIONS.md). Every window "
                         "size is one XLA compile key per batch bucket.")
     p.add_argument("--decode-kernel", type=str, default="auto",
+                   choices=DECODE_KERNELS,
                    help="decode-window kernel: 'scan' (the lax.scan "
                         "window), 'pallas' (fused VMEM-resident window "
                         "kernel, ops/pallas_decode.py — interpreter mode "
                         "off-TPU, token-identical but slow there), or "
                         "'auto' (pallas on TPU when the VMEM plan fits, "
-                        "scan otherwise). With --loadgen a comma list "
-                        "(e.g. 'pallas,scan') runs the kernel comparison "
-                        "instead: same workload per kernel, tokens/s + "
-                        "ITL deltas + greedy parity "
-                        "(BENCH_serve_r05.json). See docs/OPERATIONS.md "
-                        "for when to pin 'scan'.")
+                        "scan otherwise). See docs/OPERATIONS.md for when "
+                        "to pin 'scan'.")
     p.add_argument("--prefix-cache", type=str, default=None,
                    choices=["on", "off"],
                    help="shared-prompt prefix-state cache: fresh prompts "
@@ -1649,111 +1641,9 @@ def build_serve_parser() -> argparse.ArgumentParser:
     p.add_argument("--top-k", type=int, default=None)
     p.add_argument("--top-p", type=float, default=None)
     p.add_argument("--greedy", action="store_true")
-    # --- loadgen workload ---
+    # --- selftest workload ---
     p.add_argument("--sessions", type=int, default=8)
-    p.add_argument("--requests-per-session", type=int, default=4)
-    p.add_argument("--prompt-len", type=int, default=8)
     p.add_argument("--max-new-tokens", type=int, default=16)
-    p.add_argument("--mode", type=str, default="closed",
-                   choices=["closed", "open"])
-    p.add_argument("--rate", type=float, default=None,
-                   help="open-loop arrival rate (req/s)")
-    p.add_argument("--arrival", type=str, default="fixed",
-                   choices=["fixed", "burst", "sine"],
-                   help="open-loop arrival shape: 'fixed' = constant "
-                        "--rate; 'burst' = --burst-n simultaneous "
-                        "arrivals every --burst-gap seconds; 'sine' = "
-                        "diurnal-shaped rate --rate*(1+amp*sin(2pi*t/"
-                        "period)) — the phase-shifting workloads the "
-                        "autotuner bench drives")
-    p.add_argument("--arrival-trace", type=str, default=None,
-                   help="open-loop trace replay: a file of sorted "
-                        "seconds-from-start arrival offsets, one per "
-                        "line ('#' comments ignored); a trace shorter "
-                        "than the workload loops, shifted by its span. "
-                        "Overrides --arrival/--rate")
-    p.add_argument("--burst-n", type=int, default=8,
-                   help="--arrival burst: requests per burst")
-    p.add_argument("--burst-gap", type=float, default=0.5,
-                   help="--arrival burst: seconds between burst starts")
-    p.add_argument("--sine-period", type=float, default=2.0,
-                   help="--arrival sine: modulation period (seconds)")
-    p.add_argument("--sine-amp", type=float, default=0.8,
-                   help="--arrival sine: modulation amplitude in [0, 1)")
-    p.add_argument("--compare", type=str, default=None,
-                   help="closed-loop concurrency sweep levels (default "
-                        "1,8; empty string: single run at --sessions)")
-    p.add_argument("--shared-prefix-len", type=int, default=0,
-                   help="loadgen: every prompt shares its first N tokens "
-                        "(the shared-system-prompt workload the prefix "
-                        "cache targets); 0 = fully random prompts")
-    p.add_argument("--inject-prompt-len", type=int, default=0,
-                   help="loadgen: submit ONE extra cold request with a "
-                        "prompt this long mid-run (head-of-line-blocking "
-                        "probe, reported separately); 0 = off")
-    p.add_argument("--inject-delay", type=float, default=0.25,
-                   help="seconds into the run to submit the injected "
-                        "request")
-    p.add_argument("--workload", type=str, default="random",
-                   choices=["random", "template-mix"],
-                   help="loadgen prompt shape: 'random' = the classic "
-                        "per-session random prompts; 'template-mix' = "
-                        "tenant preamble x few-shot template x unique "
-                        "suffix (--tenants/--templates/--preamble-len/"
-                        "--template-len/--suffix-len) — the shared-"
-                        "structure workload the prefix-state fabric is "
-                        "gated on (radix lookup reuses the preamble+"
-                        "template prefix; exact-match only full re-"
-                        "prompts). Runs on a bounded worker pool, so "
-                        "--sessions can be 10k+")
-    p.add_argument("--tenants", type=int, default=4,
-                   help="--workload template-mix: distinct tenant "
-                        "preambles")
-    p.add_argument("--templates", type=int, default=25,
-                   help="--workload template-mix: few-shot templates per "
-                        "tenant")
-    p.add_argument("--preamble-len", type=int, default=128,
-                   help="--workload template-mix: tenant preamble tokens")
-    p.add_argument("--template-len", type=int, default=32,
-                   help="--workload template-mix: template tokens")
-    p.add_argument("--suffix-len", type=int, default=8,
-                   help="--workload template-mix: unique per-session "
-                        "suffix tokens")
-    p.add_argument("--workers", type=int, default=32,
-                   help="--workload template-mix: bounded worker-pool "
-                        "size (closed-loop threads)")
-    p.add_argument("--idle-churn", action="store_true",
-                   help="loadgen: long-tail multi-tenant workload — "
-                        "--sessions LIVE kept sessions (size it ~10x "
-                        "--num-slots) continued by Zipf-popularity draws "
-                        "(--zipf-s), so the idle tail is LRU-evicted and "
-                        "must fill from the tiers (or re-prefill its full "
-                        "history with --tiered-cache off). Reports "
-                        "per-tier hit rates, re-prefill cost and hot-set "
-                        "tokens/s — the tiered-cache gate workload")
-    p.add_argument("--zipf-s", type=float, default=1.1,
-                   help="--idle-churn popularity exponent: session rank r "
-                        "is drawn with weight (r+1)^-s (higher = hotter "
-                        "hot set)")
-    p.add_argument("--priority-frac", type=float, default=1.0,
-                   help="loadgen: fraction of traffic submitted as the "
-                        "priority class (the rest best_effort, "
-                        "interleaved) — per-class shed/retry/TTFT "
-                        "percentiles land in the report's 'classes' "
-                        "section")
-    p.add_argument("--deadline-s", type=float, default=0,
-                   help="loadgen: per-request deadline in seconds "
-                        "(server-side; expiry = honest timeout with "
-                        "partial output). 0 = none")
-    p.add_argument("--retry-max", type=int, default=0,
-                   help="loadgen: retry a 429 shed up to N times, "
-                        "sleeping the server's Retry-After floored by "
-                        "the shared capped exponential backoff + jitter "
-                        "(resilience/backoff.py). 0 = count sheds, no "
-                        "retry")
-    p.add_argument("--json", type=str, default=None,
-                   help="also write the loadgen report (machine-readable "
-                        "JSON) to this path")
     # --- endpoint / observability ---
     p.add_argument("--host", type=str, default="127.0.0.1")
     p.add_argument("--port", type=int, default=8000)
@@ -1854,6 +1744,13 @@ def _autotune_chunk_choices(args, chunk: int | None) -> tuple[int, ...] | None:
     return tuple(sorted(derived | {chunk}))
 
 
+def _positive_int(text: str) -> int:
+    n = int(text)
+    if n < 1:
+        raise argparse.ArgumentTypeError(f"must be >= 1, got {n}")
+    return n
+
+
 def _parse_buckets(spec: str, flag: str) -> tuple[int, ...]:
     try:
         buckets = tuple(int(x) for x in spec.split(",") if x.strip())
@@ -1864,64 +1761,19 @@ def _parse_buckets(spec: str, flag: str) -> tuple[int, ...]:
     return buckets
 
 
-def _parse_replicas(spec: str, flag: str = "--replicas") -> tuple[int, ...]:
-    try:
-        levels = tuple(int(x) for x in spec.split(",") if x.strip())
-    except ValueError:
-        raise SystemExit(f"{flag}: expected an int or comma-separated ints, "
-                         f"got {spec!r}")
-    if not levels or any(n < 1 for n in levels):
-        raise SystemExit(f"{flag}: need positive replica counts, got {spec!r}")
-    return levels
-
-
-def _parse_decode_kernels(spec: str) -> tuple[str, ...]:
-    kernels = tuple(dict.fromkeys(
-        k.strip() for k in spec.split(",") if k.strip()))
-    from .serve.engine import DECODE_KERNELS
-
-    bad = [k for k in kernels if k not in DECODE_KERNELS]
-    if not kernels or bad:
-        raise SystemExit(
-            f"--decode-kernel: expected one of {DECODE_KERNELS} (or a "
-            f"comma list for the --loadgen comparison), got {spec!r}")
-    return kernels
-
-
-def _single_decode_kernel(args) -> str:
-    kernels = _parse_decode_kernels(getattr(args, "decode_kernel", "auto"))
-    if len(kernels) > 1:
-        raise SystemExit(
-            f"--decode-kernel {args.decode_kernel!r}: a comma list is the "
-            "--loadgen comparison mode; this mode needs a single kernel")
-    return kernels[0]
-
-
-def _single_replica_count(args, mode: str) -> int:
-    levels = _parse_replicas(args.replicas)
-    if len(levels) > 1:
-        raise SystemExit(
-            f"--replicas {args.replicas!r}: a comma list is the --loadgen "
-            f"comparison mode; {mode} needs a single count")
-    return levels[0]
-
-
-def _build_serve_stack(args, n_replicas: int = 1, registry=None):
+def _build_serve_stack(args, n_replicas: int = 1):
     """(params, cfg, started-server) from the serve flags.
 
     ``n_replicas`` > 1 builds one engine per replica (each with its own
     state/prefix caches and compiled programs) behind the admission
     router; when the host exposes multiple accelerators the engines are
     committed round-robin across ``jax.devices()`` (device-per-replica),
-    otherwise they share the one device (thread-per-replica).
-    ``registry`` overrides the --telemetry-selected registry (the replica
-    sweep scopes one fresh registry per level so the per-level reports
-    don't accumulate each other's samples)."""
+    otherwise they share the one device (thread-per-replica)."""
     from .models import LMConfig, init_lm
     from .serve import ServeEngine, ServeServer
 
     if getattr(args, "model_file", None):
-        return _build_decoder_stack(args, n_replicas, registry)
+        return _build_decoder_stack(args, n_replicas)
     # the two caches default ON for this family (None = not given)
     args.prefix_cache = args.prefix_cache or "on"
     args.tiered_cache = args.tiered_cache or "on"
@@ -1959,10 +1811,8 @@ def _build_serve_stack(args, n_replicas: int = 1, registry=None):
         params = jax.device_get(params)
     from .obs import NULL_REGISTRY, REGISTRY
 
-    if registry is None:
-        registry = (NULL_REGISTRY
-                    if getattr(args, "telemetry", "on") == "off"
-                    else REGISTRY)
+    registry = (NULL_REGISTRY if getattr(args, "telemetry", "on") == "off"
+                else REGISTRY)
     devices = jax.devices()
     shards = int(getattr(args, "mesh_shards", 1) or 1)
     if shards < 1:
@@ -2013,7 +1863,7 @@ def _build_serve_stack(args, n_replicas: int = 1, registry=None):
             # under (requests with no 'model' field route here)
             model_id=getattr(args, "model_id", "default"),
             replica=i,
-            decode_kernel=_single_decode_kernel(args),
+            decode_kernel=args.decode_kernel,
             # one registry argument scopes the whole serve stack's
             # telemetry (engine, caches, batcher, router, /metrics);
             # off = no-op instruments
@@ -2149,7 +1999,6 @@ def _refuse_for_decoder(args, n_replicas: int) -> None:
         "--checkpoint-dir": bool(args.checkpoint_dir),
         "--registry-dir": bool(getattr(args, "registry_dir", None)),
         "--autotune on": getattr(args, "autotune", "off") == "on",
-        "--loadgen": bool(args.loadgen),
         "--decode-kernel pallas/scan": args.decode_kernel != "auto",
         "sampled decoding (pass --greedy)": not args.greedy,
     }
@@ -2162,7 +2011,7 @@ def _refuse_for_decoder(args, n_replicas: int) -> None:
             "tier, no draft model yet — ROADMAP.md.)")
 
 
-def _build_decoder_stack(args, n_replicas: int = 1, registry=None):
+def _build_decoder_stack(args, n_replicas: int = 1):
     """(params, cfg, server) for ``--model-file``: the same `ServeServer`
     -> router -> `Batcher` stack over a `DecoderEngine` and its paged
     latent cache. Weights are random from the FILE's seed
@@ -2174,10 +2023,8 @@ def _build_decoder_stack(args, n_replicas: int = 1, registry=None):
 
     _refuse_for_decoder(args, n_replicas)
     cfg, doc = decoder.load_model_file(args.model_file)
-    if registry is None:
-        registry = (NULL_REGISTRY
-                    if getattr(args, "telemetry", "on") == "off"
-                    else REGISTRY)
+    registry = (NULL_REGISTRY if getattr(args, "telemetry", "on") == "off"
+                else REGISTRY)
     page = args.page_size
     token_bytes = cfg.latent_width * 2 * cfg.num_hidden_layers
     num_pages = int(args.latent_pool_gib * 2 ** 30) // (page * token_bytes)
@@ -2235,8 +2082,7 @@ def _serve_selftest(args) -> int:
 
     if getattr(args, "model_file", None):
         return _serve_selftest_decoder(args)
-    params, cfg, server = _build_serve_stack(
-        args, _single_replica_count(args, "--selftest"))
+    params, cfg, server = _build_serve_stack(args, args.replicas)
     rng = np.random.RandomState(args.seed)
     lengths = [3, 5, 8, 13, 2, 7][: max(args.sessions, 2)]
     while len(lengths) < args.sessions:
@@ -2386,396 +2232,15 @@ def _serve_selftest_decoder(args) -> int:
     return 0 if ok else 1
 
 
-def _serve_loadgen(args) -> int:
-    import json
-
-    from .serve import run_loadgen
-    from .serve.loadgen import concurrency_sweep
-
-    # fail in milliseconds, not after the full warmup lattice compiles
-    if args.shared_prefix_len and args.shared_prefix_len >= args.prompt_len:
-        print(f"error: --shared-prefix-len {args.shared_prefix_len} must be "
-              f"< --prompt-len {args.prompt_len} (each prompt needs >= 1 "
-              "unshared token)", file=sys.stderr)
-        return 2
-    if (args.arrival != "fixed" or args.arrival_trace) and args.mode != "open":
-        print("error: --arrival burst/sine and --arrival-trace shape "
-              "OPEN-loop arrivals; add --mode open", file=sys.stderr)
-        return 2
-    kernels = _parse_decode_kernels(args.decode_kernel)
-    replica_levels = _parse_replicas(args.replicas)
-    if len(kernels) > 1:
-        if len(replica_levels) > 1 or args.idle_churn:
-            print("error: --decode-kernel comparison runs at one replica "
-                  "count without --idle-churn", file=sys.stderr)
-            return 2
-        return _serve_loadgen_kernel_sweep(args, kernels,
-                                           replica_levels[0])
-    if args.idle_churn:
-        if len(replica_levels) > 1:
-            print("error: --idle-churn runs at one replica count "
-                  "(--replicas N, not a comma list)", file=sys.stderr)
-            return 2
-        return _serve_loadgen_longtail(args, replica_levels[0])
-    if getattr(args, "workload", "random") == "template-mix":
-        if len(replica_levels) > 1:
-            print("error: --workload template-mix runs at one replica "
-                  "count (--replicas N, not a comma list)",
-                  file=sys.stderr)
-            return 2
-        return _serve_loadgen_template_mix(args, replica_levels[0])
-    if len(replica_levels) > 1:
-        return _serve_loadgen_replica_sweep(args, replica_levels)
-    _, cfg, server = _build_serve_stack(args, replica_levels[0])
-    sampling = _serve_sampling(args)
-    # the prefix/inject probes are single-run workloads (the sweep does not
-    # thread them through) — never let the default --compare silently drop
-    # them, and never silently drop an EXPLICIT --compare either
-    probe_run = bool(args.shared_prefix_len or args.inject_prompt_len)
-    if probe_run and args.compare:
-        print("note: --shared-prefix-len/--inject-prompt-len run single-run "
-              f"at --sessions {args.sessions}; ignoring --compare "
-              f"{args.compare!r}", file=sys.stderr)
-    compare = "1,8" if args.compare is None else args.compare
-    with server:
-        if compare and args.mode == "closed" and not probe_run:
-            levels = tuple(
-                sorted({int(x) for x in compare.split(",") if x.strip()}
-                       | {args.sessions})
-            )
-            out = concurrency_sweep(
-                server, vocab_size=cfg.vocab_size, levels=levels,
-                requests_per_session=args.requests_per_session,
-                prompt_len=args.prompt_len,
-                max_new_tokens=args.max_new_tokens,
-                sampling=sampling, seed=args.seed,
-            )
-        else:
-            lens = {args.prompt_len}
-            # an unchunked inject longer than the largest bucket has no
-            # program to warm — admission rejects it and loadgen reports
-            # it under injected["error"]; warming it would just crash
-            if args.inject_prompt_len and (
-                    server.batcher.prefill_chunk is not None
-                    or args.inject_prompt_len
-                    <= server.batcher.engine.max_prompt_len):
-                lens.add(args.inject_prompt_len)
-            server.warmup(sampling, prompt_lens=tuple(lens))
-            out = run_loadgen(
-                server, vocab_size=cfg.vocab_size, sessions=args.sessions,
-                requests_per_session=args.requests_per_session,
-                prompt_len=args.prompt_len,
-                max_new_tokens=args.max_new_tokens,
-                sampling=sampling, mode=args.mode, rate=args.rate,
-                seed=args.seed, shared_prefix_len=args.shared_prefix_len,
-                inject_prompt_len=args.inject_prompt_len,
-                inject_delay_s=args.inject_delay,
-                priority_frac=args.priority_frac,
-                deadline_s=args.deadline_s or None,
-                retry_max=args.retry_max,
-                arrival=args.arrival,
-                arrival_times=_read_arrival_trace(args.arrival_trace),
-                burst_n=args.burst_n, burst_gap_s=args.burst_gap,
-                sine_period_s=args.sine_period, sine_amp=args.sine_amp,
-            )
-    # aggregate across replicas — a --replicas N run spreads traffic, and
-    # replica-0-only counters would silently halve every number vs /stats
-    from .serve.loadgen import prefix_totals
-
-    compiles_by_key: dict = {}
-    cache_tot: dict = {}
-    for rep in server.replicas:
-        es = rep.engine.stats()
-        for k, v in es["compiles"].items():
-            compiles_by_key[k] = compiles_by_key.get(k, 0) + v
-        for k, v in es["cache"].items():
-            if k == "slots" and cache_tot:
-                continue  # per-replica config, not a counter to sum
-            cache_tot[k] = cache_tot.get(k, 0) + v
-    prefix_tot = prefix_totals(server)
-    out["engine"] = {
-        "compiles_prefill": sum(
-            r.engine.num_compiles("prefill") for r in server.replicas),
-        "compiles_prefill_chunk": sum(
-            r.engine.num_compiles("prefill_chunk") for r in server.replicas),
-        "compiles_decode": sum(
-            r.engine.num_compiles("decode") for r in server.replicas),
-        "compiles_decode_window": sum(
-            r.engine.num_compiles("decode_window") for r in server.replicas),
-        "compiles_by_key": compiles_by_key,
-        "prefix_cache": prefix_tot,
-        **cache_tot,
-    }
-    bstats = server.stats()["batcher"]  # the cross-replica aggregate
-    out["batcher"] = {
-        k: bstats[k]
-        for k in ("window_ladder", "windows_dispatched", "windows_pipelined",
-                  "prefill_chunk", "prefill_chunks_dispatched",
-                  "prefix_resumed", "prefix_tokens_saved")
-    }
-    # absolute router counters (incl. retired list) under a DISTINCT key —
-    # each run report's "router" section stays the per-run delta view
-    out["router_totals"] = server.router.stats()
-    # server-side registry view (histogram p50/p99 + counters) so the
-    # loadgen JSON carries both measurement sides — see also the per-run
-    # "server_histograms" inside each report
-    out["server_metrics"] = server.metrics_summary()
-    print(json.dumps(out))
-    # the one-line human summary (stats live in the JSON above)
-    r = out.get("levels", {}).get(args.sessions, out)
-    px = r.get("prefix_cache") or {}
-    print(
-        f"loadgen summary: {r.get('completed', '?')} req, "
-        f"{r.get('tokens_per_sec', '?')} tok/s, "
-        f"ttft p50 {r.get('p50_ttft_ms', '?')} ms, "
-        f"itl p99 {r.get('p99_itl_ms', '?')} ms, "
-        f"prefix hit rate {px.get('hit_rate', 'n/a')}, "
-        f"compiles {out['engine']['compiles_prefill']}p"
-        f"+{out['engine']['compiles_prefill_chunk']}pc"
-        f"+{out['engine']['compiles_decode']}d"
-        f"+{out['engine']['compiles_decode_window']}w, "
-        f"swap generation {out['engine']['generation']}",
-        file=sys.stderr)
-    if args.json:
-        with open(args.json, "w") as f:
-            json.dump(out, f, indent=1, sort_keys=True)
-        print(f"loadgen: report written to {args.json}", file=sys.stderr)
-    return 0
-
-
-def _read_arrival_trace(path: str | None) -> list[float] | None:
-    """``--arrival-trace``: sorted seconds-from-start offsets, one float
-    per line, blank lines and '#' comments ignored (loadgen validates
-    ordering/sign so a bad trace fails with its own message)."""
-    if not path:
-        return None
-    try:
-        with open(path) as f:
-            lines = f.read().splitlines()
-    except OSError as e:
-        raise SystemExit(f"--arrival-trace: cannot read {path!r}: {e}")
-    out: list[float] = []
-    for ln in lines:
-        ln = ln.split("#", 1)[0].strip()
-        if not ln:
-            continue
-        try:
-            out.append(float(ln))
-        except ValueError:
-            raise SystemExit(
-                f"--arrival-trace: bad offset {ln!r} in {path!r}")
-    if not out:
-        raise SystemExit(f"--arrival-trace: {path!r} has no offsets")
-    return out
-
-
-def _serve_loadgen_longtail(args, n_replicas: int) -> int:
-    """``serve --loadgen --idle-churn``: the long-tail multi-tenant
-    workload the tiered cache is gated on — N live kept sessions over
-    few device slots, Zipf-popularity continuations, per-tier hit rates
-    + re-prefill cost + hot-set tokens/s in one machine-readable report
-    (tools/bench_serve.py --tiered-cache writes BENCH_serve_r03.json)."""
-    import json
-
-    from .serve import run_longtail
-
-    _, cfg, server = _build_serve_stack(args, n_replicas)
-    sampling = _serve_sampling(args)
-    with server:
-        # warm the full final-prefill lattice: re-prefills (tiers off /
-        # lost state) replay a session's whole history, whose length
-        # lands on arbitrary buckets — an unwarmed one would charge a
-        # mid-run compile to exactly the workload being measured
-        server.warmup(sampling, prompt_lens=tuple(
-            set(server.engine.prefill_buckets) | {args.prompt_len}))
-        out = run_longtail(
-            server, vocab_size=cfg.vocab_size, sessions=args.sessions,
-            requests_per_session=args.requests_per_session,
-            prompt_len=args.prompt_len,
-            max_new_tokens=args.max_new_tokens,
-            sampling=sampling, zipf_s=args.zipf_s, seed=args.seed,
-        )
-        out["tier_stats_total"] = {
-            r.index: r.engine.stats()["tiers"] for r in server.replicas
-        }
-    print(json.dumps(out))
-    t = out.get("tiers") or {}
-    hr = t.get("hit_rates", {})
-    hot = out.get("hot_set", {})
-    print(
-        f"longtail summary: {out['completed']} req over {args.sessions} "
-        f"sessions, {out['tokens_per_sec']} tok/s "
-        f"(hot set {hot.get('tokens_per_sec', '?')} tok/s), tier hits "
-        f"device {hr.get('device', '?')} / host {hr.get('host', '?')} / "
-        f"disk {hr.get('disk', '?')}, re-prefills {out['re_prefills']} "
-        f"({out['re_prefill_tokens']} tokens)", file=sys.stderr)
-    if args.json:
-        with open(args.json, "w") as f:
-            json.dump(out, f, indent=1, sort_keys=True)
-        print(f"loadgen: report written to {args.json}", file=sys.stderr)
-    return 0
-
-
-def _serve_loadgen_template_mix(args, n_replicas: int) -> int:
-    """``serve --loadgen --workload template-mix``: the shared-structure
-    workload the prefix-state fabric is gated on — tenant preamble x
-    few-shot template x unique suffix on a bounded worker pool, with
-    computed-vs-offered prefill token accounting in the report
-    (tools/bench_serve.py --prefix-trie pairs this against the
-    exact-match cache for BENCH_serve_r11.json)."""
-    import json
-
-    from .serve import run_template_mix
-
-    _, cfg, server = _build_serve_stack(args, n_replicas)
-    sampling = _serve_sampling(args)
-    prompt_len = args.preamble_len + args.template_len + args.suffix_len
-    with server:
-        # one final-prefill length (all prompts are the same shape) plus
-        # the resume lattice the batcher's warmup derives from it
-        server.warmup(sampling, prompt_lens=(prompt_len,))
-        out = run_template_mix(
-            server, vocab_size=cfg.vocab_size, sessions=args.sessions,
-            tenants=args.tenants, templates=args.templates,
-            preamble_len=args.preamble_len,
-            template_len=args.template_len, suffix_len=args.suffix_len,
-            max_new_tokens=args.max_new_tokens, sampling=sampling,
-            workers=args.workers, seed=args.seed,
-        )
-        out["engine"] = {
-            "compiles_prefill": sum(
-                r.engine.num_compiles("prefill") for r in server.replicas),
-            "compiles_prefill_chunk": sum(
-                r.engine.num_compiles("prefill_chunk")
-                for r in server.replicas),
-        }
-    print(json.dumps(out))
-    pf = out.get("prefill", {})
-    px = out.get("prefix_cache") or {}
-    print(
-        f"template-mix summary: {out['completed']} req over "
-        f"{args.sessions} sessions ({args.tenants}x{args.templates} "
-        f"pairs), {out.get('tokens_per_sec', '?')} tok/s, prefill "
-        f"computed {pf.get('tokens_computed', '?')}/"
-        f"{pf.get('tokens_offered', '?')} offered "
-        f"(ratio {pf.get('compute_ratio', '?')}), prefix mode "
-        f"{px.get('mode', 'n/a')} hit rate {px.get('hit_rate', 'n/a')}",
-        file=sys.stderr)
-    if args.json:
-        with open(args.json, "w") as f:
-            json.dump(out, f, indent=1, sort_keys=True)
-        print(f"loadgen: report written to {args.json}", file=sys.stderr)
-    return 0
-
-
-def _serve_loadgen_kernel_sweep(args, kernels: tuple[str, ...],
-                                n_replicas: int = 1) -> int:
-    """``serve --loadgen --decode-kernel pallas,scan``: the decode-kernel
-    comparison — the same closed-loop workload on a fresh stack per
-    kernel, tokens/s + TTFT/ITL deltas + greedy token parity in one
-    machine-readable report (the BENCH_serve_r05.json probe)."""
-    import copy
-    import json
-
-    from .serve.loadgen import kernel_sweep
-
-    if args.mode != "closed":
-        print("error: --decode-kernel comparison is closed-loop only",
-              file=sys.stderr)
-        return 2
-    sampling = _serve_sampling(args)
-
-    def make_server(kern):
-        from .obs import MetricsRegistry
-
-        a = copy.copy(args)
-        a.decode_kernel = kern
-        reg = (None if getattr(args, "telemetry", "on") == "off"
-               else MetricsRegistry())
-        # honor a plain --replicas N: each kernel's stack is built at the
-        # requested replica count, not silently at 1
-        return _build_serve_stack(a, n_replicas, registry=reg)[2]
-
-    out = kernel_sweep(
-        make_server, vocab_size=args.vocab_size, kernels=kernels,
-        sessions=args.sessions,
-        requests_per_session=args.requests_per_session,
-        prompt_len=args.prompt_len, max_new_tokens=args.max_new_tokens,
-        sampling=sampling, seed=args.seed,
-    )
-    print(json.dumps(out))
-    vs = out.get("pallas_vs_scan", {})
-    print(f"kernel sweep: tokens/s "
-          f"{ {k: r['tokens_per_sec'] for k, r in out['kernels'].items()} }, "
-          f"pallas/scan ratio {vs.get('tokens_per_sec_ratio', 'n/a')}, "
-          f"p99 ITL delta {vs.get('p99_itl_delta_ms', 'n/a')} ms, "
-          f"parity_ok {out.get('parity_ok', 'n/a')}", file=sys.stderr)
-    if args.json:
-        with open(args.json, "w") as f:
-            json.dump(out, f, indent=1, sort_keys=True)
-        print(f"loadgen: report written to {args.json}", file=sys.stderr)
-    return 0 if out.get("parity_ok", True) else 1
-
-
-def _serve_loadgen_replica_sweep(args, levels: tuple[int, ...]) -> int:
-    """``serve --loadgen --replicas 1,2``: the data-parallel scaling
-    comparison — same closed-loop workload on a fresh n-replica stack per
-    level, aggregate tokens/s + greedy parity in one machine-readable
-    report (the BENCH_serve_r02.json gate)."""
-    import json
-
-    from .serve import replica_sweep
-
-    if args.compare or args.shared_prefix_len or args.inject_prompt_len:
-        print("note: --replicas comparison runs the plain closed-loop "
-              "workload; ignoring --compare/--shared-prefix-len/"
-              "--inject-prompt-len", file=sys.stderr)
-    if args.mode != "closed":
-        print("error: --replicas comparison is closed-loop only",
-              file=sys.stderr)
-        return 2
-    sampling = _serve_sampling(args)
-
-    def make_server(n):
-        # fresh registry per level (telemetry on): a sweep's levels build
-        # separate servers, and sharing the process registry would fold
-        # level 1's samples into level 2's embedded summaries
-        from .obs import MetricsRegistry
-
-        reg = (None if getattr(args, "telemetry", "on") == "off"
-               else MetricsRegistry())
-        return _build_serve_stack(args, n, registry=reg)[2]
-
-    out = replica_sweep(
-        make_server, vocab_size=args.vocab_size, levels=levels,
-        sessions=args.sessions,
-        requests_per_session=args.requests_per_session,
-        prompt_len=args.prompt_len, max_new_tokens=args.max_new_tokens,
-        sampling=sampling, seed=args.seed,
-    )
-    print(json.dumps(out))
-    sc = out["scaling"]
-    print(f"replica sweep: tokens/s {sc['tokens_per_sec']}, "
-          f"speedup {sc['speedup_top_vs_base']}x "
-          f"({sc['top_level']} vs {sc['base_level']} replicas), "
-          f"parity_ok {out.get('parity_ok', 'n/a')}", file=sys.stderr)
-    if args.json:
-        with open(args.json, "w") as f:
-            json.dump(out, f, indent=1, sort_keys=True)
-        print(f"loadgen: report written to {args.json}", file=sys.stderr)
-    return 0 if out.get("parity_ok", True) else 1
-
-
 def _serve_http(args) -> int:
     from .serve.server import make_http_server
 
-    _, _, server = _build_serve_stack(
-        args, _single_replica_count(args, "--http"))
+    _, _, server = _build_serve_stack(args, args.replicas)
     # pre-compile the bucket lattice for the default sampling config BEFORE
     # taking traffic: on TPU a compile is ~20-40 s, which would both time
     # out first requests and starve the scheduler heartbeat long enough to
     # flip /healthz 503 on a healthy warming server (an orchestrator would
-    # then kill-loop it). Selftest/loadgen warm implicitly; --http must too.
+    # then kill-loop it). The selftest warms implicitly; --http must too.
     print(f"serve: warming the compile lattice "
           f"({len(server.replicas)} replica(s))...", flush=True)
     n = server.warmup(_serve_sampling(args),
@@ -2813,8 +2278,6 @@ def _run_serve(argv) -> int:
     try:
         if args.selftest:
             return _serve_selftest(args)
-        if args.loadgen:
-            return _serve_loadgen(args)
         return _serve_http(args)
     finally:
         if tracer is not None:

@@ -4,9 +4,8 @@ Motivation (VERDICT r3 item 2): the bi-LSTM classifier (BASELINE.md
 config 2) ran its forward and reverse directions as TWO sequential
 `pallas_lstm_scan` invocations — 2T serialized chain steps per layer —
 even though the two chains are completely data-independent until the
-output concat (models/classifier.py). The strategy-aware roofline
-(`bench.py _impl_bound`) identified that serialization as config 2's
-binding constraint (41% of the strategy-aware bound in round 3).
+output concat (models/classifier.py): that serialization was config 2's
+binding constraint.
 
 Design: ONE `pallas_call` advances BOTH chains in every sub-step. The
 reverse direction is realised exactly as in `pallas_lstm_scan` — a

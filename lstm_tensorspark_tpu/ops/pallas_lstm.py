@@ -326,10 +326,9 @@ def chosen_bwd_strategy(B: int, T: int, H: int, pbytes: int, *,
     `pallas_lstm_scan` at PADDED hidden size ``H`` (and padded input width
     ``Dp``, None when the xproj is hoisted) will actually take —
     ``"residentx"`` / ``"resident"`` / ``"tiled"`` fused kernels, or
-    ``"recompute"`` (the pure-jax remat fallback). Both `_scan_core_fwd`
-    and bench.py's strategy-aware roofline read THIS function, so the
-    published `impl_bwd_strategy` can never diverge from the path that
-    ran. Gates, in order: remat_chunk is the explicit memory-priority
+    ``"recompute"`` (the pure-jax remat fallback). `_scan_core_fwd` reads
+    THIS function, and `tests/test_pallas.py` pins its answer at the
+    published shapes. Gates, in order: remat_chunk is the explicit memory-priority
     signal; a backward kernel must plan; its O(T) residuals must fit the
     HBM budget; and the matching residual-saving forward must also fit
     (residentx bwd consumes the residentx fwd's cs-only residuals; the
@@ -1253,8 +1252,7 @@ def _scan_core_fwd(params, xs, h0, c0, mask_tbl, compute_dtype, interpret,
     H = fused.hidden_size
     pbytes = 2 if fused.kernel.dtype == jnp.bfloat16 else 4
     Dp = _pad_to_lane(D) if T >= _FUSEDX_MIN_T else None
-    # gate rationale lives on chosen_bwd_strategy — the one decision both
-    # this path and bench.py's strategy-aware roofline read
+    # gate rationale lives on chosen_bwd_strategy
     strategy = chosen_bwd_strategy(B, T, H, pbytes, has_mask=has_mask, Dp=Dp,
                                    remat_chunk=remat_chunk)
     fusedx = strategy == "residentx"

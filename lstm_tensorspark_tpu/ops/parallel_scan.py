@@ -46,8 +46,7 @@ The FLOP trade is real and priced honestly: the dense tile/tree
 composes do O(H) more arithmetic than sequential BPTT's vector chain.
 On a latency-bound accelerator chain (small per-step matmuls, T deep)
 the log-depth tree wins; on a throughput-bound CPU it usually does not
-— `tools/bench_train_scan.py` records the honest CPU ratio and
-`tests_tpu/test_parallel_scan_tpu.py` is the hardware >= 1.0x gate.
+— `tests_tpu/test_parallel_scan_tpu.py` is the hardware >= 1.0x gate.
 
 ``resolve_bptt`` implements the ``bptt="auto"`` policy (ops/scan.py):
 assoc only when the `plan_bytes` memory model fits the budget AND

@@ -80,9 +80,9 @@ into a serving engine:
   exposition of the stack's telemetry registry (obs/, ``replica``-
   labelled serve families) and histogram summaries inside ``/stats``;
   ``/healthz`` fans per-replica heartbeats into ok/degraded/down;
-- ``loadgen``: closed/open-loop load generator (p50/p99 request latency,
-  TTFT, inter-token latency, tokens/s), embedding the server-side
-  histogram summaries next to its own percentiles.
+- ``loadgen``: the in-process closed/open-loop driver the chaos drill and
+  the serving tests push traffic through (not a benchmark: speed is
+  measured by ``BENCHMARK.json`` + ``benchmark/``).
 
 Telemetry: every layer records into ONE registry (``ServeEngine(
 registry=...)``, default ``obs.REGISTRY``; ``obs.NULL_REGISTRY``
@@ -123,14 +123,7 @@ from .rollout import RolloutController, RolloutError
 from .router import Replica, Router
 from .remote import RemoteBatcher, RemoteReplica
 from .server import InprocessClient, ServeServer
-from .loadgen import (
-    mesh_sweep,
-    replica_sweep,
-    run_loadgen,
-    run_longtail,
-    run_template_mix,
-    template_mix_prompts,
-)
+from .loadgen import run_loadgen
 
 __all__ = [
     "AutoTuneConfig",
@@ -165,10 +158,5 @@ __all__ = [
     "UnknownModelError",
     "build_engine",
     "config_fingerprint",
-    "mesh_sweep",
-    "replica_sweep",
     "run_loadgen",
-    "run_longtail",
-    "run_template_mix",
-    "template_mix_prompts",
 ]

@@ -1,42 +1,29 @@
-"""Offline load generator for the serving engine (CPU-runnable).
+"""In-process driver of a `ServeServer` for the drills and the tests.
 
-Two standard modes:
+Not a benchmark: the system's speed is measured by `BENCHMARK.json` +
+`benchmark/` (whose own generator, `benchmark/loadgen.py`, is independent
+of this package). `run_loadgen` is what `tools/chaos_serve.py`'s
+burst-shedding drill and the serving tests push traffic through when
+they need many concurrent requests with one report at the end.
+
+Two modes:
 
 - **closed-loop**: N session threads, each issuing its requests
   back-to-back through the in-process client — models N always-busy
-  clients; throughput scales with continuous batching until the decode
-  bucket saturates;
-- **open-loop**: requests arrive at a fixed rate regardless of completion
-  (the arrival process does not slow down when the server does), exposing
-  queueing delay and backpressure (429s are counted, not retried).
+  clients;
+- **open-loop**: requests arrive on a schedule (`arrival_offsets`: fixed
+  rate, bursts, a sine-modulated rate or a replayed trace) regardless of
+  completion, exposing queueing delay and backpressure (429s are counted,
+  and retried only with ``retry_max``).
 
 The report carries request latency p50/p99/mean, time-to-first-token
-p50/p99, **inter-token latency** p50/p99/max (pooled over every token
-gap of every completed request — the decode-window tradeoff made
-visible: larger K raises tokens/sec AND raises tail ITL, because a
-window's K tokens arrive in one burst after a K-step device program),
-aggregate tokens/sec and requests/sec. Phases are wrapped in
-`utils.tracing` spans, so ``--trace`` on the CLI captures the run.
-
-`concurrency_sweep` runs the same closed-loop workload at increasing
-session counts on one warm server — the headline check that batched
-decode beats sequential serving (ISSUE acceptance: >= 8 concurrent
-sessions must out-throughput 1 session). `replica_sweep` runs it at
-increasing REPLICA counts (a fresh server per level) — the data-parallel
-scaling gate (aggregate tokens/s across N schedulers, greedy parity
-token-identical to one replica); every report carries per-replica
-routed/served counts plus the router's requeue/rejection deltas.
-
-Prefix-cache / chunked-prefill probes: ``shared_prefix_len`` makes every
-prompt share its first N tokens (the shared-system-prompt workload —
-TTFT with the cache on should beat cache-off once the prefix is hot, and
-the report carries the cache's hit/miss/insert deltas);
+p50/p99, inter-token latency p50/p99/max (pooled over every token gap of
+every completed request), aggregate tokens/sec and requests/sec, the
+per-class slices (``priority_frac``, ``deadline_s``, ``retry_max``),
+per-replica served counts and the router's requeue/rejection deltas.
+``shared_prefix_len`` makes every prompt share its first N tokens;
 ``inject_prompt_len`` submits one cold long-prompt request mid-run and
-reports it separately — the head-of-line-blocking probe (without chunked
-prefill, its monolithic prefill program shows up in every running
-session's p99 ITL; with ``prefill_chunk`` the stall is bounded by one
-chunk). Reports are JSON-ready dicts: ``cli serve --loadgen --json PATH``
-persists them (BENCH_serve_r01.json is the checked-in baseline).
+reports it separately. Phases are wrapped in `utils.tracing` spans.
 """
 
 from __future__ import annotations
@@ -66,10 +53,9 @@ def arrival_offsets(
     sine_amp: float = 0.8,
 ) -> list[float]:
     """Arrival schedule for ``n`` open-loop requests: seconds-from-start
-    offsets, non-decreasing. The autotuner bench and the chaos drill
-    drive phase shifts through these instead of the single fixed rate.
+    offsets, non-decreasing.
 
-    - ``arrival_times`` (trace replay, ``--arrival-trace``): explicit
+    - ``arrival_times`` (trace replay): explicit
       offsets, one request each. A trace shorter than ``n`` LOOPS —
       replayed shifted by its span plus one mean gap, so the recorded
       diurnal/burst shape repeats instead of truncating the workload.
@@ -179,7 +165,7 @@ def _class_report(recs: list[dict], shed: int, retried: int,
     """Per-admission-class slice of a run: completion/shed/retry/timeout
     counts plus TTFT and latency percentiles — the evidence the
     burst-shedding gate compares (priority p99 TTFT holds its SLO while
-    best_effort sheds; BENCH_serve_r04.json). ``tokens_per_sec`` is the
+    best_effort sheds). ``tokens_per_sec`` is the
     class's ACCEPTED-token throughput over the run's wall clock, so a
     speculative win (or a shedding loss) shows up per SLO class instead
     of being averaged away in the aggregate rate."""
@@ -537,8 +523,7 @@ def run_loadgen(
         }
     # server-side latency distributions (obs/ registry histograms) next to
     # the loadgen-side percentiles above, so the two views are directly
-    # diffable. NOTE: the registry is CUMULATIVE over the server's life —
-    # a sweep's later levels include earlier levels' samples.
+    # diffable. NOTE: the registry is CUMULATIVE over the server's life.
     summary = server.metrics_summary()
     hists = {k: summary[k] for k in ("serve_ttft_seconds",
                                      "serve_itl_seconds",
@@ -547,670 +532,3 @@ def run_loadgen(
     if hists:
         report["server_histograms"] = hists
     return report
-
-
-def template_mix_prompts(sessions: int, *, vocab_size: int,
-                         tenants: int = 4, templates: int = 25,
-                         preamble_len: int = 128, template_len: int = 32,
-                         suffix_len: int = 8, seed: int = 0):
-    """The template-mix workload (ISSUE 19 / ROADMAP item 4): each
-    prompt is tenant preamble × few-shot template × UNIQUE suffix —
-    ``tenants`` distinct preambles, ``templates`` templates per tenant,
-    sessions assigned round-robin over the (tenant, template) pairs.
-    Every prompt's first ``preamble_len + template_len`` tokens are
-    shared with every sibling of its pair (and its first
-    ``preamble_len`` with every session of its tenant) while the suffix
-    keeps it unique — the shape the radix fabric exploits at two depths
-    and the exact-match cache cannot (no two FULL prompts ever match).
-    Returns ``(prompts, pair_ids)`` with pair_ids[i] the (tenant,
-    template) index of prompt i — the parity harness groups by it."""
-    if min(tenants, templates, preamble_len, template_len,
-           suffix_len) < 1:
-        raise ValueError("template-mix dimensions must all be >= 1")
-    rng = np.random.RandomState(seed)
-    preambles = [rng.randint(0, vocab_size, size=preamble_len)
-                 for _ in range(tenants)]
-    temps = [[rng.randint(0, vocab_size, size=template_len)
-              for _ in range(templates)] for _ in range(tenants)]
-    prompts, pair_ids = [], []
-    for i in range(sessions):
-        tn = i % tenants
-        tm = (i // tenants) % templates
-        prompts.append(np.concatenate([
-            preambles[tn], temps[tn][tm],
-            rng.randint(0, vocab_size, size=suffix_len),
-        ]).astype(np.int32))
-        pair_ids.append((tn, tm))
-    return prompts, pair_ids
-
-
-def run_template_mix(
-    server: ServeServer,
-    *,
-    vocab_size: int,
-    sessions: int = 1000,
-    tenants: int = 4,
-    templates: int = 25,
-    preamble_len: int = 128,
-    template_len: int = 32,
-    suffix_len: int = 8,
-    max_new_tokens: int = 8,
-    sampling: SamplingParams = GREEDY,
-    workers: int = 32,
-    seed: int = 0,
-    timeout: float = 300.0,
-    collect_tokens: bool = False,
-) -> dict:
-    """Closed-loop template-mix run on a BOUNDED worker pool (a
-    thread-per-session closed loop cannot shoulder 10k sessions; this
-    pool pulls prompts off a shared index, so ``sessions`` scales to the
-    gate's population). One request per session — the workload measures
-    PREFILL sharing, not decode. The report adds the prefix-store
-    deltas, the computed-vs-offered prefill token split (the fabric
-    gate's numerator: ``prefill_tokens_computed`` counts tokens that
-    actually ran through the model; offered = Σ len(prompt)), and —
-    with ``collect_tokens`` — per-session output-token digests keyed by
-    session index for cross-arm greedy-parity comparison."""
-    prompts, pair_ids = template_mix_prompts(
-        sessions, vocab_size=vocab_size, tenants=tenants,
-        templates=templates, preamble_len=preamble_len,
-        template_len=template_len, suffix_len=suffix_len, seed=seed)
-    results: list[dict] = []
-    tokens_by_session: dict[int, list[int]] = {}
-    failed = [0]
-    lock = threading.Lock()
-    next_idx = [0]
-    prefix_before = prefix_totals(server)
-    batcher_before = server.stats()["batcher"]
-
-    def worker() -> None:
-        while True:
-            with lock:
-                i = next_idx[0]
-                if i >= len(prompts):
-                    return
-                next_idx[0] += 1
-            t0 = time.perf_counter()
-            try:
-                req = server.generate(
-                    prompts[i], max_new_tokens=max_new_tokens,
-                    sampling=sampling, timeout=timeout)
-            except Exception:
-                with lock:
-                    failed[0] += 1
-                continue
-            rec = {
-                "latency_s": time.perf_counter() - t0,
-                "ttft_s": (req.t_first_token - req.t_submit)
-                if req.t_first_token and req.t_submit else None,
-                "tokens": len(req.tokens),
-                "itl_s": req.itl_gaps(),
-                "replica": req.replica,
-            }
-            with lock:
-                results.append(rec)
-                if collect_tokens:
-                    tokens_by_session[i] = [int(t) for t in req.tokens]
-
-    with span("template_mix", sessions=sessions, workers=workers):
-        t_start = time.perf_counter()
-        threads = [threading.Thread(target=worker, daemon=True)
-                   for _ in range(min(workers, sessions))]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        wall = time.perf_counter() - t_start
-    report = _report(results, 0, failed[0], wall, "closed", sessions)
-    report["workload"] = "template-mix"
-    report["tenants"] = tenants
-    report["templates_per_tenant"] = templates
-    report["prompt_len"] = preamble_len + template_len + suffix_len
-    report["replicas"] = _per_replica(results)
-    offered = sum(len(p) for p in prompts)
-    b_after = server.stats()["batcher"]
-    computed = (b_after["prefill_tokens_computed"]
-                - batcher_before["prefill_tokens_computed"])
-    report["prefill"] = {
-        "tokens_offered": offered,
-        "tokens_computed": computed,
-        "tokens_saved": (b_after["prefix_tokens_saved"]
-                         - batcher_before["prefix_tokens_saved"]),
-        "sessions_resumed": (b_after["prefix_resumed"]
-                             - batcher_before["prefix_resumed"]),
-        "compute_ratio": round(computed / offered, 4) if offered else None,
-    }
-    if prefix_before is not None:
-        after = prefix_totals(server)
-        hits = after["hits"] - prefix_before["hits"]
-        misses = after["misses"] - prefix_before["misses"]
-        report["prefix_cache"] = {
-            "mode": after.get("mode", "exact"),
-            "hits": hits,
-            "misses": misses,
-            "hit_rate": round(hits / (hits + misses), 4)
-            if hits + misses else 0.0,
-            "inserts": after["inserts"] - prefix_before["inserts"],
-            "entries": after["entries"],
-            "invalidated": (after["invalidated"]
-                            - prefix_before["invalidated"]),
-        }
-        # fabric-only detail: spill/promote traffic and the host-bound
-        # evidence the memory gate reads (absent in exact mode)
-        if "spilled_bytes" in after:
-            report["prefix_cache"]["spilled_bytes"] = after["spilled_bytes"]
-            report["prefix_cache"]["host_bytes"] = after["host_bytes"]
-            report["prefix_cache"]["spilled"] = (
-                after["spilled"] - prefix_before.get("spilled", 0))
-            report["prefix_cache"]["promoted"] = (
-                after["promoted"] - prefix_before.get("promoted", 0))
-    if collect_tokens:
-        report["tokens_by_session"] = tokens_by_session
-    return report
-
-
-def tier_totals(server: ServeServer) -> dict | None:
-    """SessionTiers stats summed across replicas (same aggregation policy
-    as :func:`prefix_totals`): counters summed, per-tier entry counts
-    summed, config keys keep replica 0's value."""
-    totals = None
-    for rep in server.replicas:
-        if rep.engine.tiers is None:
-            continue
-        st = rep.engine.tiers.stats()
-        if totals is None:
-            totals = {
-                "host_entries_max": st["host_entries_max"],
-                "entries": dict(st["entries"]),
-                "spills": dict(st["spills"]),
-                "fills": dict(st["fills"]),
-                "misses": st["misses"],
-                "corrupt": st["corrupt"],
-                "lost": st["lost"],
-                "disk_errors": st["disk_errors"],
-            }
-            continue
-        for k in ("entries", "spills", "fills"):
-            for t, v in st[k].items():
-                totals[k][t] = totals[k].get(t, 0) + v
-        for k in ("misses", "corrupt", "lost", "disk_errors"):
-            totals[k] += st[k]
-    return totals
-
-
-def run_longtail(
-    server: ServeServer,
-    *,
-    vocab_size: int,
-    sessions: int,
-    requests_per_session: int = 3,
-    prompt_len: int = 8,
-    max_new_tokens: int = 8,
-    sampling: SamplingParams = GREEDY,
-    zipf_s: float = 1.1,
-    concurrency: int = 8,
-    seed: int = 0,
-    timeout: float = 300.0,
-) -> dict:
-    """Long-tail multi-tenant workload (``cli serve --loadgen
-    --idle-churn``): ``sessions`` live kept sessions — size it to ~10x
-    the device slots — each created once and then continued by draws
-    from a Zipf(``zipf_s``) popularity distribution, so a small hot set
-    sees most of the traffic while the long tail sits idle and gets
-    LRU-evicted. Exactly the workload the tiered cache is gated on
-    (ROADMAP item 2): without tiers, every evicted session's
-    continuation fails "expired" and the client re-prefills its FULL
-    accumulated history (counted as ``re_prefills`` /
-    ``re_prefill_tokens``); with tiers, continuations fill from host or
-    disk for one tiny state copy.
-
-    The report extends :func:`_report` with per-tier hit counts and
-    rates for the continuations (``tiers``: device/host/disk/lost),
-    the re-prefill cost, and the HOT-SET throughput
-    (``hot_set.tokens_per_sec`` over the top-10% sessions by rank) —
-    the number the tiered-vs-all-on-device gate compares
-    (tools/bench_serve.py --tiered-cache → BENCH_serve_r03.json).
-
-    Each logical session's full token history is tracked so a
-    re-prefilled session resumes token-identically — re-prefill changes
-    the COST, never the output."""
-    if sessions < 1:
-        raise ValueError(f"sessions must be >= 1, got {sessions}")
-    rng = np.random.RandomState(seed)
-    prompts = _random_prompts(sessions, prompt_len, vocab_size, seed)
-    # Zipf-ish popularity: session rank r drawn with weight (r+1)^-s
-    weights = (np.arange(sessions) + 1.0) ** -float(zipf_s)
-    weights /= weights.sum()
-    schedule = list(rng.choice(sessions, size=sessions * requests_per_session,
-                               p=weights))
-    hot_k = max(1, sessions // 10)
-
-    lock = threading.Lock()
-    cond = threading.Condition(lock)
-    # per logical session: server-side sid (None until created), full
-    # history (prompt + every generated token), token count, in-flight
-    # flag (two concurrent requests on one session would be rejected
-    # "busy" — the driver serialises per session, like a real client)
-    sids: list[str | None] = [None] * sessions
-    history: list[list[int]] = [list(map(int, p)) for p in prompts]
-    tokens_by_session = [0] * sessions
-    busy: set[int] = set()
-    rejected = [0]
-    failed = [0]
-    re_prefills = [0]
-    re_prefill_tokens = [0]
-    continuations = [0]  # session_id continuations that COMPLETED
-    results: list[dict] = []
-
-    tiers_before = tier_totals(server)
-    prefix_before = prefix_totals(server)
-
-    def _generate(logical: int, prompt, *, session_id):
-        t0 = time.perf_counter()
-        req = server.generate(
-            prompt, max_new_tokens=max_new_tokens, sampling=sampling,
-            session_id=session_id, keep_session=True, timeout=timeout,
-        )
-        rec = {
-            "latency_s": time.perf_counter() - t0,
-            "ttft_s": (req.t_first_token - req.t_submit)
-            if req.t_first_token and req.t_submit else None,
-            "tokens": len(req.tokens),
-            "itl_s": req.itl_gaps(),
-            "replica": req.replica,
-            "session": logical,
-        }
-        with lock:
-            sids[logical] = req.session_id
-            history[logical].extend(int(t) for t in req.tokens)
-            tokens_by_session[logical] += len(req.tokens)
-            results.append(rec)
-
-    def one_turn(logical: int) -> None:
-        with lock:
-            sid = sids[logical]
-        try:
-            if sid is None:
-                _generate(logical, prompts[logical], session_id=None)
-                return
-            with lock:
-                cont = [history[logical][-1]]
-            try:
-                _generate(logical, np.asarray(cont, np.int32),
-                          session_id=sid)
-                with lock:
-                    continuations[0] += 1
-                return
-            except RuntimeError as e:
-                if "unknown session" not in str(e):
-                    raise
-            # evicted with no restorable tier state: the honest client
-            # re-sends its FULL history — the cost the tiers exist to kill
-            with lock:
-                full = list(history[logical])
-                re_prefills[0] += 1
-                re_prefill_tokens[0] += len(full)
-                sids[logical] = None
-            _generate(logical, np.asarray(full, np.int32), session_id=None)
-        except QueueFullError:
-            with lock:
-                rejected[0] += 1
-        except Exception:
-            with lock:
-                failed[0] += 1
-
-    def worker() -> None:
-        while True:
-            with cond:
-                idx = next((i for i, s in enumerate(schedule)
-                            if s not in busy), None)
-                if idx is None:
-                    if not schedule:
-                        return
-                    # every remaining turn targets an in-flight session:
-                    # wait for one to free up
-                    cond.wait(timeout=0.05)
-                    continue
-                logical = schedule.pop(idx)
-                busy.add(logical)
-            try:
-                one_turn(logical)
-            finally:
-                with cond:
-                    busy.discard(logical)
-                    cond.notify_all()
-
-    with span("loadgen_longtail", sessions=sessions,
-              turns=len(schedule)):
-        t_start = time.perf_counter()
-        threads = [threading.Thread(target=worker, daemon=True)
-                   for _ in range(max(1, concurrency))]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        wall = time.perf_counter() - t_start
-
-    report = _report(results, rejected[0], failed[0], wall, "longtail",
-                     sessions)
-    report["prompt_len"] = prompt_len
-    report["zipf_s"] = zipf_s
-    report["requests_per_session"] = requests_per_session
-    report["re_prefills"] = re_prefills[0]
-    report["re_prefill_tokens"] = re_prefill_tokens[0]
-    hot_tokens = sum(tokens_by_session[:hot_k])
-    report["hot_set"] = {
-        "sessions": hot_k,
-        "tokens_generated": hot_tokens,
-        "tokens_per_sec": round(hot_tokens / wall, 2) if wall > 0 else 0.0,
-    }
-    # per-tier continuation accounting: fills by tier from the tiers'
-    # own counters; device hits are the continuations that needed none.
-    # Re-prefills count the evicted-and-unrestorable tail ("lost" from
-    # the client's point of view), whatever the tiers' miss counter saw.
-    conts = continuations[0] + re_prefills[0]
-    ta, tb = tier_totals(server), tiers_before
-    if ta is not None:
-        host = ta["fills"]["host"] - (tb["fills"]["host"] if tb else 0)
-        disk = ta["fills"]["disk"] - (tb["fills"]["disk"] if tb else 0)
-        lost = re_prefills[0]
-        spills = {t: ta["spills"][t] - (tb["spills"][t] if tb else 0)
-                  for t in ta["spills"]}
-        device = max(continuations[0] - host - disk, 0)
-        total = max(conts, 1)
-        report["tiers"] = {
-            "continuations": conts,
-            "hits": {"device": device, "host": host, "disk": disk},
-            "lost": lost,
-            "spills": spills,
-            "hit_rates": {
-                "device": round(device / total, 4),
-                "host": round(host / total, 4),
-                "disk": round(disk / total, 4),
-            },
-            "entries": dict(ta["entries"]),
-        }
-    else:
-        report["tiers"] = {
-            "continuations": conts,
-            "hits": {"device": continuations[0], "host": 0, "disk": 0},
-            "lost": re_prefills[0],
-            "spills": {},
-            "hit_rates": {
-                "device": round(continuations[0] / max(conts, 1), 4),
-                "host": 0.0, "disk": 0.0,
-            },
-            "entries": {},
-        }
-    report["replicas"] = _per_replica(results)
-    if prefix_before is not None:
-        after = prefix_totals(server)
-        report["prefix_cache"] = {
-            k: after[k] - prefix_before[k]
-            for k in ("hits", "misses", "inserts", "invalidated")
-        }
-    return report
-
-
-def concurrency_sweep(
-    server: ServeServer,
-    *,
-    vocab_size: int,
-    levels: tuple[int, ...] = (1, 8),
-    requests_per_session: int = 4,
-    prompt_len: int = 8,
-    max_new_tokens: int = 16,
-    sampling: SamplingParams = GREEDY,
-    seed: int = 0,
-) -> dict:
-    """Closed-loop throughput at each concurrency level on ONE warm server
-    (the engine pre-compiles the full bucket lattice before timing, so no
-    level is charged XLA compiles mid-run). Returns
-    ``{"levels": {n: report}, "speedup_max_vs_1": x}``."""
-    with span("loadgen_warmup"):
-        # the batcher derives its own window-ladder / chunk / prefix-split
-        # programs, so no level is charged a compile mid-run
-        server.warmup(sampling, prompt_lens=(prompt_len,))
-    reports = {}
-    for n in levels:
-        reports[n] = run_loadgen(
-            server, vocab_size=vocab_size, sessions=n,
-            requests_per_session=requests_per_session,
-            prompt_len=prompt_len, max_new_tokens=max_new_tokens,
-            sampling=sampling, seed=seed + n,
-        )
-    out = {"levels": reports}
-    if 1 in reports:
-        base = reports[1]["tokens_per_sec"] or 1e-9
-        out["speedup_max_vs_1"] = round(
-            max(r["tokens_per_sec"] for r in reports.values()) / base, 3
-        )
-    return out
-
-
-def replica_sweep(
-    make_server,
-    *,
-    vocab_size: int,
-    levels: tuple[int, ...] = (1, 2),
-    sessions: int = 8,
-    requests_per_session: int = 4,
-    prompt_len: int = 8,
-    max_new_tokens: int = 16,
-    sampling: SamplingParams = GREEDY,
-    seed: int = 0,
-    parity_prompts: int = 4,
-) -> dict:
-    """Replica-count comparison: run the SAME closed-loop workload on a
-    fresh ``make_server(n)`` stack per level — the machine-checkable
-    scaling gate for data-parallel serving (``cli serve --loadgen
-    --replicas 1,2``; BENCH_serve_r02.json).
-
-    ``make_server(n)`` must return an UNSTARTED :class:`ServeServer`
-    with ``n`` replicas; each level is warmed before timing (every
-    replica compiles its own program lattice) and stopped after.
-    ``parity_prompts`` > 0 with greedy sampling additionally decodes a
-    fixed prompt set through every level and reports ``parity_ok`` —
-    multi-replica greedy output must be token-identical to
-    ``--replicas 1`` (each replica runs the same params through the
-    same programs; routing must not change a single token).
-
-    Returns ``{"levels": {n: report}, "scaling": {...}, "parity_ok"}``;
-    each level's report carries the per-replica routed/served counts
-    (``report["replicas"]``/``report["router"]``)."""
-    levels = tuple(sorted({int(n) for n in levels}))
-    if not levels or levels[0] < 1:
-        raise ValueError(f"levels must be positive replica counts, "
-                         f"got {levels!r}")
-    check_parity = parity_prompts > 0 and sampling.greedy
-    probes = (_random_prompts(parity_prompts, prompt_len, vocab_size,
-                              seed + 4242) if check_parity else [])
-    out: dict = {"levels": {}}
-    parity: dict[int, list[list[int]]] = {}
-    for n in levels:
-        server = make_server(n)
-        if len(server.replicas) != n:
-            raise ValueError(
-                f"make_server({n}) built {len(server.replicas)} replicas")
-        with server:
-            with span("replica_sweep_warmup", replicas=n):
-                server.warmup(sampling, prompt_lens=(prompt_len,))
-            out["levels"][n] = run_loadgen(
-                server, vocab_size=vocab_size, sessions=sessions,
-                requests_per_session=requests_per_session,
-                prompt_len=prompt_len, max_new_tokens=max_new_tokens,
-                sampling=sampling, seed=seed,
-            )
-            if probes:
-                parity[n] = [
-                    list(server.generate(p, max_new_tokens=max_new_tokens,
-                                         sampling=sampling).tokens)
-                    for p in probes
-                ]
-    base, top = levels[0], levels[-1]
-    tps = {n: out["levels"][n]["tokens_per_sec"] for n in levels}
-    out["scaling"] = {
-        "tokens_per_sec": tps,
-        "base_level": base,
-        "top_level": top,
-        "speedup_top_vs_base": round(tps[top] / (tps[base] or 1e-9), 3),
-    }
-    if parity:
-        out["parity_ok"] = all(parity[n] == parity[base] for n in levels)
-    return out
-
-
-def mesh_sweep(
-    make_server,
-    *,
-    vocab_size: int,
-    levels: tuple[int, ...] = (1, 2),
-    sessions: int = 8,
-    requests_per_session: int = 4,
-    prompt_len: int = 8,
-    max_new_tokens: int = 16,
-    sampling: SamplingParams = GREEDY,
-    seed: int = 0,
-    parity_prompts: int = 4,
-) -> dict:
-    """Tensor-parallel shard-count comparison (``tools/bench_serve.py
-    --mesh-shards 1,2``; BENCH_serve_r06.json): the SAME closed-loop
-    workload on a fresh ``make_server(shards)`` stack per level —
-    aggregate tokens/s + TTFT/ITL percentiles per shard count, the
-    sharded/single-device ratio, greedy cross-config token parity, and
-    a warmup-asserted zero-mid-traffic-compile check (the measured run
-    must never be charged an XLA compile: the warmed lattice IS the
-    claim that sharding adds no compile-key gaps).
-
-    On CPU virtual devices the "shards" are threads of one host, so the
-    ratio prices GSPMD partition overhead WITHOUT the memory-capacity
-    win sharding exists for — it is recorded honestly and is expected
-    to be <= 1.0; the capacity claim belongs to real multi-chip hosts
-    (the plumbing + parity are what this sweep gates)."""
-    levels = tuple(sorted({int(n) for n in levels}))
-    if not levels or levels[0] < 1:
-        raise ValueError(f"levels must be positive shard counts, "
-                         f"got {levels!r}")
-    check_parity = parity_prompts > 0 and sampling.greedy
-    probes = (_random_prompts(parity_prompts, prompt_len, vocab_size,
-                              seed + 4242) if check_parity else [])
-    out: dict = {"levels": {}}
-    parity: dict[int, list[list[int]]] = {}
-    mid_traffic_compiles: dict[int, int] = {}
-    for n in levels:
-        server = make_server(n)
-        with server:
-            with span("mesh_sweep_warmup", shards=n):
-                server.warmup(sampling, prompt_lens=(prompt_len,))
-            warm = sum(r.engine.num_compiles() for r in server.replicas)
-            out["levels"][n] = run_loadgen(
-                server, vocab_size=vocab_size, sessions=sessions,
-                requests_per_session=requests_per_session,
-                prompt_len=prompt_len, max_new_tokens=max_new_tokens,
-                sampling=sampling, seed=seed,
-            )
-            if probes:
-                parity[n] = [
-                    list(server.generate(p, max_new_tokens=max_new_tokens,
-                                         sampling=sampling).tokens)
-                    for p in probes
-                ]
-            # zero mid-traffic compiles, warmup-asserted: every program
-            # the workload touched was already in the warmed lattice
-            mid_traffic_compiles[n] = (
-                sum(r.engine.num_compiles() for r in server.replicas)
-                - warm)
-            es = server.engine.stats()
-            out["levels"][n]["mesh_shards"] = es["mesh_shards"]
-            out["levels"][n]["decode_window_scan_fallbacks"] = (
-                es["decode_window_scan_fallbacks"])
-    base, top = levels[0], levels[-1]
-    tps = {n: out["levels"][n]["tokens_per_sec"] for n in levels}
-    out["scaling"] = {
-        "tokens_per_sec": tps,
-        "base_shards": base,
-        "top_shards": top,
-        "shard_ratio_top_vs_base": round(tps[top] / (tps[base] or 1e-9), 3),
-        "p50_ttft_ms": {n: out["levels"][n]["p50_ttft_ms"]
-                        for n in levels},
-        "p99_itl_ms": {n: out["levels"][n]["p99_itl_ms"]
-                       for n in levels},
-    }
-    out["mid_traffic_compiles"] = mid_traffic_compiles
-    out["warmup_covered"] = all(v == 0
-                                for v in mid_traffic_compiles.values())
-    if parity:
-        out["parity_ok"] = all(parity[n] == parity[base] for n in levels)
-    return out
-
-
-def kernel_sweep(
-    make_server,
-    *,
-    vocab_size: int,
-    kernels: tuple[str, ...] = ("scan", "pallas"),
-    sessions: int = 8,
-    requests_per_session: int = 4,
-    prompt_len: int = 8,
-    max_new_tokens: int = 16,
-    sampling: SamplingParams = GREEDY,
-    seed: int = 0,
-    parity_prompts: int = 4,
-) -> dict:
-    """Decode-kernel comparison (``cli serve --loadgen --decode-kernel
-    pallas,scan``; the BENCH_serve_r05.json probe): the SAME closed-loop
-    workload on a fresh ``make_server(kernel)`` stack per kernel, with
-    tokens/s + TTFT/ITL percentiles per kernel, the pallas-vs-scan
-    deltas, and greedy token parity across kernels — the decode window
-    must produce the SAME stream whichever kernel computes it.
-
-    Off-TPU the pallas kernel runs in interpreter mode, which is slower
-    than the scan window by construction — the report records the honest
-    ratio either way (the speed claim belongs to real hardware,
-    tests_tpu/)."""
-    kernels = tuple(dict.fromkeys(kernels))  # dedupe, keep order
-    if not kernels:
-        raise ValueError("kernels must name at least one decode kernel")
-    check_parity = parity_prompts > 0 and sampling.greedy
-    probes = (_random_prompts(parity_prompts, prompt_len, vocab_size,
-                              seed + 4242) if check_parity else [])
-    out: dict = {"kernels": {}}
-    parity: dict[str, list[list[int]]] = {}
-    fallbacks: dict[str, int] = {}
-    for kern in kernels:
-        server = make_server(kern)
-        with server:
-            with span("kernel_sweep_warmup", kernel=kern):
-                server.warmup(sampling, prompt_lens=(prompt_len,))
-            out["kernels"][kern] = run_loadgen(
-                server, vocab_size=vocab_size, sessions=sessions,
-                requests_per_session=requests_per_session,
-                prompt_len=prompt_len, max_new_tokens=max_new_tokens,
-                sampling=sampling, seed=seed,
-            )
-            if probes:
-                parity[kern] = [
-                    list(server.generate(p, max_new_tokens=max_new_tokens,
-                                         sampling=sampling).tokens)
-                    for p in probes
-                ]
-            es = server.engine.stats()
-            out["kernels"][kern]["decode_kernel"] = es["decode_kernel"]
-            fallbacks[kern] = es["decode_window_scan_fallbacks"]
-    out["scan_fallbacks"] = fallbacks
-    if "scan" in out["kernels"] and "pallas" in out["kernels"]:
-        s, p = out["kernels"]["scan"], out["kernels"]["pallas"]
-        out["pallas_vs_scan"] = {
-            "tokens_per_sec_ratio": round(
-                p["tokens_per_sec"] / (s["tokens_per_sec"] or 1e-9), 3),
-            "p50_itl_delta_ms": round(
-                p["p50_itl_ms"] - s["p50_itl_ms"], 3),
-            "p99_itl_delta_ms": round(
-                p["p99_itl_ms"] - s["p99_itl_ms"], 3),
-            "p50_ttft_delta_ms": round(
-                p["p50_ttft_ms"] - s["p50_ttft_ms"], 3),
-        }
-    if parity:
-        base = kernels[0]
-        out["parity_ok"] = all(parity[k] == parity[base] for k in kernels)
-    return out

@@ -33,8 +33,8 @@ One replica's roll is four phases, each counted in
    own compile-key namespace.
 3. **warmup** — the batcher replays the server's remembered warmup spec
    off-path, so a NEW model id's programs (or a resize's new cache
-   shapes) compile before traffic returns. ``BENCH_serve_r08.json``
-   asserts zero mid-traffic compiles across the whole swap.
+   shapes) compile before traffic returns: zero mid-traffic compiles
+   across the whole swap (``tests/test_serve_rollout.py``).
 4. **rejoin** — a fresh scheduler thread starts and
    ``Router.end_drain`` returns the replica to rotation.
 

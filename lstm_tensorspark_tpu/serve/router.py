@@ -9,8 +9,7 @@ entries are replica-local, so there is no cross-replica cache coherence
 to get wrong — the source paper's driver/worker split applied to
 inference (the router is the driver, replicas are the map workers; cf.
 the DrJAX map/reduce framing in PAPERS.md). Aggregate decode tokens/s
-then scales with replicas instead of hard-capping at one scheduler
-(BENCH_serve_r02.json is the measured trajectory).
+then scales with replicas instead of hard-capping at one scheduler.
 
 Routing (:meth:`Router.submit`):
 

@@ -723,7 +723,7 @@ class ServeServer:
 
     def metrics_summary(self) -> dict:
         """JSON-ready registry view (histograms as {count,sum,p50,p99})
-        — embedded in ``/stats`` and the loadgen/bench reports so
+        — embedded in ``/stats`` and the loadgen reports so
         server-side and loadgen-side percentiles sit next to each other.
         ``replica``-labelled families export per-child entries plus one
         cross-replica aggregate under the bare name."""

@@ -73,8 +73,8 @@ class CacheFullError(RuntimeError):
 
 # jitted h/c slot scatter: the eager ``.at[].set()`` pair costs ~1 ms of
 # dispatch overhead per call on CPU (two un-jitted ops each tracing
-# through the eager path) — measured as the dominant per-continuation
-# fill cost in the BENCH_serve_r05 hot-set re-gate. One jitted program
+# through the eager path), the dominant per-continuation fill cost under
+# session churn. One jitted program
 # (cached per shape; fill batches are power-of-two padded so the shape
 # set stays tiny) makes a warm fill dispatch sub-millisecond.
 @jax.jit
@@ -1560,8 +1560,7 @@ class SessionTiers:
         into their (already acquired AND PINNED) slots with ONE scatter
         program per source class, instead of one gather+scatter dispatch
         per session — the admission path's per-continuation device cost
-        under session churn, which is exactly the hot-set-ratio gate's
-        overhead (BENCH_serve_r05.json re-gate).
+        under session churn.
 
         ``pairs`` is ``[(sid, slot), ...]`` with UNIQUE sids (admission
         guarantees it — one in-flight request per session). Returns
@@ -1747,8 +1746,7 @@ class SessionTiers:
         padded onto exactly these shapes). Called from
         ``ServeEngine.warmup`` so the first real continuation burst is
         never charged a mid-traffic XLA compile — the same discipline as
-        the engine's program lattice (and what the BENCH_serve_r05
-        re-gate measured as a 0.76 s fill p99 outlier without it). All
+        the engine's program lattice. All
         writes target the scratch slot: harmless by definition."""
         L, H = self.cache.num_layers, self.cache.hidden_size
         scratch = self.cache.scratch_slot

@@ -323,9 +323,8 @@ class TrainStepCompileCache:
     """Keyed train-step executables with trace-time compile counting and
     a warmup path — the serve engine's compile-key discipline applied to
     the training side. A (bucket, bptt_mode) step program that first
-    traces mid-measurement charges one timed sample a full XLA compile
-    (the exact failure class `tools/bench_train_scan.py` pairs runs to
-    avoid); the ``("train_step", bucket, bptt_mode)`` family is gated by
+    traces mid-measurement charges one timed sample a full XLA compile;
+    the ``("train_step", bucket, bptt_mode)`` family is gated by
     graftlint's warmup-coverage rule like the serve families, so an
     unwarmed consumer cannot land.
 
@@ -354,7 +353,7 @@ class TrainStepCompileCache:
                 return _raw(state, batch)
 
             # not donated: warmup() dispatches states its caller goes on
-            # to train from (tools/bench_train_scan.py pairs runs on them)
+            # to train from
             self._fns[key] = jax.jit(counted)
         return self._fns[key]
 

@@ -1,5 +1,4 @@
-"""Model-FLOPs accounting — ONE source shared by the benchmark harness
-(bench.py) and runtime logging (--log-flops).
+"""Model-FLOPs accounting for runtime logging (--log-flops).
 
 Matmul-only counts (the MXU work; embedding gathers and elementwise ops
 are excluded, matching standard MFU practice). Training ≈ 3× forward:

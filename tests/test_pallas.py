@@ -4,9 +4,15 @@ supported() gating, and the custom-VJP gradient path."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
+from test_chip_compile import TRAIN_SHAPES
 
 from lstm_tensorspark_tpu.ops import init_lstm_params, lstm_scan
-from lstm_tensorspark_tpu.ops.pallas_lstm import pallas_lstm_scan, supported
+from lstm_tensorspark_tpu.ops.pallas_bilstm import bilstm_supported
+from lstm_tensorspark_tpu.ops.pallas_lstm import (
+    _FUSEDX_MIN_T, _pad_to_lane, chosen_bwd_strategy, pallas_lstm_scan,
+    supported,
+)
 
 B, T, D, H = 8, 10, 16, 128
 
@@ -24,6 +30,39 @@ def test_supported_gating():
     # lane misalignment is handled by internal padding now
     assert supported(8, 100, platform="tpu")
     assert supported(8, 650, platform="tpu")  # config 3, padded to 768
+
+
+_PUBLISHED = {name: (b, t, d, h, masked)
+              for name, b, t, d, h, masked, _ in TRAIN_SHAPES}
+
+
+@pytest.mark.parametrize("shape,t,stacked,want", [
+    ("ptb_char", None, False, "resident"),
+    # both directions advance in the stacked kernel (residentx only); the
+    # two-call fallback plans the same strategy
+    ("imdb_bilstm_fwd", None, True, "residentx"),
+    ("wikitext2", None, False, "resident"),         # H=650 padded to 768
+    ("uci_seq2seq_enc", None, False, "resident"),
+    ("wikitext103", None, False, "resident"),       # U^T alone is 8.4 MiB
+    # one model, two plans: a long encoder takes the fused-x path, its
+    # horizon-24 decoder the hoisted-xproj one
+    ("uci_seq2seq_enc", 300, False, "residentx"),
+    ("uci_seq2seq_enc", 24, False, "resident"),
+], ids=["ptb_char", "imdb_bilstm", "wikitext2", "uci_seq2seq", "wikitext103",
+        "long_seq2seq_encoder", "long_seq2seq_decoder"])
+def test_backward_plan_at_published_shapes(shape, t, stacked, want):
+    """The backward strategy the kernels' own decision functions choose
+    at each BASELINE shape (bf16 weights, as the launch scripts run them):
+    a cost-model change that flips a plan at a published shape fails
+    here. Compiling the plans is tests/test_chip_compile.py's."""
+    B, T, D, H, masked = _PUBLISHED[shape]
+    T = t or T
+    Dp = _pad_to_lane(D) if T >= _FUSEDX_MIN_T else None
+    if stacked:
+        assert bilstm_supported(B, H, D, T, platform="tpu",
+                                param_dtype_bytes=2, has_mask=masked)
+    assert chosen_bwd_strategy(B, T, _pad_to_lane(H), 2, has_mask=masked,
+                               Dp=Dp) == want
 
 
 def test_interpret_forward_parity():

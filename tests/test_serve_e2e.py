@@ -110,16 +110,30 @@ def test_http_endpoint_roundtrip(stack, refs):
     assert stats["batcher"]["completed"] >= 1
 
 
-def test_cli_serve_selftest():
-    """The acceptance command: `cli serve --selftest` exits 0 (PASS)."""
+@pytest.mark.parametrize("mode,rc", [
+    (["--selftest"], 0),
+    # speed is measured in one place (BENCHMARK.json + benchmark/): the
+    # CLI has no load test of its own, and none comes back through a comma
+    (["--loadgen"], 2),
+    (["--selftest", "--replicas", "1,2"], 2),
+    (["--selftest", "--decode-kernel", "pallas,scan"], 2),
+], ids=["selftest", "no_loadgen", "no_replica_list", "no_kernel_list"])
+def test_cli_serve_selftest(mode, rc):
+    """The acceptance command: `cli serve --selftest` exits 0 (PASS);
+    what is not a mode or a value of `cli serve` is a usage error."""
     from lstm_tensorspark_tpu.cli import main
 
-    rc = main([
-        "serve", "--selftest", "--vocab-size", "31", "--hidden-units", "12",
+    argv = [
+        "serve", *mode, "--vocab-size", "31", "--hidden-units", "12",
         "--num-layers", "1", "--sessions", "2", "--max-new-tokens", "4",
         "--prefill-buckets", "8", "--batch-buckets", "2",
-    ])
-    assert rc == 0
+    ]
+    if rc == 0:
+        assert main(argv) == 0
+        return
+    with pytest.raises(SystemExit) as usage:
+        main(argv)
+    assert usage.value.code == rc
 
 
 def test_loadgen_reports_latency_and_throughput(stack):

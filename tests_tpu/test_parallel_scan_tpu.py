@@ -8,8 +8,7 @@ tests_tpu/test_pallas_decode_tpu.py does for the serve plane: the CPU
 suite proves the ALGEBRA (tests/test_parallel_scan.py — grads allclose
 at fp64-validated tolerances), but the perf claim is about the
 accelerator's latency-bound sequential chain. On CPU the assoc path's
-extra dense-compose FLOPs usually lose (the honest ratio lives in
-BENCH_train_scan_r01.json); on TPU the log-depth tree of MXU matmuls
+extra dense-compose FLOPs usually lose; on TPU the log-depth tree of MXU matmuls
 must be at least break-even at T=400 or the plan/tile is mis-chosen.
 
 Perf gate: assoc tokens/s >= 1.0x sequential (median of warm repeats,

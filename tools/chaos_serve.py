@@ -23,7 +23,7 @@ Four phases, each building a fresh in-process stack from one fixed seed:
    the priority class p99 TTFT must hold the configured SLO while
    best-effort sheds with honest ``Retry-After`` 429s; the same burst is
    replayed with the old indiscriminate-FIFO settings for contrast, and
-   both land in BENCH_serve_r04.json (``--json``).
+   both land in the report (stdout; ``--json`` also writes it to a file).
 5. **host death** (``host_die`` fault kind) — a REMOTE replica (a real
    ``cli serve --http`` subprocess behind the front router via the RPC
    transport, serve/remote.py) is SIGKILLed mid-conversation; the
@@ -40,8 +40,7 @@ Four phases, each building a fresh in-process stack from one fixed seed:
    closes the circuit, fresh traffic routes there again) and the full
    conversation stays token-identical. A dropped-response generate then
    proves exactly-once: the transport retries under the request_id and
-   the peer replays its settled reply — ZERO duplicate decodes
-   (``--json-partition`` → BENCH_serve_r09.json).
+   the peer replays its settled reply — ZERO duplicate decodes.
 
 Wired into tools/verify.sh after the serve smoke (sequenced, never
 concurrent with the timed suite). Exit 0 on PASS, 1 on any violated
@@ -51,7 +50,7 @@ it printed (see docs/OPERATIONS.md "Chaos drill failed").
 Usage::
 
     JAX_PLATFORMS=cpu python tools/chaos_serve.py [--json OUT] \
-        [--json-partition OUT2] [--slo-ms 1000] [--seed 0]
+        [--slo-ms 1000] [--seed 0]
 """
 
 from __future__ import annotations
@@ -831,12 +830,8 @@ def _phase_burst_shed(params, seed, slo_ms, failures):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--json", type=str, default=None,
-                    help="write the machine-readable drill report here "
-                         "(BENCH_serve_r04.json in CI)")
-    ap.add_argument("--json-partition", type=str, default=None,
-                    help="write the partition/heal phase's zero-lost / "
-                         "zero-duplicate / routed-around accounting here "
-                         "(BENCH_serve_r09.json in CI)")
+                    help="also write the machine-readable drill report "
+                         "(the JSON line printed on stdout) here")
     ap.add_argument("--slo-ms", type=float, default=1000.0,
                     help="priority-class p99 TTFT SLO under the 4x burst "
                          "(CPU-noise-tolerant default)")
@@ -867,15 +862,6 @@ def main(argv=None) -> int:
             json.dump(summary, f, indent=1, sort_keys=True)
         print(f"chaos_serve: report written to {args.json}",
               file=sys.stderr)
-    if args.json_partition:
-        part = dict(summary["partition"])
-        part["note"] = "chaos_serve partition/heal (ISSUE 17)"
-        part["result"] = ("PASS" if not any(
-            f.startswith("partition:") for f in failures) else "FAIL")
-        with open(args.json_partition, "w") as f:
-            json.dump(part, f, indent=1, sort_keys=True)
-        print("chaos_serve: partition report written to "
-              f"{args.json_partition}", file=sys.stderr)
     print(f"chaos_serve: {summary['result']} in {summary['wall_s']}s"
           + (f" — {len(failures)} violated invariant(s)" if failures
              else ""),

@@ -10,9 +10,8 @@ from the one table (resilience/exit_codes.py):
 
 ``--update-baseline`` rewrites tools/lint_baseline.txt to the current
 finding set (keeping existing justifications; new entries get a TODO a
-human must replace). ``--json PATH`` writes the machine-readable report
-(mirrors serve/loadgen.py --json) so finding counts can be trended next
-to the BENCH_*.json baselines; when a previous report exists at the
+human must replace). ``--json PATH`` writes the machine-readable
+report; when a previous report exists at the
 same path the summary line grows per-rule ``d(rule)=±k`` deltas vs it.
 ``--changed GIT_REF`` is the sub-second pre-commit mode: only files
 changed vs the ref plus their importers (from the project model) are

@@ -211,8 +211,8 @@ def report(findings: list[Finding], baseline: dict[str, str],
     deltas = ""
     if json_path:
         # per-rule deltas vs the PREVIOUS report at this path, when one
-        # exists (verify.sh writes LINT_report.json in place each run, so
-        # the summary line trends finding movement next to BENCH_*.json).
+        # exists (verify.sh writes the untracked LINT_report.json in place
+        # each run, so the summary line trends finding movement).
         # Scoped (--changed) runs neither compute deltas nor count as a
         # trend point: partial counts vs full-tree counts would print
         # large spurious deltas either way — the scoped flag in the

@@ -6,8 +6,8 @@
 # docs/LINT.md) over lstm_tensorspark_tpu/ + tools/, gated on
 # tools/lint_baseline.txt. Prints its own `GRAFTLINT new=N baseline=M`
 # summary line — with per-rule `d(rule)=±k` deltas vs the previous
-# LINT_report.json when one exists (the report is rewritten in place
-# each run, trendable next to BENCH_*.json) — and exits REGRESSION_RC
+# LINT_report.json when one exists (an untracked local file, listed in
+# .gitignore, rewritten in place each run) — and exits REGRESSION_RC
 # (3) on NEW findings — the run aborts HERE, before the ~30 min suite,
 # because a lint regression is a deterministic fail and the feedback
 # should be seconds, not minutes (phase-0 budget: 10 s; see
@@ -37,10 +37,12 @@
 # priority p99 TTFT holds its SLO under a 4x burst while best-effort
 # sheds with honest Retry-After 429s; a blackholed remote host opens
 # its circuit, is routed around losing nothing, and REJOINS on heal
-# with replay-deduped exactly-once generates) and rewrites
-# BENCH_serve_r04.json + BENCH_serve_r09.json — sequenced after the
-# smoke, never concurrent with the timed suite; ~60 s budget, 900 s
-# hard cap.
+# with replay-deduped exactly-once generates); its report is the JSON
+# line it prints — sequenced after the smoke, never concurrent with the
+# timed suite; ~60 s budget, 900 s hard cap.
+#
+# Nothing here writes a tracked file: after a run `git status` shows
+# what it showed before.
 #
 # Usage: tools/verify.sh        (from anywhere; cd's to the repo root)
 # Exit:  graftlint's code on lint regressions (3), else tier1_diff's on
@@ -91,9 +93,6 @@ fi
 # plus host_die's 15 s retirement wait and partition's 25 s circuit-
 # open + 20 s rejoin waits on top of the ~30 s fault phases) so the
 # drill's failure diagnostics always print before the outer kill
-# fires. Rewrites BENCH_serve_r04.json (burst-shedding + host-death
-# trajectory) and BENCH_serve_r09.json (partition/heal zero-lost /
-# zero-duplicate / routed-around accounting) in place.
-JAX_PLATFORMS=cpu timeout -k 10 900 python tools/chaos_serve.py \
-  --json BENCH_serve_r04.json --json-partition BENCH_serve_r09.json
+# fires.
+JAX_PLATFORMS=cpu timeout -k 10 900 python tools/chaos_serve.py
 exit $?
