@@ -54,7 +54,7 @@ backward strategies:
   (z_t, c_{t-1}) in-kernel — bit-identical in f32 — so the backward
   streams one fewer [T,B,H] tensor than a save-everything design;
 - **tiled fused BPTT** (`_lstm_bwd_tiled_kernel`): the sequential kernel
-  computes only dz (streaming U^T in tiles for the dh carry);
+  computes only dz (streaming U in column tiles for the dh carry);
 - in EVERY strategy the weight cotangents dU/dW/db and dxs are single
   large MXU matmuls OUTSIDE the kernel (XLA's job — they contract over
   T·B at once; an in-kernel dU accumulate would serialize one more MXU
@@ -131,6 +131,14 @@ def _rbytes(pbytes: int) -> int:
     return 4
 
 
+def _dot_ut(dz, u_ref):
+    """``dz @ U^T`` with U read as it is stored ([H, 4H], or a column tile
+    of it): dz's gate axis contracts with U's SECOND axis, f32 accumulate."""
+    return jax.lax.dot_general(
+        dz.astype(u_ref.dtype), u_ref[:], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
 # ---------------------------------------------------------------------------
 # Unified VMEM cost model. Every supported()/strategy decision reads these
 # four functions; there is no second, implicit accounting (ADVICE.md #1).
@@ -176,7 +184,9 @@ def _residentx_bwd_vmem(B: int, H: int, Dp: int, pbytes: int,
     if has_mask:
         streamed += c * B * _LANE * 4  # mask blocks
     return (
-        2 * 4 * H * H * pbytes  # U (z recompute) + U^T (dh carry) resident
+        2 * 4 * H * H * pbytes  # U resident (both matmuls read it); the x2
+                                # is margin: the chunk plans measured on
+                                # the chip were chosen at this count
         + Dp * 4 * H * pbytes  # W resident
         + 4 * H * 4  # bias
         + c * B * 4 * H * 4  # in-kernel zx chunk (live value)
@@ -218,7 +228,7 @@ def _resident_bwd_vmem(B: int, H: int, pbytes: int,
     if has_mask:
         streamed += c * B * _LANE * 4  # mask blocks
     return (
-        4 * H * H * pbytes  # U^T resident
+        4 * H * H * pbytes  # U resident
         + streamed * 2  # double-buffered pipelining
         + 4 * B * H * 4  # dh/dc scratch + dh0/dc0 out
     )
@@ -244,7 +254,7 @@ def _tiled_fwd_vmem(B: int, H: int, pbytes: int, save_residuals: bool,
 def _tiled_bwd_vmem(B: int, H: int, pbytes: int, ttile: int,
                     has_mask: bool = False) -> int:
     r = _rbytes(pbytes)
-    v = 2 * ttile * H * pbytes  # U^T row-tile
+    v = 2 * H * ttile * pbytes  # U column-tile
     v += 2 * B * 4 * H * r  # z in block (stream dtype)
     v += 2 * 2 * B * H * 4  # dys/c_prev in blocks (c_t recomputed)
     v += 2 * B * 4 * H * r  # dz out block (stream dtype)
@@ -288,7 +298,7 @@ def _plan_fwd(B: int, H: int, pbytes: int, *, save_residuals: bool,
 def _plan_bwd(B: int, H: int, pbytes: int, has_mask: bool = False,
               Dp: int | None = None) -> tuple[str, int] | None:
     """(strategy, ttile) for the fused backward kernel, or None → recompute
-    fallback. ttile tiles U^T's leading (4H) dim. The residentx strategy
+    fallback. ttile tiles U's gate (4H) dim. The residentx strategy
     (recompute-z) is only offered when the matching residentx FORWARD also
     fits — its cs-only residual contract requires the pair."""
     if Dp is not None and _residentx_fwd_vmem(
@@ -454,10 +464,10 @@ def _lstm_bwdx_kernel(*refs, hidden: int, dpad: int, chunk: int,
     the tiled backward uses. That keeps the sequential chain to two MXU
     ops per step (z recompute, dh carry) instead of three — the per-step
     accumulate serialized real MXU issue slots with the chain."""
-    n_in = 10 + has_mask
+    n_in = 9 + has_mask
     xs_ref, dys_ref, cprev_ref, hprev_ref = refs[:4]
     mask_ref = refs[4] if has_mask else None
-    w_ref, b_ref, u_ref, ut_ref, dhT_ref, dcT_ref = refs[4 + has_mask:n_in]
+    w_ref, b_ref, u_ref, dhT_ref, dcT_ref = refs[4 + has_mask:n_in]
     dz_ref, dh0_ref, dc0_ref = refs[n_in:n_in + 3]
     dh_scr, dc_scr = refs[n_in + 3:]
     t = pl.program_id(0)
@@ -503,8 +513,7 @@ def _lstm_bwdx_kernel(*refs, hidden: int, dpad: int, chunk: int,
         dg = dc_new * i * (1.0 - g * g)
         dz = jnp.concatenate([di, df, dg, do], axis=1)  # [B, 4H] f32
         dz_ref[s] = dz.astype(dz_ref.dtype)  # stored in the stream dtype
-        dh = jnp.dot(dz.astype(ut_ref.dtype), ut_ref[:],
-                     preferred_element_type=jnp.float32)
+        dh = _dot_ut(dz, u_ref)
         dc = dc_new * f
         if has_mask:
             # frozen fraction of the cotangents bypasses the gates
@@ -607,7 +616,7 @@ def _lstm_bwd_kernel(*refs, hidden: int, chunk: int, has_mask: bool):
     n_in = 6 + has_mask
     z_ref, dys_ref, cprev_ref = refs[:3]
     mask_ref = refs[3] if has_mask else None
-    ut_ref, dhT_ref, dcT_ref = refs[3 + has_mask:n_in]
+    u_ref, dhT_ref, dcT_ref = refs[3 + has_mask:n_in]
     dz_ref, dh0_ref, dc0_ref = refs[n_in:n_in + 3]
     dh_scr, dc_scr = refs[n_in + 3:]
     t = pl.program_id(0)
@@ -645,8 +654,7 @@ def _lstm_bwd_kernel(*refs, hidden: int, chunk: int, has_mask: bool):
         dg = dc_new * i * (1.0 - g * g)
         dz = jnp.concatenate([di, df, dg, do], axis=1)  # [B, 4H] f32
         dz_ref[s] = dz.astype(dz_ref.dtype)  # stored in the stream dtype
-        dh = jnp.dot(dz.astype(ut_ref.dtype), ut_ref[:],
-                     preferred_element_type=jnp.float32)
+        dh = _dot_ut(dz, u_ref)
         dc = dc_new * f
         if has_mask:
             # frozen fraction of the cotangents bypasses the gates
@@ -742,7 +750,7 @@ def _lstm_tiled_kernel(*refs, hidden: int, htile: int, save_residuals: bool,
 
 def _lstm_bwd_tiled_kernel(*refs, hidden: int, ttile: int, has_mask: bool):
     """Tiled BPTT: computes ONLY the sequential part — dz_t and the dh/dc
-    carries — streaming U^T in [ttile, H] row-tiles for the carry matmul.
+    carries — streaming U in [H, ttile] column-tiles for the carry matmul.
     The weight cotangents (dU, dW, db) and dxs contract over all T·B outside
     the kernel as single large MXU matmuls (`_pallas_backward`). The cell
     state c_t is recomputed from (z_t, c_{t-1}). With ``has_mask`` the
@@ -751,7 +759,7 @@ def _lstm_bwd_tiled_kernel(*refs, hidden: int, ttile: int, has_mask: bool):
     n_in = 6 + has_mask
     z_ref, dys_ref, cprev_ref = refs[:3]
     mask_ref = refs[3] if has_mask else None
-    ut_ref, dhT_ref, dcT_ref = refs[3 + has_mask:n_in]
+    u_ref, dhT_ref, dcT_ref = refs[3 + has_mask:n_in]
     dz_ref, dh0_ref, dc0_ref = refs[n_in:n_in + 3]
     scratch = refs[n_in + 3:]
     if has_mask:
@@ -802,10 +810,7 @@ def _lstm_bwd_tiled_kernel(*refs, hidden: int, ttile: int, has_mask: bool):
             dc_scr[:] = dc_new * f
         dhacc_scr[:] = jnp.zeros_like(dhacc_scr)
 
-    dhacc_scr[:] = dhacc_scr[:] + jnp.dot(
-        dz_tiles[k].astype(ut_ref.dtype), ut_ref[:],
-        preferred_element_type=jnp.float32,
-    )
+    dhacc_scr[:] = dhacc_scr[:] + _dot_ut(dz_tiles[k], u_ref)
 
     @pl.when(k == K - 1)
     def _():
@@ -1043,7 +1048,10 @@ def _pallas_backward(fused, params, xs, h0, c0, mask_tbl, ys, z, cs,
     h_prev = jnp.concatenate([h0.astype(jnp.float32)[None], ys_t[:-1]], axis=0)
     c_prev = jnp.concatenate([c0.astype(jnp.float32)[None], cs[:-1]], axis=0)
     dys_t = jnp.moveaxis(dys.astype(jnp.float32), 0, 1)
-    u_t = fused.recurrent.T  # [4H, H], compute dtype
+    # U goes in as stored, [H, 4H]; the kernels contract its second axis
+    # (`_dot_ut`). A `.T` out here made XLA carry all 96 f32[1024,1024] of
+    # config 5's state transposed: 240 copies, 1.92 ms a step (PERF.md PR 33).
+    u = fused.recurrent
 
     if strategy == "residentx":
         C = _chunk_for(T, parg)
@@ -1066,12 +1074,10 @@ def _pallas_backward(fused, params, xs, h0, c0, mask_tbl, ys, z, cs,
             pl.BlockSpec(memory_space=pltpu.VMEM),                   # W
             pl.BlockSpec(memory_space=pltpu.VMEM),                   # bias
             pl.BlockSpec(memory_space=pltpu.VMEM),                   # U
-            pl.BlockSpec(memory_space=pltpu.VMEM),                   # U^T
             pl.BlockSpec(memory_space=pltpu.VMEM),                   # dhT
             pl.BlockSpec(memory_space=pltpu.VMEM),                   # dcT
         ]
-        operands += [w, fused.bias.reshape(1, -1).astype(jnp.float32),
-                     fused.recurrent, u_t,
+        operands += [w, fused.bias.reshape(1, -1).astype(jnp.float32), u,
                      dhT.astype(jnp.float32), dcT.astype(jnp.float32)]
         dz, dh0, dc0 = pl.pallas_call(
             functools.partial(_lstm_bwdx_kernel, hidden=H, dpad=Dp,
@@ -1112,11 +1118,11 @@ def _pallas_backward(fused, params, xs, h0, c0, mask_tbl, ys, z, cs,
             )
             operands.append(mask_tbl)
         in_specs += [
-            pl.BlockSpec(memory_space=pltpu.VMEM),                   # U^T
+            pl.BlockSpec(memory_space=pltpu.VMEM),                   # U
             pl.BlockSpec(memory_space=pltpu.VMEM),                   # dhT
             pl.BlockSpec(memory_space=pltpu.VMEM),                   # dcT
         ]
-        operands += [u_t, dhT.astype(jnp.float32), dcT.astype(jnp.float32)]
+        operands += [u, dhT.astype(jnp.float32), dcT.astype(jnp.float32)]
         dz, dh0, dc0 = pl.pallas_call(
             kernel,
             grid=(n,),
@@ -1154,12 +1160,12 @@ def _pallas_backward(fused, params, xs, h0, c0, mask_tbl, ys, z, cs,
             )
             operands.append(mask_tbl)
         in_specs += [
-            pl.BlockSpec((ttile, H), lambda t, k: (k, 0),
-                         memory_space=pltpu.VMEM),                   # U^T tile
+            pl.BlockSpec((H, ttile), lambda t, k: (0, k),
+                         memory_space=pltpu.VMEM),                   # U tile
             pl.BlockSpec(memory_space=pltpu.VMEM),                   # dhT
             pl.BlockSpec(memory_space=pltpu.VMEM),                   # dcT
         ]
-        operands += [u_t, dhT.astype(jnp.float32), dcT.astype(jnp.float32)]
+        operands += [u, dhT.astype(jnp.float32), dcT.astype(jnp.float32)]
         scratch = [
             pltpu.VMEM((B, H), jnp.float32),          # dh carry
             pltpu.VMEM((B, H), jnp.float32),          # dc carry

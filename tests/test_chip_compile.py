@@ -15,6 +15,7 @@ up to a minute and a half each, run by hand before a chip call:
 
 import contextlib
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
 
@@ -101,6 +102,13 @@ def _kernel_calls(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _copies_of(compiled, *dims: str) -> list:
+    """The `copy` instructions of the compiled text whose result is an f32
+    array of one of ``dims`` ("1024,1024")."""
+    return re.findall(r"= f32\[(?:%s)\]\S* copy\(" % "|".join(dims),
+                      compiled.as_text())
+
+
 # ---- train recurrence, forward + backward -----------------------------
 
 # (name, B, T, D, H, masked, reversed) — the BASELINE.md configs' scans at
@@ -158,6 +166,41 @@ def test_stacked_bilstm_fwd_bwd_compiles(chip):
     compiled = _compile(jax.grad(loss, argnums=(0, 1)),
                         _on(chip, pf), _on(chip, pb), xs, mask)
     assert _kernel_calls(compiled) >= 2
+
+
+def test_layer_state_keeps_its_layout_under_adam(chip):
+    """One config-5 layer (B=64, T=128, H=1024, bf16) trained for two Adam
+    steps inside a `lax.scan` whose carry is the donated parameters and
+    moments, as the cell's K-step program carries them: the backward
+    kernel reads U as it is stored, so the compiler has no reason to carry
+    the state transposed and relay it around every Adam fusion."""
+    import optax
+
+    B, T, H = 64, 128, 1024
+    optimizer = optax.adam(1e-3)
+    params = jax.eval_shape(
+        lambda: init_lstm_params(jax.random.PRNGKey(0), H, H))
+    opt_state = jax.eval_shape(optimizer.init, params)
+
+    def loss(params, xs):
+        (hT, _), ys = pallas_lstm_scan(params, xs,
+                                       compute_dtype=jnp.bfloat16)
+        return jnp.sum(ys) + jnp.sum(hT)
+
+    def two_steps(params, opt_state, xs):
+        def step(carry, x):
+            params, opt_state = carry
+            updates, opt_state = optimizer.update(
+                jax.grad(loss)(params, x), opt_state, params)
+            return (optax.apply_updates(params, updates), opt_state), None
+
+        return jax.lax.scan(step, (params, opt_state), xs)[0]
+
+    xs = jax.ShapeDtypeStruct((2, B, T, H), jnp.float32)
+    compiled = jax.jit(two_steps, donate_argnums=(0, 1)).lower(
+        *_on(chip, (params, opt_state, xs))).compile()
+    assert _kernel_calls(compiled) >= 2
+    assert not _copies_of(compiled, "1024,1024")
 
 
 # ---- serve decode windows ---------------------------------------------
@@ -270,13 +313,12 @@ def test_config5_train_step_compiles(chip):
 def _state_updated_in_place(compiled, state):
     """The donated train state is the program's to write into: no copy of
     the embedding, the head kernel or one of their Adam moments (205 MB
-    each) anywhere in the compiled text, and at least the parameters and
-    the optimizer's state aliased to outputs."""
-    import re
-
-    copies = re.findall(r"= f32\[(?:50000,1024|1024,50000)\]\S* copy\(",
-                        compiled.as_text())
-    assert not copies, copies
+    each) anywhere in the compiled text, nor of a layer's per-gate matrix
+    or one of its moments (240 a step, 1.92 ms, while the backward kernel
+    was handed `fused.recurrent.T`: PERF.md, PR 33), and at least the
+    parameters and the optimizer's state aliased to outputs."""
+    copies = _copies_of(compiled, "50000,1024", "1024,50000", "1024,1024")
+    assert not copies, copies[:4]
     held = sum(x.size * x.dtype.itemsize
                for x in jax.tree.leaves((state.params, state.opt_state)))
     assert held > 1.6e9
@@ -528,8 +570,6 @@ def test_decoder_serve_programs_fit_the_chip(chip, program):
     memory = compiled.memory_analysis()
     pool_bytes = sum(p.size * 2 for p in pools)
     assert memory.alias_size_in_bytes >= pool_bytes          # updated in place
-    import re
-
     assert not re.search(r"= bf16\[2294,256,640\]\S* copy\(", compiled.as_text())
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 16 * 2 ** 30)

@@ -675,3 +675,45 @@ def test_chunk2_resident_bf16_bigh_parity():
         lambda a, b: np.testing.assert_allclose(a, b, rtol=8e-2, atol=8e-3),
         g1, g2,
     )
+
+
+@pytest.mark.parametrize("strategy", ["residentx", "resident", "tiled"])
+def test_carry_grads_with_unequal_gate_blocks_of_u(monkeypatch, strategy):
+    """The backward kernels read U as stored, [H, 4H], and contract dz's
+    gate axis with U's second axis. With the four gate blocks of U at
+    scales 0.25 / 1 / 2 / 4 a contraction over the wrong axis of one block,
+    or a column tile taken from the wrong place, moves dh0 and dxs far
+    outside the tolerance; the tiled case runs one tile per gate block."""
+    import lstm_tensorspark_tpu.ops.pallas_lstm as pallas_mod
+
+    if strategy == "residentx":
+        monkeypatch.setattr(pallas_mod, "_FUSEDX_MIN_T", 0)
+    if strategy == "tiled":
+        monkeypatch.setattr(pallas_mod, "_resident_bwd_vmem",
+                            lambda *a, **k: 10**12)
+        monkeypatch.setattr(
+            pallas_mod, "_tiled_bwd_vmem",
+            lambda B, H, pbytes, ttile, has_mask=False:
+                0 if ttile == H else 10**12)
+    Dp = _pad_to_lane(D) if strategy == "residentx" else None
+    assert chosen_bwd_strategy(B, T, H, 4, Dp=Dp) == strategy
+
+    params, xs = _setup()
+    params = params._replace(
+        U_i=params.U_i * 0.25, U_g=params.U_g * 2.0, U_o=params.U_o * 4.0)
+    h0 = jax.random.normal(jax.random.PRNGKey(4), (B, H))
+    c0 = jax.random.normal(jax.random.PRNGKey(5), (B, H))
+
+    def loss(scan_fn):
+        def f(h, x):
+            (hT, cT), ys = scan_fn(params, x, (h, c0))
+            return jnp.mean(ys**2) + jnp.sum(hT * 0.3) + jnp.sum(cT * 0.1)
+        return f
+
+    import functools
+    g1 = jax.grad(loss(functools.partial(pallas_lstm_scan, interpret=True)),
+                  argnums=(0, 1))(h0, xs)
+    g2 = jax.grad(loss(lstm_scan), argnums=(0, 1))(h0, xs)
+    for got, want in zip(g1, g2):
+        assert float(jnp.abs(want).max()) > 1e-3  # the carry path is live
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-6)
