@@ -1316,11 +1316,18 @@ def build_serve_parser() -> argparse.ArgumentParser:
                         "optimizer does not matter); random init otherwise")
     # --- the decoder family (models/decoder.py, serve/decoder_engine.py) ---
     p.add_argument("--model-file", type=str, default=None,
-                   help="serve a DECODER (latent attention over a paged "
-                        "latent cache, expert layers) described by this "
-                        "JSON file: the published config.json keys, the "
-                        "share this chip holds, assumed.weights_seed (e.g. "
-                        "benchmark/configs/deepseek-v2-ep4.json). The same "
+                   help="serve a DECODER described by this JSON file: the "
+                        "published config.json keys, the share this chip "
+                        "holds, assumed.weights_seed. Its model_type picks "
+                        "the block: deepseek_v2 (latent attention over a "
+                        "paged latent cache, group-limited routed and "
+                        "shared experts; e.g. benchmark/configs/"
+                        "deepseek-v2-ep4.json, sized by --latent-pool-gib) "
+                        "or mellum (grouped-query attention over a paged "
+                        "K/V cache whose window layers keep a session's "
+                        "last pages, renormalised top-k experts; "
+                        "benchmark/configs/mellum2-12b-l8.json, sized by "
+                        "--kv-pool-gib and --kv-window-gib). The same "
                         "server, router, batcher and session API; the "
                         "LSTM model flags are then unused, and the "
                         "LSTM-only features (--prefix-cache/--prefix-fabric "
@@ -1342,8 +1349,21 @@ def build_serve_parser() -> argparse.ArgumentParser:
                    help="decoder: device memory of the paged latent cache, "
                         "all layers together (pages are taken as sessions "
                         "grow and freed when they end; nothing is evicted)")
+    p.add_argument("--kv-pool-gib", type=float, default=0.25,
+                   help="decoder with keys and values per head (model_type "
+                        "mellum): device memory of the paged K/V cache, "
+                        "both kinds of page together (full-attention "
+                        "layers' pages are kept while a session lives; "
+                        "window layers' pages are returned as it outgrows "
+                        "them)")
+    p.add_argument("--kv-window-gib", type=float, default=None,
+                   help="the part of --kv-pool-gib given to the window "
+                        "layers' pages (a session holds at most 7 of them "
+                        "at pages of 256 and a 1,024-token window, "
+                        "however long it is); required with such a "
+                        "model file: nothing derives it")
     p.add_argument("--page-size", type=int, default=256,
-                   help="decoder: tokens per page of the latent cache")
+                   help="decoder: tokens per page of the cache")
     p.add_argument("--max-context", type=int, default=4096,
                    help="decoder: the most tokens one session may hold")
     p.add_argument("--prefill-rows", type=int, default=4,
@@ -2014,7 +2034,8 @@ def _refuse_for_decoder(args, n_replicas: int) -> None:
 def _build_decoder_stack(args, n_replicas: int = 1):
     """(params, cfg, server) for ``--model-file``: the same `ServeServer`
     -> router -> `Batcher` stack over a `DecoderEngine` and its paged
-    latent cache. Weights are random from the FILE's seed
+    cache, whichever block the file's ``model_type`` names. Weights are
+    random from the FILE's seed
     (``assumed.weights_seed``), never from --seed: one file is one model."""
     from .models import decoder
     from .obs import NULL_REGISTRY, REGISTRY
@@ -2026,12 +2047,25 @@ def _build_decoder_stack(args, n_replicas: int = 1):
     registry = (NULL_REGISTRY if getattr(args, "telemetry", "on") == "off"
                 else REGISTRY)
     page = args.page_size
-    token_bytes = cfg.latent_width * 2 * cfg.num_hidden_layers
-    num_pages = int(args.latent_pool_gib * 2 ** 30) // (page * token_bytes)
-    if num_pages < 1:
+    if cfg.grouped:
+        if args.kv_window_gib is None:
+            raise SystemExit(
+                f"--model-file {args.model_file} (model_type "
+                f"{cfg.model_type}) keeps two kinds of page: give "
+                "--kv-window-gib, the window layers' part of --kv-pool-gib")
+        gib = (args.kv_pool_gib - args.kv_window_gib, args.kv_window_gib)
+        flag = "--kv-pool-gib/--kv-window-gib"
+    else:
+        gib, flag = (args.latent_pool_gib,), "--latent-pool-gib"
+    # a kind's page: `page` rows of `latent_width` bf16 lanes in each of
+    # the kind's layers
+    page_bytes = [page * cfg.latent_width * 2 * cfg.layer_kinds.count(k)
+                  for k in range(len(gib))]
+    num_pages = tuple(int(g * 2 ** 30) // b for g, b in zip(gib, page_bytes))
+    if min(num_pages) < 1:
         raise SystemExit(
-            f"--latent-pool-gib {args.latent_pool_gib} holds no page of "
-            f"{page} tokens ({page * token_bytes} bytes)")
+            f"{flag} {gib} hold no page of {page} tokens ({page_bytes} "
+            "bytes a page)")
     params = decoder.init_decoder(
         int(doc.get("assumed", {}).get("weights_seed", 0)), cfg,
         dtype=np.dtype(args.weights_dtype))
@@ -2220,7 +2254,7 @@ def _serve_selftest_decoder(args) -> int:
         print(f"session {i}: token {j} {a[0][j]} vs {b[0][j]}, logits "
               f"{la:.5f} vs {lb:.5f}: {'a rounding tie' if tie else 'REAL'}")
     cache = server.engine.cache.stats()
-    leaked = cache["latent_pages_in_use"] or cache["live_sessions"]
+    leaked = server.engine.cache.pages_in_use or cache["live_sessions"]
     print(json.dumps({
         "note": "serve_selftest", "family": "decoder",
         "sessions": len(turns), "tokens_per_turn": n_new,

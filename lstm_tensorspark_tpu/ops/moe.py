@@ -1,12 +1,14 @@
 """Routed experts for one chip's share of an expert-parallel layer.
 
-`route` is the published group-limited greedy router over ALL experts
-(softmax scores, the best ``topk_group`` of ``n_group`` groups by their
-largest score, top-``k`` of what stays, weights ``scale * p`` NOT
-renormalised). `routed_experts` computes the terms of the experts THIS chip
-holds (a contiguous range ``first .. first + held``) and nothing that stands
-in for the others: pairs routed elsewhere are dropped before any work is
-done for them.
+`route` is the published greedy router over ALL experts: softmax scores,
+the best ``topk_group`` of ``n_group`` groups by their largest score (one
+group: no limit), top-``k`` of what stays, weights ``scale * p``, divided by
+their sum over the ``k`` picks where the model says so (``renormalise``:
+the published ``norm_topk_prob``; DeepSeek-V2 does not, Mellum2 does).
+`routed_experts` computes the terms of the experts THIS chip holds (a
+contiguous range ``first .. first + held``, which may be all of them) and
+nothing that stands in for the others: pairs routed elsewhere are dropped
+before any work is done for them.
 
 The pairs that land here are laid out expert by expert in tiles of ``tm``
 rows (`plan_tiles`, a few gathers on the device, no scatter), and one
@@ -32,7 +34,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def route(x, w_router, *, n_group: int, topk_group: int, top_k: int,
-          scale: float):
+          scale: float, renormalise: bool):
     """``x [T, D]``, ``w_router [D, E]`` -> ``(experts [T, k] int32,
     weights [T, k] float32)``. Scores and softmax in float32 (a bf16 router
     flips near-tied picks, and a flipped pick is a different expert's
@@ -47,6 +49,8 @@ def route(x, w_router, *, n_group: int, topk_group: int, top_k: int,
         jnp.arange(t)[:, None], keep].set(True)
     masked = jnp.where(jnp.repeat(group_ok, e // n_group, axis=1), p, 0.0)
     w, experts = jax.lax.top_k(masked, top_k)
+    if renormalise:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
     return experts.astype(jnp.int32), scale * w
 
 
@@ -151,14 +155,15 @@ def grouped_matmul(x, w, tile_expert, n_tiles, *, tm: int,
 
 def routed_experts(x, live, w_router, w_gate_up, w_down, *, first: int,
                    n_group: int, topk_group: int, top_k: int, scale: float,
-                   tm: int, interpret: bool = False):
+                   renormalise: bool, tm: int, interpret: bool = False):
     """The routed part of one expert layer on this chip: ``x [T, D]`` ->
     ``(sum over the experts held of weight * Expert(x) [T, D] float32,
     counts)``. ``w_gate_up [held, D, 2I]`` is ``[W_gate | W_up]``,
     ``w_down [held, I, D]``."""
     held, _, two_i = w_gate_up.shape
     experts, weights = route(x, w_router, n_group=n_group,
-                             topk_group=topk_group, top_k=top_k, scale=scale)
+                             topk_group=topk_group, top_k=top_k, scale=scale,
+                             renormalise=renormalise)
     plan = plan_tiles(experts, live, first=first, held=held, tm=tm)
     gmm = functools.partial(grouped_matmul, tile_expert=plan["tile_expert"],
                             n_tiles=plan["n_tiles"], tm=tm,

@@ -6,7 +6,7 @@ State Space Duality and Portable O(1) Autoregressive Caching"). This
 package turns the repo's training LM + one-shot sampler (models/generate.py)
 into a serving engine:
 
-- ``decoder_engine`` (+ ``state_cache.PagedLatentCache``): the second
+- ``decoder_engine`` (+ ``state_cache.PagedCache``): the second
   FAMILY — a decoder with latent attention whose session state is pages of
   latents that grow with the session; it answers the same calls as
   ``engine`` (``engine.build_engine`` picks by the configuration's family)
@@ -98,7 +98,7 @@ admit→queue→prefill→decode→readback timeline.
 CLI: ``python -m lstm_tensorspark_tpu.cli serve --selftest`` (see cli.py).
 """
 
-from .state_cache import (CacheFullError, PagedLatentCache, PrefixCache,
+from .state_cache import (CacheFullError, PagedCache, PrefixCache,
                           SessionTiers, StateCache)
 from .prefix_trie import PrefixPropagator, PrefixTrie
 from .autotune import AutoTuneConfig, AutoTuner
@@ -137,7 +137,7 @@ __all__ = [
     "InprocessClient",
     "ModelRegistry",
     "PAD_TOKEN",
-    "PagedLatentCache",
+    "PagedCache",
     "PrefixCache",
     "PrefixPropagator",
     "PrefixTrie",

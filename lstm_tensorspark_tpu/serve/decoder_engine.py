@@ -1,5 +1,7 @@
 """The serve engine of the decoder family: the programs `Batcher` dispatches,
-over a paged latent cache.
+over a paged cache (latent rows, or keys and values per head: what differs
+between the two decoders sits behind `models.decoder`'s block and the
+cache's page kinds).
 
 It answers the calls `ServeEngine` answers (`prefill`, `prefill_chunk`,
 `decode`, `decode_window`, `decode_window_next`, `fetch_window_summary`,
@@ -7,9 +9,12 @@ It answers the calls `ServeEngine` answers (`prefill`, `prefill_chunk`,
 batcher drive either family; `serve.engine.build_engine` picks by the
 configuration's family. What differs from the LSTM engine:
 
-- **State**: `state_cache.PagedLatentCache`. A slot is a session's row in
-  the page bookkeeping, not a carry. Every program takes the pools DONATED
-  and hands them back: they are updated in place, never copied.
+- **State**: `state_cache.PagedCache`. A slot is a session's row in the
+  page bookkeeping, not a carry. Every program takes the pools DONATED and
+  hands them back: they are updated in place, never copied. A cache with
+  two kinds of page (full and window layers) gives every dispatch two page
+  tables and two item lists; a window layer's names only the pages that
+  hold a key inside some query's window.
 - **Prefill** packs the rows of one dispatch onto ONE flat token axis
   (each row's new tokens at a multiple of the q-tile), padded to a token
   bucket; a row continues at the length its slot holds. Pages are taken as
@@ -30,7 +35,10 @@ configuration's family. What differs from the LSTM engine:
   under `Request.token_logits`: what the benchmark's judge compares.
 - Counters (``moe_pairs_total``, ``moe_pairs_here``, ``experts_touched``)
   are summed on the device in an accumulator every program threads through,
-  and reach the host with the tokens of the next fetch.
+  and reach the host with the tokens of the next fetch. The host counts, per
+  kind of page, the (query, key) pairs inside the mask that it dispatched:
+  ``decode_<kind>_keys_read`` and ``prefill_<kind>_pairs`` (one layer's; a
+  reader multiplies by the layers of the kind).
 """
 
 from __future__ import annotations
@@ -46,11 +54,11 @@ import numpy as np
 
 from .. import obs
 from ..models import decoder
-from ..ops import mla_attention
+from ..ops import paged_attention
 from ..utils.tracing import span
 from .engine import (GREEDY, PAD_TOKEN, DecodeWindow, SamplingParams,
                      UnknownModelError, _bucket_for)
-from .state_cache import PagedLatentCache
+from .state_cache import PagedCache
 
 COUNTERS = ("moe_pairs_total", "moe_pairs_here", "experts_touched")
 
@@ -68,8 +76,9 @@ class DecoderWindow(DecodeWindow):
     pos: jax.Array | None = None     # [batch_b] each row's position after it
     logits: jax.Array | None = None  # [batch_b, window, 2]: chosen, largest
     acc: jax.Array | None = None     # the counters' accumulator after it
-    live: int = 0                    # rows alive at its dispatch
-    contexts: int = 0                # their contexts then, summed
+    #: the host's estimate of each row's position at its dispatch (-1: a
+    #: row dead then); rows that end inside a window are still counted
+    host_pos: np.ndarray | None = None
     # set once the window's tokens were fetched and the rows' lengths
     # advanced (`fetch_window_summary`)
     fetched: threading.Event = dataclasses.field(
@@ -80,14 +89,15 @@ class DecoderEngine:
     family = "decoder"
 
     def __init__(self, params, cfg: decoder.DecoderConfig, *,
-                 num_slots: int = 64, num_pages: int = 64, page: int = 256,
+                 num_slots: int = 64, num_pages: int | tuple = 64,
+                 page: int = 256,
                  max_context: int = 4096,
                  prefill_buckets: tuple[int, ...] = (128, 512),
                  batch_buckets: tuple[int, ...] = (8, 32),
                  max_prefill_rows: int = 4,
                  registry=None, device=None, model_id: str = "default",
                  model_version: int = 0, interpret: bool = False):
-        tq = mla_attention.PREFILL_TQ
+        tq = paged_attention.PREFILL_TQ
         if any(b % tq for b in prefill_buckets):
             raise ValueError(f"prefill buckets must be multiples of {tq}")
         self.cfg = cfg
@@ -107,14 +117,17 @@ class DecoderEngine:
         self.max_context = int(max_context)
         self.pages_per_row = -(-self.max_context // page)
         self.metrics = obs.REGISTRY if registry is None else registry
-        self.cache = PagedLatentCache(
-            cfg.num_hidden_layers, num_slots, num_pages, page,
-            cfg.latent_width, self.params["embedding"].dtype, device=device)
+        #: ``num_pages``: one count per kind of page (`decoder.cache_kinds`)
+        self.cache = PagedCache(
+            num_slots, page, decoder.cache_kinds(cfg, num_pages),
+            self.params["embedding"].dtype, device=device,
+            grow_step=self.prefill_buckets[-1])
+        self.kinds = self.cache.kinds
         self.prefix = None
         self.tiers = None
         self.has_draft = False
         self.mesh_shards = 1
-        self.decode_kernel = "mla"
+        self.decode_kernel = "gqa" if cfg.grouped else "mla"
         self._interpret = interpret
         self._lock = threading.RLock()
         self._counts_lock = threading.Lock()
@@ -129,6 +142,9 @@ class DecoderEngine:
         self.prefill_tokens = 0
         self.prefill_attended = 0      # (query, key) pairs of prefilled tokens
         self.prefill_context_tokens = 0  # sum over prefill rows of their ends
+        # (query, key) pairs inside the mask, per kind of page (one layer's)
+        self.decode_keys_read = [0] * len(self.kinds)
+        self.prefill_pairs = [0] * len(self.kinds)
         self._warming = False
 
     # ---- limits and residency (the batcher's and router's questions) ----
@@ -205,8 +221,27 @@ class DecoderEngine:
         with self._counts_lock:
             self.compile_counts[key] += 1
 
-    def _items_capacity(self, tiles: int) -> int:
-        return tiles * self.pages_per_row
+    def _items_capacity(self, tiles: int, k: int) -> int:
+        """Items a program of ``tiles`` q-tiles may list for kind ``k``:
+        every page of a row, or the most a session holds of a window kind."""
+        if self.kinds[k].window is None:
+            return tiles * self.pages_per_row
+        return tiles * min(self.pages_per_row, self.cache.window_cap(k))
+
+    def _scratch_filled(self, *shape) -> np.ndarray:
+        """``[kinds, *shape]`` int32, each kind's plane its scratch page."""
+        out = np.empty((len(self.kinds), *shape), np.int32)
+        out[:] = np.asarray(self.cache.scratch_pages).reshape(
+            -1, *(1,) * len(shape))
+        return out
+
+    def _keys_seen(self, k: int, positions) -> int:
+        """(query, key) pairs inside kind ``k``'s mask of queries at
+        ``positions``: each sees itself and what precedes it, a window kind
+        at most ``window`` keys."""
+        seen = np.asarray(positions, np.int64) + 1
+        w = self.kinds[k].window
+        return int((seen if w is None else np.minimum(seen, w)).sum())
 
     def _prefill_fn(self, tokens_b: int, final: bool):
         key = ("decoder_prefill" if final else "decoder_prefill_chunk",
@@ -221,7 +256,7 @@ class DecoderEngine:
             self._count(key)
             hidden, pools, counts = decoder.forward_tokens(
                 params, absorbed, cfg, pools, tokens, pos, live, write_page,
-                write_off, items, tq=mla_attention.PREFILL_TQ,
+                write_off, items, tq=paged_attention.PREFILL_TQ,
                 interpret=interpret)
             acc = acc.at[0].add(jnp.stack([counts[k] for k in COUNTERS]))
             if not final:
@@ -241,7 +276,7 @@ class DecoderEngine:
         if fn is not None:
             return fn
         cfg, interpret, page = self.cfg, self._interpret, self.cache.page
-        scratch = self.cache.scratch_page
+        scratch = jnp.asarray(self.cache.scratch_pages, jnp.int32)[:, None]
 
         def decoder_window_fn(params, absorbed, pools, acc, tokens, pos,
                               alive, remaining, eos_ids, page_table, items):
@@ -251,13 +286,13 @@ class DecoderEngine:
             def step(carry, _):
                 pools, acc, token, pos, alive, remaining = carry
                 write_page = jnp.where(
-                    alive, page_table[rows, pos // page], scratch)
-                step_items = dict(items, qpos=pos,
-                                  klen=jnp.where(alive, pos + 1, 0))
+                    alive, page_table[:, rows, pos // page], scratch)
+                klen = jnp.where(alive, pos + 1, 0)
+                step_items = [dict(it, qpos=pos, klen=klen) for it in items]
                 hidden, pools, counts = decoder.forward_tokens(
                     params, absorbed, cfg, pools, token, pos, alive,
                     write_page, jnp.where(alive, pos % page, 0), step_items,
-                    tq=mla_attention.DECODE_TQ, interpret=interpret)
+                    tq=paged_attention.DECODE_TQ, interpret=interpret)
                 nxt, chosen, top = decoder.pick_greedy(
                     decoder.head_logits(params, hidden))
                 # a row alive at the step's start consumed its token (its
@@ -294,8 +329,9 @@ class DecoderEngine:
 
     def _pack_prefill(self, items):
         """Lay the rows' new tokens on the flat token axis and plan the
-        attention items. Takes pages as rows grow."""
-        cache, tq = self.cache, mla_attention.PREFILL_TQ
+        attention items, per kind of page. Takes pages as rows grow (and
+        returns the window pages they have outgrown)."""
+        cache, tq = self.cache, paged_attention.PREFILL_TQ
         if len(items) > self.max_prefill_rows:
             raise ValueError(f"{len(items)} rows in a prefill dispatch of "
                              f"at most {self.max_prefill_rows}")
@@ -308,34 +344,40 @@ class DecoderEngine:
                 raise ValueError("empty prompt")
             n = int(prompt.size)
             if slot == cache.scratch_slot:      # warm-up rows
-                start, pages = 0, [cache.scratch_page] * cache.pages_for(n)
+                start = 0
+                held = [(0, [scratch] * cache.pages_for(n))
+                        for scratch in cache.scratch_pages]
             else:
                 start = int(cache.length[slot])
-                pages = cache.ensure(slot, start + n)
-            rows.append((prompt, slot, start, pages, at))
+                held = cache.ensure(slot, start + n)
+            rows.append((prompt, slot, start, held, at))
             at += -(-n // tq) * tq
         tokens_b = _bucket_for(at, self.token_buckets, "prefill tokens")
         tokens = np.zeros((tokens_b,), np.int32)
         pos = np.zeros((tokens_b,), np.int32)
         live = np.zeros((tokens_b,), bool)
-        write_page = np.full((tokens_b,), cache.scratch_page, np.int32)
+        write_page = self._scratch_filled(tokens_b)
         write_off = np.zeros((tokens_b,), np.int32)
         last_idx = np.zeros((self.max_prefill_rows,), np.int32)
-        for r, (prompt, slot, start, pages, at) in enumerate(rows):
+        for r, (prompt, slot, start, held, at) in enumerate(rows):
             n = prompt.size
             p = start + np.arange(n)
             tokens[at:at + n], pos[at:at + n], live[at:at + n] = prompt, p, True
-            write_page[at:at + n] = np.asarray(pages, np.int32)[p // cache.page]
+            for k, (base, pages) in enumerate(held):
+                write_page[k, at:at + n] = np.asarray(
+                    pages, np.int32)[p // cache.page - base]
             write_off[at:at + n] = p % cache.page
             last_idx[r] = at + n - 1
-        plan = mla_attention.plan_items(
-            [r[3] for r in rows], [r[2] for r in rows],
-            [r[0].size for r in rows], page=cache.page, tq=tq,
-            tiles=tokens_b // tq,
-            capacity=self._items_capacity(tokens_b // tq),
-            scratch_page=cache.scratch_page)
+        tiles = tokens_b // tq
+        plans = [paged_attention.plan_items(
+            [r[3][k][1] for r in rows], [r[2] for r in rows],
+            [r[0].size for r in rows], page=cache.page, tq=tq, tiles=tiles,
+            capacity=self._items_capacity(tiles, k),
+            scratch_page=cache.scratch_pages[k], window=kind.window,
+            bases=[r[3][k][0] for r in rows])
+            for k, kind in enumerate(self.kinds)]
         return (tokens_b, rows, (tokens, pos, live, write_page, write_off,
-                                 plan, last_idx))
+                                 plans, last_idx))
 
     def _run_prefill(self, items, final: bool):
         with span("engine:pack"):
@@ -350,7 +392,7 @@ class DecoderEngine:
                          program=("decoder_prefill_fn" if final
                                   else "decoder_chunk_fn"),
                          batch_bucket=tokens_b, len_bucket=tokens_b,
-                         context_bucket=int(host[5]["n"][0])):
+                         context_bucket=sum(int(p["n"][0]) for p in host[5])):
                 out = fn(self.params, self.absorbed, self.cache.pools,
                          self._acc, *args)
             self.cache.swap(out[0])
@@ -364,6 +406,9 @@ class DecoderEngine:
                 self.prefill_tokens += n
                 self.prefill_attended += n * start + n * (n + 1) // 2
                 self.prefill_context_tokens += start + n
+                for k in range(len(self.kinds)):
+                    self.prefill_pairs[k] += self._keys_seen(
+                        k, start + np.arange(n))
         return out[1:]
 
     def prefill(self, items, sampling: SamplingParams = GREEDY, *,
@@ -398,43 +443,53 @@ class DecoderEngine:
     # ---- decode -----------------------------------------------------------
 
     def _plan_window(self, slots, ahead: int, batch_b: int):
-        """Pages and attention items for rows that may grow by ``ahead``
-        tokens from the lengths the host knows."""
+        """Page tables and attention items, per kind of page, for rows that
+        may grow by ``ahead`` tokens from the lengths the host knows."""
         cache = self.cache
-        table = np.full((batch_b, self.pages_per_row), cache.scratch_page,
-                        np.int32)
-        page_rows, starts = [], []
+        tables = self._scratch_filled(batch_b, self.pages_per_row)
+        held_rows = []
         for i, slot in enumerate(slots):
             if slot == cache.scratch_slot:
-                pages, length = [cache.scratch_page], 0
+                held = [(0, [scratch]) for scratch in cache.scratch_pages]
             else:
                 length = int(cache.length[slot])
-                pages = cache.ensure(
+                held = cache.ensure(
                     slot, min(length + ahead, int(cache.limit[slot])))
-            table[i, :len(pages)] = pages
-            page_rows.append(pages)
-            starts.append(max(len(pages) * cache.page - 1, 0))
-        # each row is one q-tile that may see every page it owns
-        plan = mla_attention.plan_items(
-            page_rows, starts, [1] * len(slots), page=cache.page, tq=1,
-            tiles=batch_b, capacity=self._items_capacity(batch_b),
-            scratch_page=cache.scratch_page)
-        return table, plan
+            for k, (base, pages) in enumerate(held):
+                tables[k, i, base:base + len(pages)] = pages
+            held_rows.append(held)
+        # each row is one q-tile that may see every page it holds (a window
+        # layer's rows hold only the pages their windows reach)
+        plans = [paged_attention.plan_items(
+            [h[k][1] for h in held_rows],
+            [max((h[k][0] + len(h[k][1])) * cache.page - 1, 0)
+             for h in held_rows],
+            [1] * len(slots), page=cache.page, tq=1, tiles=batch_b,
+            capacity=self._items_capacity(batch_b, k),
+            scratch_page=cache.scratch_pages[k],
+            bases=[h[k][0] for h in held_rows])
+            for k in range(len(self.kinds))]
+        return tables, plans
 
     def _launch_window(self, fn, batch_b, window, tokens, pos, alive,
-                       remaining, eos, table, plan, *, live_rows, contexts):
+                       remaining, eos, table, plans, *, host_pos):
         with self._lock, span("engine:launch", program="decoder_window_fn",
                               batch_bucket=batch_b,
-                              context_bucket=int(plan["n"][0])):
+                              context_bucket=sum(int(p["n"][0])
+                                                 for p in plans)):
             out = fn(self.params, self.absorbed, self.cache.pools, self._acc,
                      tokens, pos, alive, remaining, eos,
-                     *jax.device_put((table, plan)))
+                     *jax.device_put((table, plans)))
             self.cache.swap(out[0])
             self._acc = out[1]
         if not self._warming:
+            at = host_pos[host_pos >= 0]
             self.decode_steps += window
-            self.decode_row_steps += live_rows * window
-            self.decode_context_tokens += contexts * window
+            self.decode_row_steps += at.size * window
+            self.decode_context_tokens += int(at.sum()) * window
+            steps = at[:, None] + np.arange(window)[None, :]
+            for k in range(len(self.kinds)):
+                self.decode_keys_read[k] += self._keys_seen(k, steps)
         return out[1:]
 
     def decode_window(self, slots, tokens, remaining, eos_ids=None,
@@ -460,18 +515,18 @@ class DecoderEngine:
                 eos_p[:n] = np.asarray(eos_ids, np.int32)
             alive_p = rem_p > 0
             pos_p = cache.length[slots_p].astype(np.int32)
-            table, plan = self._plan_window(slots_p, window, batch_b)
+            table, plans = self._plan_window(slots_p, window, batch_b)
             dev = jax.device_put((tokens_p, pos_p, alive_p, rem_p, eos_p))
+        host_pos = np.where(alive_p, pos_p, -1)
         acc, toks, logits, next_tok, pos, alive, rem = self._launch_window(
             self._window_fn(batch_b, window), batch_b, window, *dev,
-            table, plan, live_rows=int(alive_p.sum()),
-            contexts=int(pos_p[alive_p].sum()))
+            table, plans, host_pos=host_pos)
         return DecoderWindow(
             tokens=toks, next_tokens=next_tok, alive=alive, remaining=rem,
             slots=slots_p, eos_ids=dev[4], batch_b=batch_b, window=window,
             n=n, sampling=sampling, t_dispatch=time.perf_counter(),
             model=self.model_id, pos=pos, logits=logits, acc=acc,
-            live=int(alive_p.sum()), contexts=int(pos_p[alive_p].sum()))
+            host_pos=host_pos)
 
     def decode_window_next(self, prev: DecoderWindow, *,
                            window: int | None = None) -> DecoderWindow:
@@ -482,18 +537,17 @@ class DecoderEngine:
         # every window before ``prev`` has been fetched (the batcher keeps
         # one in flight); ``prev`` itself may not have been
         ahead = (0 if prev.fetched.is_set() else prev.window) + window
-        contexts = prev.contexts + prev.live * prev.window
+        host_pos = np.where(prev.host_pos >= 0, prev.host_pos + prev.window, -1)
         with span("engine:pack"):
-            table, plan = self._plan_window(prev.slots, ahead, prev.batch_b)
+            table, plans = self._plan_window(prev.slots, ahead, prev.batch_b)
         acc, toks, logits, next_tok, pos, alive, rem = self._launch_window(
             self._window_fn(prev.batch_b, window), prev.batch_b, window,
             prev.next_tokens, prev.pos, prev.alive, prev.remaining,
-            prev.eos_ids, table, plan, live_rows=prev.live,
-            contexts=contexts)
+            prev.eos_ids, table, plans, host_pos=host_pos)
         return dataclasses.replace(
             prev, tokens=toks, next_tokens=next_tok, alive=alive,
             remaining=rem, window=window, t_dispatch=time.perf_counter(),
-            pos=pos, logits=logits, acc=acc, contexts=contexts,
+            pos=pos, logits=logits, acc=acc, host_pos=host_pos,
             fetched=threading.Event())
 
     def fetch_window_summary(self, win: DecoderWindow):
@@ -624,5 +678,9 @@ class DecoderEngine:
                         "prefill_tokens": self.prefill_tokens,
                         "prefill_attended": self.prefill_attended,
                         "prefill_context_tokens":
-                            self.prefill_context_tokens},
+                            self.prefill_context_tokens,
+                        **{f"decode_{k.name}_keys_read": v for k, v in
+                           zip(self.kinds, self.decode_keys_read)},
+                        **{f"prefill_{k.name}_pairs": v for k, v in
+                           zip(self.kinds, self.prefill_pairs)}},
         }

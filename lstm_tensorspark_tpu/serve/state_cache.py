@@ -35,10 +35,11 @@ receives the handle and is therefore data-ordered after it on device.
 Host-side bookkeeping is lock-protected; device reads/writes are plain
 jnp gather/scatter ops (one compile each per batch-shape, amortised).
 
-:class:`PagedLatentCache` (same file) is the second KIND of state: a cache
-that grows with the session (pages of latent rows for a decoder with latent
-attention), behind the same session table; nothing of it is evicted, and
-admission is by free pages (see its docstring).
+:class:`PagedCache` (same file) is the second KIND of state: a cache that
+grows with the session (pages of latent rows, or of keys and values, for a
+decoder), behind the same session table; nothing of it is evicted, window
+layers' pages are returned as a session outgrows them, and admission is by
+free pages (see its docstring).
 
 :class:`PrefixCache` (same file) layers shared-prompt reuse on top: a
 store of "state after token-prefix P" entries, each backed by a
@@ -118,7 +119,7 @@ class DetachedState(NamedTuple):
 class SessionTable:
     """Which session holds which slot, and which slots are pinned by active
     work: the host-side table BOTH kinds of session state keep
-    (`StateCache`: a slot is a row of carries; `PagedLatentCache`: a slot is
+    (`StateCache`: a slot is a row of carries; `PagedCache`: a slot is
     a row of the page bookkeeping). One reentrant lock guards it and
     whatever a subclass keeps per slot; the subclass creates it and hands
     it in (graftlint's lock model is per class: a lock made here would be
@@ -1911,14 +1912,27 @@ class SessionTiers:
             }
 
 
-class PagedLatentCache(SessionTable):
+class PagedCache(SessionTable):
     """The second kind of session state: a cache that GROWS with the
-    session. A decoder's state is one latent row per token and layer
-    (`ops/mla_attention.py`), kept in pages of ``page`` rows; the device
-    holds one pool ``[num_pages + 1, page, width]`` per layer (page
-    ``num_pages`` is scratch: dead rows write there) and the host holds the
-    bookkeeping — which session has which slot, which pages a slot owns in
-    order, how many tokens it holds.
+    session. A decoder's state is one row per token and layer
+    (`ops/paged_attention.py`: a latent row, or keys and values per head),
+    kept in pages of ``page`` rows; the device holds one pool ``[num_pages +
+    1, page, width]`` per layer (the last page is scratch: dead rows write
+    there) and the host holds the bookkeeping: which session has which
+    slot, which pages a slot owns in order, how many tokens it holds.
+
+    Pages come in KINDS (`models.decoder.PageKind`; one for a latent cache,
+    two for a K/V cache whose layers mix full and window attention): one
+    page id of a kind indexes the same page of every pool of that kind's
+    layers, each kind has its own free list, and a session owns pages of
+    every kind side by side. A kind with a ``window`` keeps only the pages
+    that hold a key the session's next query can see: as the session grows,
+    pages wholly behind ``length - (window - 1)`` go back to the free list
+    WHILE IT RUNS (`ensure`, on the scheduler's thread) and when it goes
+    idle (`unpin`), so a session of any length holds a bounded number of
+    them; its page list then starts at page index `held`'s ``base``. A
+    returned page is never read again: the item lists name only pages a
+    session holds.
 
     The session table IS `StateCache`'s (`SessionTable`: ``acquire_pinned``,
     ``unpin``, ``release``, ``in``, ``session_ids``, ``stats``), so the
@@ -1929,34 +1943,53 @@ class PagedLatentCache(SessionTable):
       snapshot). A kept session holds its pages until it is released;
       when slots or pages run out, `acquire`/`commit` raise
       `CacheFullError` and admission waits or fails loudly;
-    - admission is by PAGES: `commit` promises a session the pages its
-      request can grow into (prompt + new tokens) before any are taken,
-      `ensure` takes them as the session grows, `unpin`/`release` return
-      what was promised and not used; ``pages_free - pages_promised`` is
-      what a new request may count on (`can_commit`);
+    - admission is by PAGES, of every kind: `commit` promises a session the
+      pages its request can grow into (prompt + new tokens; of a window
+      kind at most `window_cap` at a time) before any are taken, `ensure`
+      takes them as the session grows, `unpin`/`release` return what was
+      promised and not used; ``free - promised`` of a kind is what a new
+      request may count on (`can_commit`);
     - the pools are updated IN PLACE: every program that writes them takes
       them donated and hands them back (`swap`); a copy of a multi-GiB
       pool per dispatch would not fit beside the weights.
 
-    One page id indexes the same page of every layer's pool. Spans
-    ``cache:pages_alloc`` / ``cache:pages_free`` (on the caller's thread:
-    the scheduler's) carry the number of pages moved."""
+    Spans ``cache:pages_alloc`` / ``cache:pages_free`` [``pages``, ``kind``]
+    and ``cache:window_pages_recycle`` [``pages``] (on the caller's thread:
+    the scheduler's) carry the number of pages moved. ``grow_step`` is the
+    most tokens one `ensure` may take a session beyond the length the host
+    knows (a prefill chunk; a decode window run ahead is shorter)."""
 
-    def __init__(self, num_layers: int, num_slots: int, num_pages: int,
-                 page: int, width: int, dtype=jnp.bfloat16, device=None):
+    def __init__(self, num_slots: int, page: int, kinds, dtype=jnp.bfloat16,
+                 device=None, grow_step: int = 512):
         self._lock = threading.RLock()
         super().__init__(num_slots, self._lock)
-        if num_pages < 1 or page < 1:
-            raise ValueError("num_pages and page must be >= 1")
-        self.num_layers = num_layers
-        self.num_pages, self.page, self.width = num_pages, page, width
-        make = jax.jit(lambda: jnp.zeros((num_pages + 1, page, width), dtype))
-        self.pools = tuple(make() for _ in range(num_layers))
+        self.kinds = tuple(kinds)
+        if page < 1 or any(k.num_pages < 1 for k in self.kinds):
+            raise ValueError("page and every kind's num_pages must be >= 1")
+        if self.kinds[0].window is not None:
+            raise ValueError("the first kind of page keeps every token")
+        self.page, self.grow_step = page, int(grow_step)
+        self._has_window = any(k.window is not None for k in self.kinds)
+        kind_of = {i: k for k in self.kinds for i in k.layers}
+        self.num_layers = len(kind_of)
+
+        def pool(k):
+            return jax.jit(lambda: jnp.zeros((k.num_pages + 1, page, k.width),
+                                             dtype))()
+
+        self.pools = tuple(pool(kind_of[i]) for i in range(self.num_layers))
         if device is not None:
             self.pools = jax.device_put(self.pools, device)
-        self._free_pages: list[int] = list(range(num_pages - 1, -1, -1))
-        self._pages: list[list[int]] = [[] for _ in range(num_slots + 1)]
-        self._promised = np.zeros((num_slots + 1,), np.int64)  # pages
+        n = len(self.kinds)
+        self._free_pages = [list(range(k.num_pages - 1, -1, -1))
+                            for k in self.kinds]
+        self._pages = [[[] for _ in range(num_slots + 1)] for _ in range(n)]
+        #: page index (of the session) of a slot's first page, per kind
+        self._base = np.zeros((n, num_slots + 1), np.int64)
+        #: pages a slot may hold while its admitted request runs, and what
+        #: of that it does not hold yet: the promise
+        self._target = np.zeros((n, num_slots + 1), np.int64)
+        self._promised = np.zeros((n, num_slots + 1), np.int64)
         #: tokens each slot holds (the scratch slot stays 0), and the most
         #: its admitted request may bring it to (`commit`)
         self.length = np.zeros((num_slots + 1,), np.int64)
@@ -1964,13 +1997,24 @@ class PagedLatentCache(SessionTable):
         self.generation = 0
         self.pages_allocated = 0   # running totals, for the counters
         self.pages_freed = 0
+        self.window_pages_recycled = 0
 
     @property
-    def scratch_page(self) -> int:
-        return self.num_pages
+    def scratch_pages(self) -> tuple[int, ...]:
+        return tuple(k.num_pages for k in self.kinds)
 
     def pages_for(self, tokens: int) -> int:
         return -(-int(tokens) // self.page)
+
+    def window_cap(self, k: int) -> int:
+        """The most pages of kind ``k`` one session holds at a time."""
+        w = self.kinds[k].window
+        return self.pages_for(w - 1 + self.grow_step) + 1
+
+    def _first_index(self, k: int, length: int) -> int:
+        """Page index of the first key a query at ``length`` still sees."""
+        w = self.kinds[k].window
+        return 0 if w is None else max(int(length) - (w - 1), 0) // self.page
 
     # ---- session table: `SessionTable`'s, plus the pages a slot owns -----
 
@@ -1987,13 +2031,16 @@ class PagedLatentCache(SessionTable):
             return slot, fresh
 
     def unpin(self, session_id: str) -> None:
-        """The session goes idle and keeps its pages; what it was promised
-        and did not grow into is returned."""
+        """The session goes idle and keeps its pages (of a window kind:
+        those its length implies); what it was promised and did not grow
+        into is returned."""
         with self._lock:
             super().unpin(session_id)
             slot = self._slots.get(session_id)
             if slot is not None:
-                self._promised[slot] = 0
+                self._target[:, slot] = 0
+                self._promised[:, slot] = 0
+                self._recycle_locked(slot)
 
     def release(self, session_id: str) -> int | None:
         """Drop the session: its pages and its slot are free again."""
@@ -2001,73 +2048,144 @@ class PagedLatentCache(SessionTable):
             slot = super().release(session_id)
             if slot is None:
                 return None
-            pages, self._pages[slot] = self._pages[slot], []
-            self._promised[slot] = 0
+            self._target[:, slot] = 0
+            self._promised[:, slot] = 0
             self.length[slot] = 0
-            if pages:
-                with _span("cache:pages_free", pages=len(pages)):
-                    self._free_pages.extend(reversed(pages))
-                    self.pages_freed += len(pages)
+            for k, kind in enumerate(self.kinds):
+                pages, self._pages[k][slot] = self._pages[k][slot], []
+                self._base[k, slot] = 0
+                if pages:
+                    with _span("cache:pages_free", pages=len(pages),
+                               kind=kind.name):
+                        self._free_pages[k].extend(reversed(pages))
+                        self.pages_freed += len(pages)
             return slot
 
     # ---- pages ----------------------------------------------------------
 
-    def _uncommitted_locked(self) -> int:
-        return len(self._free_pages) - int(self._promised.sum())
+    def _uncommitted_locked(self, k: int) -> int:
+        return len(self._free_pages[k]) - int(self._promised[k].sum())
+
+    def _target_locked(self, k: int, slot, tokens: int) -> int:
+        """Pages of kind ``k`` a session may hold while it grows by
+        ``tokens`` (``slot`` None: a session not opened yet)."""
+        length = 0 if slot is None else int(self.length[slot])
+        total = self.pages_for(length + int(tokens))
+        if self.kinds[k].window is None:
+            return total
+        return min(total - self._first_index(k, length), self.window_cap(k))
+
+    def _need_locked(self, k: int, slot, tokens: int) -> int:
+        target = self._target_locked(k, slot, tokens)
+        if slot is None:
+            return target
+        return max(target - len(self._pages[k][slot])
+                   - int(self._promised[k, slot]), 0)
 
     def can_commit(self, slot_tokens) -> bool:
         """Could sessions growing to these ``(slot or None, tokens)`` totals
-        all be promised their pages now? (``tokens`` is what the request
-        adds; a slot's present length and pages count for it.)"""
+        all be promised their pages now, of every kind? (``tokens`` is what
+        the request adds; a slot's present length and pages count for it.)"""
         with self._lock:
-            need = sum(self._need_locked(slot, tokens)
-                       for slot, tokens in slot_tokens)
-            return need <= self._uncommitted_locked()
-
-    def _need_locked(self, slot, tokens: int) -> int:
-        if slot is None:
-            return self.pages_for(tokens)
-        total = self.pages_for(int(self.length[slot]) + int(tokens))
-        return max(total - len(self._pages[slot])
-                   - int(self._promised[slot]), 0)
+            asked = list(slot_tokens)
+            return all(
+                sum(self._need_locked(k, slot, tokens)
+                    for slot, tokens in asked) <= self._uncommitted_locked(k)
+                for k in range(len(self.kinds)))
 
     def commit(self, slot: int, tokens: int) -> None:
-        """Promise ``slot`` the pages to grow by ``tokens`` more tokens."""
+        """Promise ``slot`` the pages, of every kind, to grow by ``tokens``
+        more tokens."""
         with self._lock:
-            need = self._need_locked(slot, tokens)
-            if need > self._uncommitted_locked():
-                raise CacheFullError(
-                    f"{need} pages needed, {self._uncommitted_locked()} of "
-                    f"{self.num_pages} neither held nor promised")
-            self._promised[slot] += need
+            needs = [self._need_locked(k, slot, tokens)
+                     for k in range(len(self.kinds))]
+            for k, need in enumerate(needs):
+                if need > self._uncommitted_locked(k):
+                    raise CacheFullError(
+                        f"{need} {self.kinds[k].name} pages needed, "
+                        f"{self._uncommitted_locked(k)} of "
+                        f"{self.kinds[k].num_pages} neither held nor promised")
+            for k, need in enumerate(needs):
+                self._promised[k, slot] += need
+                self._target[k, slot] = (len(self._pages[k][slot])
+                                         + int(self._promised[k, slot]))
             self.limit[slot] = int(self.length[slot]) + int(tokens)
 
-    def ensure(self, slot: int, tokens: int) -> list[int]:
+    def _recycle_locked(self, slot: int) -> None:
+        """Return the window pages wholly behind what ``slot``'s next query
+        sees; the promise follows (a running session may take as many
+        again)."""
+        for k, kind in enumerate(self.kinds):
+            pages = self._pages[k][slot]
+            drop = min(self._first_index(k, self.length[slot])
+                       - int(self._base[k, slot]), len(pages))
+            if kind.window is None or drop <= 0:
+                continue
+            with _span("cache:window_pages_recycle", pages=drop):
+                self._free_pages[k].extend(reversed(pages[:drop]))
+                del pages[:drop]
+                self._base[k, slot] += drop
+                self.window_pages_recycled += drop
+                self.pages_freed += drop
+            self._promised[k, slot] = max(
+                int(self._target[k, slot]) - len(pages), 0)
+
+    def ensure(self, slot: int, tokens: int) -> list[tuple[int, list[int]]]:
         """Give ``slot`` pages for ``tokens`` tokens in all (first out of its
-        promise, then out of the free pages) and return its page list."""
+        promise, then out of the free pages), of every kind, after
+        returning the window pages it has outgrown; per kind, ``(base,
+        pages)``: the session's page index of the first page, and the page
+        list."""
         with self._lock:
-            pages = self._pages[slot]
-            need = self.pages_for(tokens) - len(pages)
-            if need > 0:
-                spare = self._uncommitted_locked() + int(self._promised[slot])
+            if self._has_window and \
+                    tokens - int(self.length[slot]) > self.grow_step:
+                raise ValueError(
+                    f"slot {slot} asked to grow by "
+                    f"{tokens - int(self.length[slot])} tokens at once; "
+                    f"window pages are promised for {self.grow_step}")
+            self._recycle_locked(slot)
+            for k, kind in enumerate(self.kinds):
+                pages = self._pages[k][slot]
+                if not pages:       # a window kind's list may start late
+                    self._base[k, slot] = self._first_index(
+                        k, self.length[slot])
+                need = (self.pages_for(tokens) - int(self._base[k, slot])
+                        - len(pages))
+                if need <= 0:
+                    continue
+                spare = (self._uncommitted_locked(k)
+                         + int(self._promised[k, slot]))
                 if need > spare:
                     raise CacheFullError(
-                        f"slot {slot} needs {need} more pages, {spare} free")
-                with _span("cache:pages_alloc", pages=need):
-                    pages.extend(self._free_pages.pop() for _ in range(need))
-                    self._promised[slot] = max(
-                        int(self._promised[slot]) - need, 0)
+                        f"slot {slot} needs {need} more {kind.name} pages, "
+                        f"{spare} free")
+                with _span("cache:pages_alloc", pages=need, kind=kind.name):
+                    pages.extend(self._free_pages[k].pop()
+                                 for _ in range(need))
+                    self._promised[k, slot] = max(
+                        int(self._promised[k, slot]) - need, 0)
                     self.pages_allocated += need
-            return pages
+            return [self.held(slot, k) for k in range(len(self.kinds))]
+
+    def held(self, slot: int, k: int = 0) -> tuple[int, list[int]]:
+        """``(base, pages)`` of kind ``k``: ``pages[i]`` holds the session's
+        page index ``base + i``."""
+        with self._lock:
+            return int(self._base[k, slot]), self._pages[k][slot]
 
     def pages_of(self, slot: int) -> list[int]:
+        """The pages of the first kind (which keeps every token)."""
+        return self.held(slot)[1]
+
+    def free_page_ids(self, k: int) -> list[int]:
         with self._lock:
-            return self._pages[slot]
+            return list(self._free_pages[k])
 
     @property
     def pages_in_use(self) -> int:
         with self._lock:
-            return self.num_pages - len(self._free_pages)
+            return sum(kind.num_pages - len(free) for kind, free
+                       in zip(self.kinds, self._free_pages))
 
     # ---- device state ---------------------------------------------------
 
@@ -2080,14 +2198,19 @@ class PagedLatentCache(SessionTable):
 
     def stats(self) -> dict:
         with self._lock:
-            return {
-                **super().stats(),
-                "generation": self.generation,
-                "latent_pages_total": self.num_pages,
-                "latent_pages_in_use": self.num_pages - len(self._free_pages),
-                "latent_pages_promised": int(self._promised.sum()),
-                "latent_tokens": int(self.length.sum()),
+            out = {**super().stats(), "generation": self.generation}
+            for k, kind in enumerate(self.kinds):
+                out.update({
+                    f"{kind.name}_pages_total": kind.num_pages,
+                    f"{kind.name}_pages_in_use":
+                        kind.num_pages - len(self._free_pages[k]),
+                    f"{kind.name}_pages_promised":
+                        int(self._promised[k].sum())})
+            if self._has_window:
+                out["window_pages_recycled"] = self.window_pages_recycled
+            out.update({
+                f"{self.kinds[0].name}_tokens": int(self.length.sum()),
                 "page": self.page,
                 "pages_allocated": self.pages_allocated,
-                "pages_freed": self.pages_freed,
-            }
+                "pages_freed": self.pages_freed})
+            return out

@@ -458,19 +458,22 @@ def test_config5_dp4_train_step_compiles():
     _state_updated_in_place(compiled, state)
 
 
-# ---- the decoder family: paged latent attention, grouped experts -------
-# deepseek-v2-ep4's widths (benchmark/configs/deepseek-v2-ep4.json), bf16
+# ---- the decoder family: paged attention, grouped experts --------------
+# the benchmark's two decoder files at their published widths, bf16:
+# deepseek-v2-ep4 (latent attention) and mellum2-12b-l8 (grouped-query
+# attention, window and full layers)
+
+DSV2, MELLUM = "deepseek-v2-ep4", "mellum2-12b-l8"
 
 
-def _decoder_cfg():
+def _decoder_cfg(name=DSV2):
     import json
     import os
 
     from lstm_tensorspark_tpu.models import decoder
 
     path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "configs",
-        "deepseek-v2-ep4.json")
+        os.path.abspath(__file__))), "benchmark", "configs", name + ".json")
     with open(path) as f:
         return decoder, decoder.DecoderConfig.from_model(json.load(f))
 
@@ -485,32 +488,40 @@ def _attention_items(tiles, capacity):
             "qpos": _int(tiles), "klen": _int(tiles)}
 
 
-@pytest.mark.parametrize("name,tq,tiles", [("mla_decode", 1, 32),
-                                           ("mla_prefill", 16, 128)])
-def test_paged_latent_attention_compiles(chip, name, tq, tiles):
-    from lstm_tensorspark_tpu.ops import mla_attention
+@pytest.mark.parametrize("model,name,tq,tiles,per_tile,window", [
+    (DSV2, "mla_decode", 1, 32, 96, None),
+    (DSV2, "mla_prefill", 16, 128, 96, None),
+    (MELLUM, "gqa_decode", 1, 32, 192, None),
+    (MELLUM, "gqa_decode", 1, 32, 7, 1024),
+    (MELLUM, "gqa_prefill", 16, 128, 192, None),
+    (MELLUM, "gqa_prefill", 16, 128, 7, 1024)])
+def test_paged_attention_compiles(chip, model, name, tq, tiles, per_tile,
+                                  window):
+    from lstm_tensorspark_tpu.ops import paged_attention
 
-    _, cfg = _decoder_cfg()
+    _, cfg = _decoder_cfg(model)
     h, width = cfg.num_attention_heads, cfg.latent_width
-    q = jax.ShapeDtypeStruct((tiles, tq * h, width), jnp.bfloat16)
+    q = jax.ShapeDtypeStruct((tiles, tq * h, cfg.reading.k_width),
+                             jnp.bfloat16)
     pool = jax.ShapeDtypeStruct((2294, 256, width), jnp.bfloat16)
 
     def attend(q, pool, items):
-        return mla_attention.paged_attention(
-            q, pool, items, scale=cfg.softmax_scale, heads=h,
-            kv_rank=cfg.kv_lora_rank, name=name, interpret=False)
+        return paged_attention.paged_attention(
+            q, pool, items, scale=cfg.softmax_scale, reading=cfg.reading,
+            window=window, name=name, interpret=False)
 
     compiled = _compile(attend, *_on(chip, (q, pool, _attention_items(
-        tiles, tiles * 96))))
+        tiles, tiles * per_tile))))
     assert _kernel_calls(compiled) == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20  # no pool copy
 
 
-@pytest.mark.parametrize("tokens", [32, 2048])
-def test_routed_experts_compile(chip, tokens):
+@pytest.mark.parametrize("model,tokens", [(DSV2, 32), (DSV2, 2048),
+                                          (MELLUM, 32), (MELLUM, 2048)])
+def test_routed_experts_compile(chip, model, tokens):
     from lstm_tensorspark_tpu.ops import moe
 
-    decoder, cfg = _decoder_cfg()
+    decoder, cfg = _decoder_cfg(model)
     d, inter = cfg.hidden_size, cfg.moe_intermediate_size
     args = (jax.ShapeDtypeStruct((tokens, d), jnp.bfloat16),
             jax.ShapeDtypeStruct((tokens,), jnp.bool_),
@@ -523,53 +534,85 @@ def test_routed_experts_compile(chip, tokens):
             x, live, w_router, w_gate_up, w_down, first=cfg.experts_first,
             n_group=cfg.n_group, topk_group=cfg.topk_group,
             top_k=cfg.num_experts_per_tok, scale=cfg.routed_scaling_factor,
+            renormalise=cfg.norm_topk_prob,
             tm=decoder.moe_tile_rows(tokens), interpret=False)
 
     compiled = _compile(routed, *_on(chip, args))
     assert _kernel_calls(compiled) == 2        # gate/up and down
 
 
-@pytest.mark.slow
-@pytest.mark.parametrize("program", ["window", "prefill"])
-def test_decoder_serve_programs_fit_the_chip(chip, program):
-    """The decode window (32 rows x 4 steps) and the widest prefill (2,048
-    tokens) at the published widths beside a 3.5 GiB pool: they compile, the
-    pools are aliased in place (no copy of one), and arguments plus
-    temporaries stay inside the chip's memory."""
+def _decoder_program(chip, model, program, batch=32, tokens=2048):
+    """A decode window (``batch`` rows x 4 steps) or the final prefill of
+    ``tokens`` tokens of ``model`` at its published widths, compiled beside
+    the pools its file asks for: ``(compiled, pools)``."""
     import threading
     from collections import defaultdict
 
     from lstm_tensorspark_tpu.serve.decoder_engine import DecoderEngine
 
-    decoder, cfg = _decoder_cfg()
-    pages, page = 2293, 256
+    decoder, cfg = _decoder_cfg(model)
+    page = 256
+    counts, per_row = ((2293,), 96) if model == DSV2 else ((3584, 853), 192)
+    kinds = decoder.cache_kinds(cfg, counts)
     params = jax.eval_shape(lambda: decoder.init_decoder(0, cfg))
     absorbed = jax.eval_shape(lambda p: decoder.absorb(p, cfg), params)
-    pools = tuple(jax.ShapeDtypeStruct((pages + 1, page, cfg.latent_width),
-                                       jnp.bfloat16)
-                  for _ in range(cfg.num_hidden_layers))
+    pools = tuple(jax.ShapeDtypeStruct(
+        (kinds[k].num_pages + 1, page, cfg.latent_width), jnp.bfloat16)
+        for k in cfg.layer_kinds)
     engine = object.__new__(DecoderEngine)     # the programs, not the arrays
     engine.cfg, engine._interpret, engine._fns = cfg, False, {}
     engine._counts_lock, engine.compile_counts = threading.Lock(), defaultdict(int)
-    engine.pages_per_row = 96
-    engine.cache = type("Pages", (), {"page": page, "scratch_page": pages})()
-    acc = _int(2, 3)
+    engine.pages_per_row, engine.kinds = per_row, kinds
+    engine.cache = type("Pages", (), {
+        "page": page, "scratch_pages": counts,
+        "window_cap": lambda self, k: 7})()
+    acc, n = _int(2, 3), len(kinds)
     if program == "window":
-        b = 32
+        b = batch
         fn = engine._window_fn(b, 4)
         args = (params, absorbed, pools, acc, _int(b), _int(b),
                 jax.ShapeDtypeStruct((b,), jnp.bool_), _int(b), _int(b),
-                _int(b, 96), _attention_items(b, b * 96))
+                _int(n, b, per_row),
+                [_attention_items(b, engine._items_capacity(b, k))
+                 for k in range(n)])
     else:
-        t = 2048
+        t = tokens
         fn = engine._prefill_fn(t, True)
         args = (params, absorbed, pools, acc, _int(t), _int(t),
-                jax.ShapeDtypeStruct((t,), jnp.bool_), _int(t), _int(t),
-                _attention_items(t // 16, t // 16 * 96), _int(4))
-    compiled = fn.lower(*_on(chip, args)).compile()
+                jax.ShapeDtypeStruct((t,), jnp.bool_), _int(n, t), _int(t),
+                [_attention_items(t // 16, engine._items_capacity(t // 16, k))
+                 for k in range(n)], _int(4))
+    return fn.lower(*_on(chip, args)).compile(), pools
+
+
+def _pools_updated_in_place(compiled, pools):
     memory = compiled.memory_analysis()
-    pool_bytes = sum(p.size * 2 for p in pools)
-    assert memory.alias_size_in_bytes >= pool_bytes          # updated in place
-    assert not re.search(r"= bf16\[2294,256,640\]\S* copy\(", compiled.as_text())
+    assert memory.alias_size_in_bytes >= sum(p.size * 2 for p in pools)
+    for shape in {p.shape for p in pools}:
+        dims = ",".join(str(d) for d in shape)
+        assert not re.search(rf"= bf16\[{dims}\]\S* copy\(", compiled.as_text())
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 16 * 2 ** 30)
+
+
+@pytest.mark.parametrize("program,size", [("window", 8), ("prefill", 128)])
+def test_kv_decoder_programs_compile(chip, program, size):
+    """The K/V decoder's decode window at batch 8 and its smallest final
+    prefill, at the published widths beside 6 GiB of pools of two kinds:
+    they compile, every pool is aliased in place (no copy of one), and
+    arguments plus temporaries stay inside the chip's memory."""
+    compiled, pools = _decoder_program(chip, MELLUM, program, batch=size,
+                                       tokens=size)
+    _pools_updated_in_place(compiled, pools)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("model", [DSV2, MELLUM])
+@pytest.mark.parametrize("program", ["window", "prefill"])
+def test_decoder_serve_programs_fit_the_chip(chip, model, program):
+    """The decode window (32 rows x 4 steps) and the widest prefill (2,048
+    tokens) at the published widths beside the file's pools: they compile,
+    the pools are aliased in place (no copy of one), and arguments plus
+    temporaries stay inside the chip's memory."""
+    compiled, pools = _decoder_program(chip, model, program)
+    _pools_updated_in_place(compiled, pools)

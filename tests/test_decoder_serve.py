@@ -1,7 +1,7 @@
 """The decoder family at tiny widths with the real structure (a dense layer
 and two expert layers, 16 experts in 4 groups, top-3 of the best 2 groups,
 rope and nope parts, a vocabulary slice), float32 on the CPU: the program
-(`models/decoder.py`, `ops/mla_attention.py`, `ops/moe.py`, the paged cache,
+(`models/decoder.py`, `ops/paged_attention.py`, `ops/moe.py`, the paged cache,
 the decoder engine, the batcher and the server) against the plain reference
 (`benchmark/reference/deepseek_v2.py`, imported from where it lives: one
 source of truth), and the bookkeeping around it."""
@@ -22,11 +22,11 @@ from reference import deepseek_v2 as reference  # noqa: E402
 
 from lstm_tensorspark_tpu import cli  # noqa: E402
 from lstm_tensorspark_tpu.models import decoder  # noqa: E402
-from lstm_tensorspark_tpu.ops import mla_attention, moe  # noqa: E402
+from lstm_tensorspark_tpu.ops import moe, paged_attention  # noqa: E402
 from lstm_tensorspark_tpu.serve import SamplingParams, ServeServer  # noqa: E402
 from lstm_tensorspark_tpu.serve.engine import build_engine  # noqa: E402
 from lstm_tensorspark_tpu.serve.state_cache import (  # noqa: E402
-    CacheFullError, PagedLatentCache)
+    CacheFullError, PagedCache)
 
 GREEDY = SamplingParams(greedy=True)
 TINY = os.path.join(ROOT, "benchmark", "tests", "data", "configs",
@@ -91,17 +91,17 @@ def test_one_pass_matches_the_reference(params):
                   for _ in range(CFG.num_hidden_layers))
     pages = [3, 0, 2]
     pos = np.arange(32)
-    items = mla_attention.plan_items(
+    items = paged_attention.plan_items(
         [pages], [0], [n], page=page, tq=16, tiles=2, capacity=8, scratch_page=4)
     live = pos < n
     hidden, _, counts = decoder.forward_tokens(
         params, decoder.absorb(params, CFG), CFG, pools,
         jnp.asarray(np.pad(tokens, (0, 8))), jnp.asarray(np.where(live, pos, 0)),
         jnp.asarray(live),
-        jnp.asarray(np.where(live, np.asarray(pages)[np.minimum(pos, n - 1) // page], 4)),
+        jnp.asarray(np.where(live, np.asarray(pages)[np.minimum(pos, n - 1) // page], 4))[None],
         jnp.asarray(np.where(live, pos % page, 0)),
-        {k: jnp.asarray(v) for k, v in items.items()},
-        tq=mla_attention.PREFILL_TQ, interpret=True)
+        [{k: jnp.asarray(v) for k, v in items.items()}],
+        tq=paged_attention.PREFILL_TQ, interpret=True)
     got = np.asarray(decoder.head_logits(params, hidden))[:n]
     want = np.asarray(reference.forward(params, DOC, tokens, HELD, want=(0, n),
                                         block=16, head_group=2))
@@ -189,12 +189,12 @@ def test_absorbed_attention_equals_the_decompressed_form(params):
     q_cat = np.zeros((1, h, width), np.float32)
     q_cat[0, :, :kv] = np.einsum("hn,hnc->hc", q_nope, np.asarray(ab["w_uk"]))
     q_cat[0, :, kv:kv + rope_d] = q_pe
-    items = mla_attention.plan_items([pages], [n - 1], [1], page=page, tq=1,
+    items = paged_attention.plan_items([pages], [n - 1], [1], page=page, tq=1,
                                      tiles=1, capacity=4, scratch_page=3)
-    ctx = mla_attention.paged_attention(
+    ctx = paged_attention.paged_attention(
         jnp.asarray(q_cat), jnp.asarray(pool),
         {k: jnp.asarray(v) for k, v in items.items()},
-        scale=CFG.softmax_scale, heads=h, kv_rank=kv, name="mla_decode",
+        scale=CFG.softmax_scale, reading=CFG.reading, name="mla_decode",
         interpret=True)
     got = np.einsum("hc,hcv->hv", np.asarray(ctx)[0], np.asarray(ab["w_uv"]))
     kvb = np.asarray(layer["w_kvb"]).reshape(kv, h, nope + vd)
@@ -228,8 +228,8 @@ def test_the_four_shares_add_up_to_the_uncut_layer(params):
             xn, jnp.ones((16,), bool), full["w_router"],
             full["w_gate_up"][first:first + 4], full["w_down"][first:first + 4],
             first=first, n_group=CFG.n_group, topk_group=CFG.topk_group,
-            top_k=CFG.num_experts_per_tok, scale=CFG.routed_scaling_factor, tm=8,
-            interpret=True)
+            top_k=CFG.num_experts_per_tok, scale=CFG.routed_scaling_factor,
+            renormalise=False, tm=8, interpret=True)
         total = total + part
         pairs_here += int(counts["moe_pairs_here"])
     assert pairs_here == 16 * CFG.num_experts_per_tok     # every pair lands once
@@ -240,7 +240,8 @@ def test_router_is_group_limited():
     rng = np.random.default_rng(4)
     x = jnp.asarray(rng.normal(size=(64, 32)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(32, 16)), jnp.float32)
-    experts, weights = moe.route(x, w, n_group=4, topk_group=2, top_k=3, scale=16.0)
+    experts, weights = moe.route(x, w, n_group=4, topk_group=2, top_k=3, scale=16.0,
+                                 renormalise=False)
     p = np.asarray(jax.nn.softmax(x @ w, axis=-1))
     assert (np.asarray([len(set(e // 4)) for e in np.asarray(experts)]) <= 2).all()
     np.testing.assert_allclose(np.asarray(weights),
@@ -291,7 +292,8 @@ def test_flops_count_agrees_with_the_parameters_held():
 # ---- the paged cache's bookkeeping -----------------------------------------
 
 def small_cache():
-    return PagedLatentCache(1, 3, 6, 4, 128, jnp.float32)
+    return PagedCache(3, 4, [decoder.PageKind("latent", (0,), 6, 128)],
+                      jnp.float32)
 
 
 def test_pages_follow_the_session():
@@ -300,7 +302,7 @@ def test_pages_follow_the_session():
     assert fresh and cache.length[slot] == 0
     cache.commit(slot, 10)                              # 3 pages promised
     assert cache.stats()["latent_pages_promised"] == 3
-    assert len(cache.ensure(slot, 5)) == 2 and cache.pages_in_use == 2
+    assert len(cache.ensure(slot, 5)[0][1]) == 2 and cache.pages_in_use == 2
     assert cache.stats()["latent_pages_promised"] == 1
     cache.length[slot] = 5
     cache.unpin("a")                                    # kept: pages stay
