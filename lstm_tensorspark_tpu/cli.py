@@ -456,8 +456,15 @@ def _setup_training(
         make_train_step,
     )
     from .train.loop import init_train_state
+    from .obs import REGISTRY
+    from .train.sharded_update import place_dp_state, sharded_share
 
     mesh, shards = _select_backend(args)
+    sharded_share_gauge = REGISTRY.gauge(
+        "dp_update_sharded_share",
+        "percent of parameter bytes whose DP update is sharded over the "
+        "data axis (reduce-scatter, update 1/dp, all-gather)")
+    sharded_share_gauge.set(0.0)
     if args.batch_size % max(shards, 1) != 0:
         raise SystemExit(
             f"--batch-size {args.batch_size} not divisible by {shards} partitions"
@@ -554,20 +561,23 @@ def _setup_training(
             train_step = make_dp_train_step(
                 loss_fn, optimizer, mesh, stateful=stateful, grad_accum=accum
             )
-        state = state._replace(
-            # EVERY leaf gets the placement the step hands back, the step
-            # counter and the rng included: left on the host they make
-            # the second dispatch a second program (a full recompile of
-            # the train step — 26 s at config 5 over four chips)
-            step=replicate(state.step, mesh),
-            rng=replicate(state.rng, mesh),
-            params=replicate(state.params, mesh),
-            # zero1: the moments are already sharded P("data") — replicate
-            # would gather them back onto every shard
-            opt_state=state.opt_state if zero1
-            else replicate(state.opt_state, mesh),
-            carries=shard_batch(state.carries, mesh) if stateful else None,
-        )
+        # EVERY leaf gets the placement the step hands back, the step
+        # counter and the rng included: left on the host they make the
+        # second dispatch a second program (a full recompile of the train
+        # step — 26 s at config 5 over four chips)
+        if zero1:
+            # the moments are already sharded P("data") — placing them
+            # again would gather them back onto every shard
+            state = state._replace(
+                step=replicate(state.step, mesh),
+                rng=replicate(state.rng, mesh),
+                params=replicate(state.params, mesh),
+            )
+        else:
+            # a large leaf and its moments live sharded over the data
+            # axis (train/sharded_update.py), everything else replicated
+            state = place_dp_state(state, mesh, stateful=stateful)
+            sharded_share_gauge.set(sharded_share(state.params, shards))
 
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -873,6 +883,7 @@ def _run_lm(args, logger) -> int:
     from .train import make_optimizer, make_eval_step
     from .train.loop import evaluate
     from .parallel import make_dp_eval_step, shard_batch
+    from .parallel.data_parallel import replicate
 
     from .utils import span
 
@@ -1036,6 +1047,10 @@ def _run_lm(args, logger) -> int:
             ev = (shard_batch(b, mesh) for b in ev)
             if stateful:
                 ev_carries = shard_batch(ev_carries, mesh)
+            # a large leaf lives sharded between train steps
+            # (train/sharded_update.py): gather it once a sweep, not once
+            # a batch (a no-op for leaves that are whole already)
+            params = replicate(params, mesh)
         return evaluate(eval_step, params, ev, carries=ev_carries)
 
     logger.log({

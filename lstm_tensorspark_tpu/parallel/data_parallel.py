@@ -12,7 +12,12 @@ Mapping, component by component:
                                          averaged grads, which also deletes
                                          the re-broadcast (SURVEY.md §3.3)
   driver-side ``params -= lr*grad``    → optimizer update runs replicated
-                                         on-device inside the same XLA program
+                                         on-device inside the same XLA program;
+                                         a LARGE leaf and its moments live
+                                         sharded 1/dp a chip: all-gather it
+                                         for the forward, reduce-scatter its
+                                         gradient, update the share
+                                         (train/sharded_update.py)
 
 The entire reference round (3 process boundaries, 2 network serializations)
 compiles to ONE jitted program per step.
@@ -37,6 +42,7 @@ from ..train.loop import (  # noqa: F401
     dp_reduce_fn,
     dp_rng_transform,
 )
+from ..train.sharded_update import dp_shard_map
 
 
 def shard_batch(batch, mesh: Mesh, axis: str = "data", *, dim: int = 0):
@@ -78,7 +84,7 @@ def make_dp_train_step(
 
     from ..train.loop import step_body
 
-    def per_shard_step(state: TrainState, batch):
+    def per_shard_step(part, state: TrainState, batch):
         return step_body(
             loss_fn,
             optimizer,
@@ -87,21 +93,13 @@ def make_dp_train_step(
             stateful=stateful,
             grad_accum=grad_accum,
             rng_transform=dp_rng_transform(axis),
-            # treeAggregate + broadcast, collapsed into one ICI all-reduce:
-            reduce_fn=dp_reduce_fn(axis),
+            # treeAggregate + broadcast, collapsed into one ICI all-reduce
+            # (a large leaf: gathered, reduce-scattered, updated as shares):
+            reduce_fn=dp_reduce_fn(part),
         )
 
-    state_spec = TrainState(
-        step=P(), params=P(), opt_state=P(), rng=P(),
-        carries=P(axis) if stateful else P(),
-    )
-    sharded = shard_map(
-        per_shard_step,
-        mesh=mesh,
-        in_specs=(state_spec, P(axis)),
-        out_specs=(state_spec, P()),
-        check_vma=False,
-    )
+    sharded = dp_shard_map(per_shard_step, mesh, (P(axis),), axis=axis,
+                           stateful=stateful)
     if jit:
         sharded = jax.jit(sharded, donate_argnums=(0,) if donate else ())
     return sharded

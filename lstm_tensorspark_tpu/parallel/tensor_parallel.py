@@ -191,7 +191,8 @@ def make_tp_train_step(
             state, ms = step_body(loss_fn, optimizer, state, batch,
                                   stateful=stateful)
             return state, _gated_eval_batches(
-                metric_fn, state, eval_batches, do_eval, ms, keys
+                metric_fn, lambda: state.params, eval_batches, do_eval, ms,
+                keys
             )
 
         in_shardings = (

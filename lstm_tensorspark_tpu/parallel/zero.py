@@ -8,9 +8,16 @@ TPU-natively): per-shard gradients are `psum_scatter`-reduced so each chip
 receives only its 1/dp slice of the summed gradient vector, updates its
 slice of the raveled parameter vector with its slice of the optimizer
 state, and an `all_gather` rebuilds the full (replicated) params for the
-next forward. Communication volume per step is the SAME as the pmean DP
-step (reduce-scatter + all-gather = one all-reduce, ring-wise), so the
-memory saving is free at the collective level.
+next forward. Communication VOLUME per step is that of the pmean DP step
+(reduce-scatter + all-gather = one all-reduce, ring-wise); the TIME is not:
+on four TPU v5 lite a reduce-scatter of 205 MB takes 2.43 ms and the
+all-gather 1.73, 4.16 ms for what one all-reduce does in 3.60 (PERF.md §6,
+PR 35; PR 30 measured 4.26), and an all-gather whose result is a donated
+parameter adds a copy of the leaf into the program and one out of it. What
+pays for the difference is the optimizer pass on 1/dp of the state.
+`train/sharded_update.py` is the leaf-wise form of the same idea that the
+default DP builders apply to large leaves by themselves; it keeps full
+logical shapes, which this raveled form does not.
 
 Numerics: the update is elementwise (SGD/momentum/Adam/AdamW/RMSProp on a
 contiguous slice of the raveled vector ≡ the same transform leaf-wise), so

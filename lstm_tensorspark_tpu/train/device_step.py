@@ -42,8 +42,6 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from jax import shard_map
-
 from ..data.device_dataset import DeviceLMData, slice_window
 from .loop import (
     TrainState,
@@ -53,6 +51,7 @@ from .loop import (
     step_body,
     summarize_scan_metrics,
 )
+from .sharded_update import dp_shard_map
 
 
 def _scan_indexed(loss_fn, optimizer, state, arrays, idxs, *, window_fn,
@@ -102,10 +101,12 @@ def _device_eval_batches(metric_fn, params, eval_batches, keys):
     return {k: tot[k] / wt for k in keys}
 
 
-def _gated_eval_batches(metric_fn, state, eval_batches, do_eval, ms, keys):
+def _gated_eval_batches(metric_fn, params_fn, eval_batches, do_eval, ms, keys):
+    """``params_fn()`` gives the whole parameters; called inside the eval
+    branch, so a DP step gathers its sharded leaves on eval calls only."""
     ms.update(lax.cond(
         do_eval,
-        lambda _: _device_eval_batches(metric_fn, state.params, eval_batches,
+        lambda _: _device_eval_batches(metric_fn, params_fn(), eval_batches,
                                        keys),
         lambda _: {k: jnp.float32(jnp.nan) for k in keys},
         operand=None,
@@ -142,12 +143,13 @@ def _device_lm_eval(loss_fn, params, eval_arrays, n_windows, seq_len, *,
     return tot / jnp.maximum(wt, 1.0)
 
 
-def _gated_lm_eval(loss_fn, state, eval_arrays, do_eval, ms, *, n_windows,
+def _gated_lm_eval(loss_fn, params_fn, eval_arrays, do_eval, ms, *, n_windows,
                    seq_len, stateful, eval_carries, psum_axis=None):
+    """``params_fn()`` as in `_gated_eval_batches`."""
     ms["eval_loss"] = lax.cond(
         do_eval,
         lambda _: _device_lm_eval(
-            loss_fn, state.params, eval_arrays, n_windows, seq_len,
+            loss_fn, params_fn(), eval_arrays, n_windows, seq_len,
             stateful=stateful, eval_carries=eval_carries,
             psum_axis=psum_axis,
         ),
@@ -194,7 +196,8 @@ def make_device_train_step(
         def step(state: TrainState, arrays, idxs, eval_batches, do_eval):
             state, ms = core(state, arrays, idxs)
             return state, _gated_eval_batches(
-                metric_fn, state, eval_batches, do_eval, ms, keys
+                metric_fn, lambda: state.params, eval_batches, do_eval, ms,
+                keys
             )
 
     return _jit_step(step, jit, donate)
@@ -225,39 +228,32 @@ def make_device_dp_train_step(
     With ``metric_fn`` set, the fused step's eval batches are REPLICATED
     (``P()``): every shard runs the identical eval concurrently — same
     wall-clock as one shard, exact same value on all, no collective."""
-    kw = dict(stateful=stateful, grad_accum=grad_accum,
-              rng_transform=dp_rng_transform(axis), reduce_fn=dp_reduce_fn(axis))
-    state_spec = TrainState(
-        step=P(), params=P(), opt_state=P(), rng=P(),
-        carries=P(axis) if stateful else P(),
-    )
-    def core(state: TrainState, arrays, idxs):
+    def core(part, state: TrainState, arrays, idxs):
         return _scan_indexed(
             loss_fn, optimizer, state, arrays, idxs, window_fn=window_fn,
-            **kw,
+            stateful=stateful, grad_accum=grad_accum,
+            rng_transform=dp_rng_transform(axis),
+            reduce_fn=dp_reduce_fn(part),
         )
 
     if metric_fn is None:
         per_shard = core
-        in_specs = (state_spec, arrays_spec, idx_spec)
+        in_specs = (arrays_spec, idx_spec)
     else:
         keys = tuple(metric_keys)
 
-        def per_shard(state: TrainState, arrays, idxs, eval_batches, do_eval):
-            state, ms = core(state, arrays, idxs)
+        def per_shard(part, state: TrainState, arrays, idxs, eval_batches,
+                      do_eval):
+            state, ms = core(part, state, arrays, idxs)
             return state, _gated_eval_batches(
-                metric_fn, state, eval_batches, do_eval, ms, keys
+                metric_fn, lambda: part.gather(state.params), eval_batches,
+                do_eval, ms, keys
             )
 
-        in_specs = (state_spec, arrays_spec, idx_spec, P(), P())
+        in_specs = (arrays_spec, idx_spec, P(), P())
 
-    sharded = shard_map(
-        per_shard,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=(state_spec, P()),
-        check_vma=False,
-    )
+    sharded = dp_shard_map(per_shard, mesh, in_specs, axis=axis,
+                           stateful=stateful)
     return _jit_step(sharded, jit, donate)
 
 
@@ -312,8 +308,9 @@ def make_device_lm_train_step(
                  eval_carries=None):
             state, ms = core(state, arrays, w0)
             return state, _gated_lm_eval(
-                loss_fn, state, eval_arrays, do_eval, ms, n_windows=n_ev,
-                seq_len=ev_T, stateful=stateful, eval_carries=eval_carries,
+                loss_fn, lambda: state.params, eval_arrays, do_eval, ms,
+                n_windows=n_ev, seq_len=ev_T, stateful=stateful,
+                eval_carries=eval_carries,
             )
 
     return _jit_step(step, jit, donate)
@@ -390,45 +387,36 @@ def make_device_dp_lm_train_step(
     global token-weighted mean (same value as make_dp_eval_step +
     evaluate())."""
     window_fn = lambda arrays, w: slice_window(arrays, w, data.seq_len)  # noqa: E731
-    kw = dict(stateful=stateful, grad_accum=grad_accum,
-              rng_transform=dp_rng_transform(axis), reduce_fn=dp_reduce_fn(axis))
-    state_spec = TrainState(
-        step=P(), params=P(), opt_state=P(), rng=P(),
-        carries=P(axis) if stateful else P(),
-    )
     stream_spec = {"streams": P(axis, None), "shifted": P(axis, None)}
 
-    def core(state: TrainState, arrays, w0):
+    def core(part, state: TrainState, arrays, w0):
         return _scan_indexed(
             loss_fn, optimizer, state, arrays,
             _lm_window_idxs(w0, data, steps_per_call),
-            window_fn=window_fn, **kw,
+            window_fn=window_fn, stateful=stateful, grad_accum=grad_accum,
+            rng_transform=dp_rng_transform(axis),
+            reduce_fn=dp_reduce_fn(part),
         )
 
     if eval_data is None:
         per_shard = core
-        in_specs = (state_spec, stream_spec, P())
+        in_specs = (stream_spec, P())
     else:
         n_ev = min(eval_data.n_windows, eval_windows or eval_data.n_windows)
         ev_T = eval_data.seq_len
 
-        def per_shard(state: TrainState, arrays, w0, eval_arrays, do_eval,
-                      eval_carries):
-            state, ms = core(state, arrays, w0)
+        def per_shard(part, state: TrainState, arrays, w0, eval_arrays,
+                      do_eval, eval_carries):
+            state, ms = core(part, state, arrays, w0)
             return state, _gated_lm_eval(
-                loss_fn, state, eval_arrays, do_eval, ms, n_windows=n_ev,
-                seq_len=ev_T, stateful=stateful, eval_carries=eval_carries,
-                psum_axis=axis,
+                loss_fn, lambda: part.gather(state.params), eval_arrays,
+                do_eval, ms, n_windows=n_ev, seq_len=ev_T, stateful=stateful,
+                eval_carries=eval_carries, psum_axis=axis,
             )
 
-        in_specs = (state_spec, stream_spec, P(), stream_spec, P(),
+        in_specs = (stream_spec, P(), stream_spec, P(),
                     P(axis) if stateful else P())
 
-    sharded = shard_map(
-        per_shard,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=(state_spec, P()),
-        check_vma=False,
-    )
+    sharded = dp_shard_map(per_shard, mesh, in_specs, axis=axis,
+                           stateful=stateful)
     return _jit_step(sharded, jit, donate)

@@ -28,6 +28,7 @@ from .. import obs
 from ..ops import parallel_scan as _pscan
 from ..resilience import faults as _faults
 from ..utils.tracing import span
+from .sharded_update import WHOLE, DpReduce
 
 
 class AnomalousTrainingError(RuntimeError):
@@ -124,17 +125,25 @@ def step_body(
     paths (keeps them provably identical — test_dp.py's loss-parity relies on
     it). ``rng_transform`` perturbs the per-step dropout key (DP folds in the
     shard index); ``reduce_fn(grads, loss)`` inserts the cross-shard mean
-    (DP: lax.pmean — the treeAggregate replacement); ``grad_accum > 1``
+    (DP: `DpReduce` — the treeAggregate replacement, under which a large
+    leaf arrives, is updated and leaves as one chip's share); ``grad_accum > 1``
     microbatches the gradient computation (stateless losses only — recurrent
     carries are batch-aligned and do not split)."""
     rng, sub = jax.random.split(state.rng)
     if rng_transform is not None:
         sub = rng_transform(sub)
+    # Under DP a large leaf lives as this chip's share of it
+    # (train/sharded_update.py): gather the whole parameters for the forward
+    # and backward; the reduction hands such a leaf's gradient back as the
+    # share, and the update below runs on shares. With no such leaf (and off
+    # DP) every `part` method is the plain form.
+    part = reduce_fn.part if isinstance(reduce_fn, DpReduce) else WHOLE
+    whole = part.gather(state.params)
     if grad_accum > 1:
         if stateful:
             raise ValueError("grad_accum is not supported with stateful TBPTT")
         loss, grads = accumulate_grads(
-            loss_fn, state.params, batch, sub, grad_accum=grad_accum
+            loss_fn, whole, batch, sub, grad_accum=grad_accum
         )
         carries = state.carries
     else:
@@ -143,22 +152,23 @@ def step_body(
                 loss_fn, p, batch, sub, state.carries, stateful=stateful
             ),
             has_aux=True,
-        )(state.params)
+        )(whole)
         carries = jax.lax.stop_gradient(aux["carries"]) if stateful else state.carries
     grads = _faults.tamper_grads(grads, state.step)  # identity when unarmed
     if reduce_fn is not None:
         grads, loss = reduce_fn(grads, loss)
-    updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
+    gnorm = part.global_norm(grads)
+    updates, opt_state = optax.with_extra_args_support(optimizer).update(
+        grads, state.opt_state, state.params, global_norm=gnorm)
     params = optax.apply_updates(state.params, updates)
-    gnorm = optax.global_norm(grads)
     # Non-finite guard: a NaN/Inf loss or gradient must not poison the
     # params/optimizer moments (one bad batch would otherwise end the run —
     # every later step inherits the NaNs). Skip the whole update (params,
     # moments, AND carries — a diverged forward pass taints the recurrent
     # state too), advance step/rng so the budget and data order hold, and
     # surface the skip as metrics["anomalous"] for the host loop to count.
-    # Under DP the guard decision is uniform across shards: loss and grads
-    # are pmean'd before the check.
+    # Under DP the guard decision is uniform across shards: the loss is
+    # pmean'd and the norm is the global one before the check.
     finite = jnp.isfinite(loss) & jnp.isfinite(gnorm)
     keep = lambda new, old: jnp.where(finite, new, old)  # noqa: E731
     params = jax.tree.map(keep, params, state.params)
@@ -182,14 +192,11 @@ def dp_rng_transform(axis: str = "data"):
     return lambda sub: jax.random.fold_in(sub, jax.lax.axis_index(axis))
 
 
-def dp_reduce_fn(axis: str = "data"):
-    """The treeAggregate replacement: mean grads (and loss, for logging)
-    across shards with one ICI all-reduce. The ONE definition shared by
-    every DP step builder — change the gradient-reduction contract here."""
-    return lambda grads, loss: (
-        jax.lax.pmean(grads, axis),
-        jax.lax.pmean(loss, axis),
-    )
+def dp_reduce_fn(part) -> DpReduce:
+    """The gradient reduction of every DP step builder, for the `Partition`
+    that `dp_shard_map` hands its per-shard function: see `DpReduce`
+    (train/sharded_update.py), where the contract lives."""
+    return DpReduce(part)
 
 
 def summarize_scan_metrics(ms) -> dict:

@@ -34,8 +34,6 @@ import jax
 import optax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from jax import shard_map
-
 from ..data.device_dataset import DeviceLMData
 from .device_step import _gated_eval_batches, _gated_lm_eval, _jit_step
 from .loop import (
@@ -45,6 +43,7 @@ from .loop import (
     step_body,
     summarize_scan_metrics,
 )
+from .sharded_update import dp_shard_map
 
 
 def _scan_steps(loss_fn, optimizer, state, batches, *, stateful, rng_transform=None,
@@ -66,14 +65,15 @@ def _scan_steps(loss_fn, optimizer, state, batches, *, stateful, rng_transform=N
 def _fused_tail(loss_fn, eval_data, eval_windows, metric_fn, metric_keys,
                 stateful, psum_axis=None):
     """Resolve which fused-eval tail (if any) the builder should append:
-    returns None (plain step) or a closure (state, ms, *eval_args) -> ms."""
+    returns None (plain step) or a closure (params_fn, ms, *eval_args) ->
+    ms, ``params_fn()`` giving the whole parameters (device_step.py)."""
     if eval_data is not None:
         n_ev = min(eval_data.n_windows, eval_windows or eval_data.n_windows)
         ev_T = eval_data.seq_len
 
-        def tail(state, ms, eval_arrays, do_eval, eval_carries=None):
+        def tail(params_fn, ms, eval_arrays, do_eval, eval_carries=None):
             return _gated_lm_eval(
-                loss_fn, state, eval_arrays, do_eval, ms, n_windows=n_ev,
+                loss_fn, params_fn, eval_arrays, do_eval, ms, n_windows=n_ev,
                 seq_len=ev_T, stateful=stateful, eval_carries=eval_carries,
                 psum_axis=psum_axis,
             )
@@ -82,9 +82,9 @@ def _fused_tail(loss_fn, eval_data, eval_windows, metric_fn, metric_keys,
     if metric_fn is not None:
         keys = tuple(metric_keys)
 
-        def tail(state, ms, eval_batches, do_eval):
+        def tail(params_fn, ms, eval_batches, do_eval):
             return _gated_eval_batches(
-                metric_fn, state, eval_batches, do_eval, ms, keys
+                metric_fn, params_fn, eval_batches, do_eval, ms, keys
             )
 
         return tail
@@ -131,7 +131,7 @@ def make_multi_train_step(
 
         def multi_step(state: TrainState, batches, *eval_args):
             state, ms = core(state, batches)
-            return state, tail(state, ms, *eval_args)
+            return state, tail(lambda: state.params, ms, *eval_args)
 
     return _jit_step(multi_step, jit, donate)
 
@@ -164,43 +164,37 @@ def make_dp_multi_train_step(
                        metric_keys, stateful,
                        psum_axis=axis if eval_data is not None else None)
 
-    def core(state: TrainState, batches):
+    def core(part, state: TrainState, batches):
         return _scan_steps(
             loss_fn, optimizer, state, batches, stateful=stateful,
             grad_accum=grad_accum,
             rng_transform=dp_rng_transform(axis),
-            reduce_fn=dp_reduce_fn(axis),
+            reduce_fn=dp_reduce_fn(part),
         )
 
-    state_spec = TrainState(
-        step=P(), params=P(), opt_state=P(), rng=P(),
-        carries=P(axis) if stateful else P(),
-    )
     if tail is None:
         per_shard = core
-        in_specs = (state_spec, P(None, axis))
+        in_specs = (P(None, axis),)
     elif eval_data is not None:
         stream_spec = {"streams": P(axis, None), "shifted": P(axis, None)}
 
-        def per_shard(state, batches, eval_arrays, do_eval, eval_carries):
-            state, ms = core(state, batches)
-            return state, tail(state, ms, eval_arrays, do_eval, eval_carries)
+        def per_shard(part, state, batches, eval_arrays, do_eval,
+                      eval_carries):
+            state, ms = core(part, state, batches)
+            return state, tail(lambda: part.gather(state.params), ms,
+                               eval_arrays, do_eval, eval_carries)
 
-        in_specs = (state_spec, P(None, axis), stream_spec, P(),
+        in_specs = (P(None, axis), stream_spec, P(),
                     P(axis) if stateful else P())
     else:
 
-        def per_shard(state, batches, eval_batches, do_eval):
-            state, ms = core(state, batches)
-            return state, tail(state, ms, eval_batches, do_eval)
+        def per_shard(part, state, batches, eval_batches, do_eval):
+            state, ms = core(part, state, batches)
+            return state, tail(lambda: part.gather(state.params), ms,
+                               eval_batches, do_eval)
 
-        in_specs = (state_spec, P(None, axis), P(), P())
+        in_specs = (P(None, axis), P(), P())
 
-    sharded = shard_map(
-        per_shard,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=(state_spec, P()),
-        check_vma=False,
-    )
+    sharded = dp_shard_map(per_shard, mesh, in_specs, axis=axis,
+                           stateful=stateful)
     return _jit_step(sharded, jit, donate)

@@ -9,7 +9,34 @@ train poorly without them).
 
 from __future__ import annotations
 
+import jax
+import jax.numpy as jnp
 import optax
+
+
+def clip_by_global_norm(max_norm: float) -> optax.GradientTransformationExtraArgs:
+    """`optax.clip_by_global_norm`, able to be GIVEN the norm: the
+    data-parallel step holds large leaves' gradients as one chip's share
+    (train/sharded_update.py), so a norm taken from the tree this stage
+    sees would differ from chip to chip. ``update(..., global_norm=n)``
+    clips by ``n``; without it the norm is the tree's own, and the stage is
+    optax's to the letter. Same (empty) state, so a chain built with either
+    reads the other's checkpoint."""
+
+    def update_fn(updates, state, params=None, *, global_norm=None, **_):
+        del params
+        g_norm = (optax.global_norm(updates) if global_norm is None
+                  else global_norm)
+        trigger = jnp.squeeze(g_norm < max_norm)
+
+        def clip_fn(t):
+            return jax.lax.select(
+                trigger, t, (t / g_norm.astype(t.dtype)) * max_norm)
+
+        return jax.tree.map(clip_fn, updates), state
+
+    return optax.GradientTransformationExtraArgs(
+        optax.init_empty_state, update_fn)
 
 
 def make_optimizer(
@@ -60,6 +87,6 @@ def make_optimizer(
 
     chain = []
     if clip_norm is not None:
-        chain.append(optax.clip_by_global_norm(clip_norm))
+        chain.append(clip_by_global_norm(clip_norm))
     chain.append(opt)
     return optax.chain(*chain)

@@ -409,9 +409,17 @@ def test_config5_dp4_train_step_compiles():
     """`chip_smoke.py --multichip`'s DP program, compiled for the four
     described chips before a four-chip call is paid for: the shard_map
     device-data step over a ("data",) mesh of 4, B=32 global (8 rows and
-    both fused kernels per chip), the gradient all-reduce in the compiled
-    text, the per-device memory inside one chip's HBM, and the donated
-    state updated in place."""
+    both fused kernels per chip), the per-device memory inside one chip's
+    HBM, and the donated state updated in place.
+
+    The weight update of the two 205 MB matrices is sharded
+    (train/sharded_update.py): they and their moments live a quarter a
+    chip, a step all-gathers the parameter, reduce-scatters its gradient
+    and runs Adam on the quarter; the small leaves keep the all-reduce. A
+    `copy` of a matrix around the gather, the scatter or the donated alias
+    would eat what the quarter saves: gathering the NEW quarter back into
+    a whole donated parameter compiled to one copy in and one out of the
+    program (PERF.md, PR 35), and PR 33 found 1.92 ms of relayout copies."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -421,6 +429,7 @@ def test_config5_dp4_train_step_compiles():
     from lstm_tensorspark_tpu.train import (
         make_device_dp_lm_train_step, make_optimizer)
     from lstm_tensorspark_tpu.train.loop import init_train_state
+    from lstm_tensorspark_tpu.train.sharded_update import dp_state_spec
 
     mesh = Mesh(np.asarray(_v5e_devices()), ("data",))
     B, T, n_windows = 32, 64, 100
@@ -430,20 +439,22 @@ def test_config5_dp4_train_step_compiles():
     def loss_fn(params, batch, rng, carries):
         return lm_loss(params, batch, cfg, carries=carries)
 
-    def put(tree, spec):
-        sharding = NamedSharding(mesh, spec)
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=sharding), tree)
+    def put(tree, specs):
+        return jax.tree.map(lambda x, spec: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, spec)),
+            tree, specs)
 
     state = jax.eval_shape(lambda: init_train_state(
         init_lm(jax.random.PRNGKey(0), cfg), optimizer,
         jax.random.PRNGKey(1), carries=init_carries(cfg, B)))
-    state = state._replace(
-        **{f: put(getattr(state, f), P())
-           for f in ("step", "params", "opt_state", "rng")},
-        carries=put(state.carries, P("data")))
+    spec = dp_state_spec(state, 4, stateful=True)
+    split = [s for s in jax.tree.leaves((spec.params, spec.opt_state))
+             if s != P()]
+    assert sorted(split, key=str) == [P("data")] * 3 + [P(None, "data")] * 3
+    state = put(state, spec)
     stream = jax.ShapeDtypeStruct((B, n_windows * T), jnp.int32)
-    arrays = put({"streams": stream, "shifted": stream}, P("data", None))
+    arrays = {"streams": stream, "shifted": stream}
+    arrays = put(arrays, {k: P("data", None) for k in arrays})
     data = DeviceLMData(arrays=arrays, batch_size=B, seq_len=T,
                         n_windows=n_windows)
     step = make_device_dp_lm_train_step(
@@ -453,9 +464,34 @@ def test_config5_dp4_train_step_compiles():
         compiled = step.lower(state, arrays, w0).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 2 * cfg.num_layers
-    assert "all-reduce" in text
+    assert "all-reduce" in text  # the layers' matrices and the biases
+
+    def results(opcode):
+        return set(re.findall(r"= \w+(\[[\d,]+\])\S* %s\(" % opcode, text))
+
+    # the forward reads bf16 casts, so the compiler may gather those
+    assert results("reduce-scatter") == {"[256,50000]", "[50000,256]"}
+    assert results("all-gather") == {"[1024,50000]", "[50000,1024]"}
+    assert not {"[1024,50000]", "[50000,1024]"} & results("all-reduce")
+    # Adam writes the parameter and both moments in one fusion: on quarters
+    fused = re.findall(r"= \((f32\[[\d,]+\])\S*, (f32\[[\d,]+\])\S*, "
+                       r"(f32\[[\d,]+\])\S*\) fusion\(", text)
+    assert ("f32[256,50000]",) * 3 in fused, fused
+    assert ("f32[50000,256]",) * 3 in fused, fused
+    whole = {"f32[1024,50000]", "f32[50000,1024]"}
+    assert not [f for f in fused if whole & set(f)], fused
+    copies = _copies_of(compiled, "50000,1024", "1024,50000", "256,50000",
+                        "50000,256", "1024,1024")
+    assert not copies, copies[:4]
     _fits_hbm(compiled)
-    _state_updated_in_place(compiled, state)
+    # per chip: the small leaves and their moments whole, a quarter of the
+    # two matrices and of theirs
+    held = sum(
+        x.size * x.dtype.itemsize // (1 if s == P() else 4)
+        for x, s in zip(jax.tree.leaves((state.params, state.opt_state)),
+                        jax.tree.leaves((spec.params, spec.opt_state))))
+    assert 0.70e9 < held < 0.72e9
+    assert compiled.memory_analysis().alias_size_in_bytes >= held
 
 
 # ---- the decoder family: paged attention, grouped experts --------------
