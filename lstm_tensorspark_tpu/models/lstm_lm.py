@@ -56,14 +56,20 @@ class LMConfig:
     bptt: str = "sequential"
     # dtype of the materialized [N,V] logits array (N = B·T). At the
     # word-LM vocab sizes every pass over that array is an HBM-bandwidth
-    # cost — the head matmul writes it, the logsumexp reads it, and the
-    # backward (ops/xent.py dense_xent_mean) reads it three more times: one
-    # pass for the bias gradient and once as the operand of each backward
-    # matmul, which form dlogits on the fly — no dlogits array is stored,
-    # and none is copied into a second layout (819 MB each at config 5).
-    # "bfloat16" halves all of them (+25% measured on config 3 with the
-    # autodiff backward) while the logsumexp/NLL itself still runs in f32
-    # over the upcast values; it is also the dtype dlogits is rounded to.
+    # cost. On a TPU (ops/xent.py dense_xent_mean, ops/pallas_xent.py) the
+    # head kernel writes it with its logsumexp and target logit, and the
+    # backward reads it twice: the dx kernel (which also sums the bias
+    # gradient) and XLA's weight-gradient matmul, each forming dlogits on
+    # the fly — no dlogits array is stored, and none is copied into a
+    # second layout (819 MB each at config 5). Where the kernels do not run
+    # (another backend, H off the 128 lanes, a head stored row-major, an
+    # automatic mesh axis around the call, a head the data-parallel step
+    # gathers) XLA reads it four times:
+    # logsumexp + target logit, the bias gradient, and each backward
+    # matmul. "bfloat16" halves all of them (+25% measured on config 3
+    # with the autodiff backward) while the logsumexp/NLL itself still
+    # runs in f32 over the upcast values; it is also the dtype dlogits is
+    # rounded to.
     # Default float32 — opt-in numerics trade. No effect on the
     # chunked-xent path (V >= _CHUNKED_XENT_MIN_V), which never
     # materializes the array this flag exists to shrink.

@@ -8,13 +8,22 @@ what they keep in HBM. Exactness tests: tests/test_xent.py.
 ``dense_xent_mean`` — the path every configuration below
 `models/lstm_lm._CHUNKED_XENT_MIN_V` runs (configs 1, 3 and 5). The
 ``[N, V]`` logits array exists (819 MB in bf16 at config 5's N = 8192,
-V = 50,000). A train step writes it once (head matmul) and reads it four
-times: logsumexp + target logit, the bias gradient, and once inside each of
-the two backward matmuls, which form their dlogits operand from
-``(logits, lse, targets)`` as they read — XLA fuses that element-wise
-producer into the dot, so no dlogits array is stored and none is copied
-into a second layout (the autodiff backward wrote dlogits, and XLA then
-wrote it again in the layout the other matmul preferred: PERF.md §6, PR 27).
+V = 50,000). On a TPU a train step writes it once and reads it twice, and
+every reduction over it happens inside a matmul that holds the tile in VMEM
+(`ops/pallas_xent.py`): ``lm_head_fwd`` writes the logits with their
+logsumexp and target logit, ``lm_head_dx`` forms dlogits from
+``(logits, lse, targets)`` for ``dys`` and sums the bias gradient, and
+XLA's weight-gradient matmul forms the same dlogits in its own operand. No
+dlogits array is stored and none is copied into a second layout (the
+autodiff backward wrote dlogits, and XLA then wrote it again in the layout
+the other matmul preferred: PERF.md §6). Where `pallas_xent.plan`
+says no — another backend, a width off the MXU's 128 lanes, a head the TPU
+stores row-major, rows no tile divides, a ``shard_map`` that leaves a mesh
+axis automatic (the tensor-, sequence- and pipeline-parallel steps without
+``use_pallas``: Mosaic lowers no kernel there), a head the data-parallel step
+gathers (its weight gradient is faster after XLA's logits) — XLA runs the same
+algorithm: logits matmul, logsumexp and target logit, the bias gradient,
+and the two backward matmuls, each forming dlogits as it reads.
 
 ``chunked_xent_mean`` — above that threshold: the ``[N, V]`` logits never
 exist in HBM. The vocabulary is processed in ``chunk``-column tiles:
@@ -43,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from . import pallas_xent
 from .embedding import selected_logits
 
 
@@ -178,7 +188,9 @@ def dense_xent_mean(ys, kernel, bias, targets, logits_dtype):
     """Mean next-token NLL with the whole ``[N, V]`` logits array in HBM
     (the fast path below `_CHUNKED_XENT_MIN_V`), and a hand-written
     backward in which dlogits is ONE ``[N, V]`` expression that both
-    backward matmuls take in the logits' own layout.
+    backward products take in the logits' own layout: on a TPU the
+    kernels of `ops/pallas_xent.py` and XLA's weight gradient, elsewhere
+    XLA's operations alone (the module's docstring says when).
 
     ``ys`` [B, T, H] (float), ``kernel`` [H, V], ``bias`` [V], ``targets``
     [B, T] int. ``logits_dtype`` is the dtype the logits are stored in and
@@ -195,7 +207,11 @@ def dense_xent_mean(ys, kernel, bias, targets, logits_dtype):
     dW its N axis, and dlogits is element-wise in (logits, lse, targets),
     so XLA fuses it into the operand of each matmul: neither the copy nor
     a dlogits array is left in the step (40.0 ms; PERF.md §6, PR 27;
-    tests_tpu/test_head_layout_tpu.py holds the compiled step to it).
+    tests_tpu/test_head_layout_tpu.py holds the compiled step to it). The
+    kernels read the head vocabulary-major (``kernel.T``), which is free
+    where the TPU stores it with H minor (config 5's ``f32[1024,50000]``
+    is stored ``{0,1}``; `pallas_xent.stored_vocab_major`); a tied head
+    (``embedding.T``) is read as the embedding it is.
     """
     loss, _ = _dense_fwd(ys, kernel, bias, targets, logits_dtype)
     return loss
@@ -210,40 +226,59 @@ def _time_major_rows(ys, targets):
 
 def _dense_fwd(ys, kernel, bias, targets, logits_dtype):
     ys2d, tgt = _time_major_rows(ys, targets)
-    logits = (
-        jnp.dot(ys2d.astype(kernel.dtype), kernel,
-                preferred_element_type=logits_dtype)
-        + bias.astype(logits_dtype)
-    )
-    # nll via logsumexp, NOT log_softmax: identical math (nll = lse - z_t)
-    # without an [N, V] log-prob array
-    logits_f = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(logits_f, axis=-1)
-    nll = lse - selected_logits(logits_f, tgt)
+    N, H = ys2d.shape
+    plan = pallas_xent.plan(N, H, kernel.shape[1], logits_dtype,
+                            head_dtype=kernel.dtype)
+    if plan is not None:
+        # one kernel writes the logits and reduces them while they are in
+        # VMEM: logsumexp and target logit on the stored values, float32
+        w = kernel.T
+        logits, lse, tl = pallas_xent.lm_head_fwd(ys2d, w, bias, tgt,
+                                                  logits_dtype, plan)
+        nll = lse - tl
+    else:
+        w = None
+        logits = (
+            jnp.dot(ys2d.astype(kernel.dtype), kernel,
+                    preferred_element_type=logits_dtype)
+            + bias.astype(logits_dtype)
+        )
+        # nll via logsumexp, NOT log_softmax: identical math (nll = lse -
+        # z_t) without an [N, V] log-prob array
+        logits_f = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits_f, axis=-1)
+        nll = lse - selected_logits(logits_f, tgt)
     # the mean sums in [B, T] order, so the value is bit-for-bit the plain
     # formula's (32 KB transposed, against 819 MB left alone)
     loss = jnp.mean(nll.reshape(targets.shape[::-1]).T)
-    return loss, (logits, lse, ys, kernel, bias, targets)
+    return loss, (logits, lse, ys, kernel, bias, targets, w)
 
 
 def _dense_bwd(logits_dtype, residuals, g):
-    logits, lse, ys, kernel, bias, targets = residuals
+    logits, lse, ys, kernel, bias, targets, w = residuals
     B, T, H = ys.shape
     N, V = logits.shape
     ys2d, tgt = _time_major_rows(ys, targets)
+    gN = (g / N).astype(jnp.float32)
     # dlogits = (softmax - onehot) * g/N, element-wise over the logits and
     # rounded once to the stored dtype (what autodiff handed the matmuls
     # too); db is summed in float32, before the rounding
     p = jnp.exp(logits.astype(jnp.float32) - lse[:, None])
     onehot = lax.broadcasted_iota(tgt.dtype, (N, V), 1) == tgt[:, None]
-    dlog = (p - onehot.astype(jnp.float32)) * (g / N).astype(jnp.float32)
-    dbias = jnp.sum(dlog, axis=0)
-    dlog = dlog.astype(logits_dtype)
-    # both products take dlogits as [N, V]: dys contracts V, dW contracts
-    # N. Output dtypes as autodiff's transposes had them
-    # (preferred_element_type rides along)
-    dys = lax.dot_general(dlog, kernel, (((1,), (1,)), ((), ())),
-                          preferred_element_type=logits_dtype)
+    dlog_f = (p - onehot.astype(jnp.float32)) * gN
+    dlog = dlog_f.astype(logits_dtype)
+    if w is not None:
+        # dys and db from one kernel that forms its dlogits tile in VMEM
+        dys, dbias = pallas_xent.lm_head_dx(
+            logits, lse, tgt, w, gN,
+            pallas_xent.plan(N, H, V, logits_dtype, head_dtype=kernel.dtype))
+    else:
+        dbias = jnp.sum(dlog_f, axis=0)
+        # both products take dlogits as [N, V]: dys contracts V, dW
+        # contracts N. Output dtypes as autodiff's transposes had them
+        # (preferred_element_type rides along)
+        dys = lax.dot_general(dlog, kernel, (((1,), (1,)), ((), ())),
+                              preferred_element_type=logits_dtype)
     dkernel = lax.dot_general(ys2d.astype(kernel.dtype), dlog,
                               (((0,), (0,)), ((), ())),
                               preferred_element_type=logits_dtype)

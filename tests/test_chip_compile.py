@@ -14,6 +14,7 @@ up to a minute and a half each, run by hand before a chip call:
 """
 
 import contextlib
+import math
 import os
 import re
 
@@ -54,17 +55,19 @@ def chip():
 
 @contextlib.contextmanager
 def _kernels_selectable():
-    """Steer the dispatch onto the kernels: `pallas_lstm.supported()` asks
-    `jax.default_backend()`, which is the CPU here — in the test, not
-    through an option of the program."""
+    """Steer the dispatch onto the kernels: `pallas_lstm.supported()` and
+    `pallas_xent.plan()` ask `jax.default_backend()`, which is the CPU
+    here — in the test, not through an option of the program."""
     import lstm_tensorspark_tpu.ops.pallas_lstm as pallas_lstm
+    import lstm_tensorspark_tpu.ops.pallas_xent as pallas_xent
 
-    real = pallas_lstm.supported
+    real, real_plan = pallas_lstm.supported, pallas_xent.plan
     pallas_lstm.supported = lambda *a, **k: real(*a, **{**k, "platform": "tpu"})
+    pallas_xent.plan = lambda *a, **k: real_plan(*a, **{**k, "platform": "tpu"})
     try:
         yield
     finally:
-        pallas_lstm.supported = real
+        pallas_lstm.supported, pallas_xent.plan = real, real_plan
 
 
 @pytest.fixture(autouse=True)
@@ -201,6 +204,169 @@ def test_layer_state_keeps_its_layout_under_adam(chip):
         *_on(chip, (params, opt_state, xs))).compile()
     assert _kernel_calls(compiled) >= 2
     assert not _copies_of(compiled, "1024,1024")
+
+
+# ---- train head + loss ------------------------------------------------
+
+# `%jvp_lm_head_fwd_.1 = (bf16[8192,50000]{1,0:T(8,128)(2,1)}, ...) custom-call(`
+_HEAD_KERNEL = re.compile(r"^\s*%[\w.\-]*(lm_head_fwd|lm_head_dx)[\w.\-]* = .* "
+                          r"custom-call\(", re.M)
+# `%fusion.563 = f32[8192]{0} fusion(%get-tuple-element.71), kind=kLoop, ...`
+_FUSION = re.compile(r"^\s*%([\w.\-]+) = (\S+) fusion\(([^)]*)\)", re.M)
+_SHAPED = re.compile(r"^\s*%([\w.\-]+) = ([a-z]+\d+)\[([\d,]*)\]", re.M)
+
+
+def _head_kernels(text: str) -> list[str]:
+    return sorted(_HEAD_KERNEL.findall(text))
+
+
+def _vocab_arrays(text: str, vocab: int, min_bytes: int = 100 * 10**6):
+    """Names of the instructions whose result is an array with a ``vocab``
+    dimension of ``min_bytes`` or more (the logits at config 5)."""
+    big = set()
+    for name, dtype, dims in _SHAPED.findall(text):
+        shape = [int(d) for d in dims.split(",") if d]
+        size = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4}.get(dtype, 4)
+        if vocab in shape and math.prod(shape) * size >= min_bytes:
+            big.add(name)
+    return big
+
+
+def _scheduled(text: str) -> str:
+    """The compiled text without the bodies of fused computations: the
+    instructions the device runs one by one."""
+    fused = set(re.findall(r"fusion\(.*?calls=%([\w.\-]+)", text))
+    kept, skip = [], False
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            skip = head[1] in fused
+        if not skip:
+            kept.append(line)
+        if line.startswith("}"):
+            skip = False
+    return "\n".join(kept)
+
+
+def _logits_readers(text: str, rows: int, vocab: int) -> list[str]:
+    """XLA fusions whose one operand of the logits' shape ``[rows, vocab]``
+    is their only large one (a pass that only reads the logits). The head's
+    weight gradient is such a fusion by design (its result is the
+    ``[H, V]`` gradient); the rest are what the kernels exist to take out."""
+    text = _scheduled(text)
+    logits = {name for name, _, dims in _SHAPED.findall(text)
+              if dims == f"{rows},{vocab}"}
+    found = []
+    for name, result, operands in _FUSION.findall(text):
+        read = [o for o in re.findall(r"%([\w.\-]+)", operands) if o in logits]
+        if len(read) == 1 and f",{vocab}]" not in result.split("{")[0]:
+            found.append(f"{name} = {result}")
+    return found
+
+
+def _assert_head_on_kernels(compiled, rows: int, vocab: int = 50_000):
+    """The compiled config-5 step: one `lm_head_fwd` and one `lm_head_dx`
+    a step, no relayout of a vocabulary array of 100 MB or more, and no
+    XLA fusion that only reads the ``[rows, vocab]`` logits (the weight
+    gradient's aside)."""
+    text = compiled.as_text()
+    assert _head_kernels(text) == ["lm_head_dx", "lm_head_fwd"], \
+        _head_kernels(text)
+    big = _vocab_arrays(text, vocab)
+    relayouts = [line.strip()[:160] for line in text.splitlines()
+                 if re.match(r"^\s*%([\w.\-]+) = \S+ (copy|transpose)\(", line)
+                 and re.match(r"^\s*%([\w.\-]+)", line)[1] in big]
+    assert not relayouts, relayouts
+    readers = _logits_readers(text, rows, vocab)
+    assert not readers, readers
+
+
+@pytest.mark.parametrize("h,v", [(1024, 50_000), (1024, 32_000),
+                                 (2048, 50_000), (128, 300), (4096, 32_000),
+                                 (1024, 50_048)])
+def test_stored_vocab_major_is_the_compilers_layout(chip, h, v):
+    """`pallas_xent.stored_vocab_major` against the layout the chip's
+    compiler gives an ``[H, V]`` float32 argument: the kernels engage only
+    where their ``[V, H]`` view of the stored head is free."""
+    from lstm_tensorspark_tpu.ops import pallas_xent
+
+    text = _compile(lambda w: w * 2, *_on(chip, (
+        jax.ShapeDtypeStruct((h, v), jnp.float32),))).as_text()
+    layout = re.search(r"entry_computation_layout=\{\(f32\[[\d,]+\]\{([\d,]+)",
+                       text)[1]
+    assert pallas_xent.stored_vocab_major(h, v) == (layout == "0,1"), layout
+
+
+@pytest.mark.parametrize("head_dtype,n,h,v", [
+    ("float32", 8192, 1024, 50_000), ("bfloat16", 8192, 1024, 50_000),
+    ("float32", 2048, 4096, 50_000)])
+def test_lm_head_kernels_compile(chip, head_dtype, n, h, v):
+    """`ops/pallas_xent.py`'s two kernels at config 5's shape (8,192 rows,
+    H=1024, V=50,000: a ragged last V tile), the head as the one-chip step
+    reads it (float32) and as a bf16 parameter, through `dense_xent_mean`'s
+    value and gradient; and at a width of 4,096, where config 5's tiles
+    overflow VMEM and the plan cuts them."""
+    from lstm_tensorspark_tpu.ops import xent
+
+    assert xent.pallas_xent.plan(n, h, v, jnp.bfloat16, platform="tpu")
+
+    def loss(ys, head, bias, targets):
+        return xent.dense_xent_mean(ys, head, bias, targets, jnp.bfloat16)
+
+    args = _on(chip, (jax.ShapeDtypeStruct((64, n // 64, h), jnp.float32),
+                      jax.ShapeDtypeStruct((h, v), jnp.dtype(head_dtype)),
+                      jax.ShapeDtypeStruct((v,), jnp.float32),
+                      jax.ShapeDtypeStruct((64, n // 64), jnp.int32)))
+    with _kernels_selectable():
+        compiled = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                            *args)
+    _assert_head_on_kernels(compiled, n, v)
+
+
+@pytest.mark.parametrize("axis", ["seq", "pipe"])
+def test_partly_manual_lm_steps_keep_the_xla_head(axis):
+    """The sequence- and pipeline-parallel LM steps without `use_pallas`
+    make only their own axes manual ({data, seq} or {pipe, data}) and leave
+    the rest automatic, "model" among them, of one device here. Mosaic
+    lowers no `pallas_call` under such a `shard_map`, whatever the sizes of
+    its automatic axes, so the head keeps XLA's operations and the step
+    compiles for the four described chips (data 2 x seq or pipe 2) at a
+    width and vocabulary the kernels take on one chip."""
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from lstm_tensorspark_tpu.parallel import (
+        make_mesh, make_pp_lm_train_step, make_sharded_lm_train_step,
+        stack_lm_params)
+    from lstm_tensorspark_tpu.train import make_optimizer
+    from lstm_tensorspark_tpu.train.loop import init_train_state
+
+    mesh = make_mesh(dp=2, **{"sp" if axis == "seq" else "pp": 2},
+                     devices=np.asarray(_v5e_devices()))
+    cfg = LMConfig(vocab_size=300, hidden_size=128, num_layers=2,
+                   compute_dtype="bfloat16", logits_dtype="bfloat16")
+    optimizer = make_optimizer("adam", 1e-3)
+    params = jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), cfg))
+    if axis == "seq":
+        step = make_sharded_lm_train_step(cfg, optimizer, mesh, params)
+    else:
+        params = jax.eval_shape(stack_lm_params, params)
+        step = make_pp_lm_train_step(cfg, optimizer, mesh, params,
+                                     microbatches=2)
+    state = jax.eval_shape(lambda: init_train_state(
+        params, optimizer, jax.random.PRNGKey(1)))
+    rows = P("data", "seq") if axis == "seq" else P("data")
+    batch = {k: jax.ShapeDtypeStruct((8, 32), jnp.int32,
+                                     sharding=NamedSharding(mesh, rows))
+             for k in ("inputs", "targets")}
+    # the leaves the step leaves to propagation, replicated
+    state = state._replace(opt_state=jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, P())),
+        state.opt_state))
+    with _kernels_selectable():
+        text = step.lower(state, batch).compile().as_text()
+    assert _head_kernels(text) == []
 
 
 # ---- serve decode windows ---------------------------------------------
@@ -362,6 +528,7 @@ def test_config5_cell_train_step_donates_its_state(chip):
     assert compiled.as_text().count("tpu_custom_call") >= 2 * cfg.num_layers
     _fits_hbm(compiled)
     _state_updated_in_place(compiled, state)
+    _assert_head_on_kernels(compiled, B * T)
 
 
 @pytest.mark.slow
@@ -472,6 +639,10 @@ def test_config5_dp4_train_step_compiles():
     # the forward reads bf16 casts, so the compiler may gather those
     assert results("reduce-scatter") == {"[256,50000]", "[50000,256]"}
     assert results("all-gather") == {"[1024,50000]", "[50000,1024]"}
+    # the gathered head is read by XLA's matmuls (pallas_xent's
+    # `_mesh_rule`), which take its bf16 half: no float32 head is gathered
+    assert re.search(r"= bf16\[1024,50000\]\S* all-gather\(", text)
+    assert not re.search(r"= f32\[1024,50000\]\S* all-gather\(", text)
     assert not {"[1024,50000]", "[50000,1024]"} & results("all-reduce")
     # Adam writes the parameter and both moments in one fusion: on quarters
     fused = re.findall(r"= \((f32\[[\d,]+\])\S*, (f32\[[\d,]+\])\S*, "
@@ -484,6 +655,9 @@ def test_config5_dp4_train_step_compiles():
                         "50000,256", "1024,1024")
     assert not copies, copies[:4]
     _fits_hbm(compiled)
+    # the gathered head keeps XLA's operations: its weight gradient is
+    # faster after XLA's logits than after the kernels' (PERF.md, PR 38)
+    assert _head_kernels(text) == []
     # per chip: the small leaves and their moments whole, a quarter of the
     # two matrices and of theirs
     held = sum(

@@ -9,6 +9,9 @@ by name (run with ``-s`` to see the table).
 What it guards (PERF.md section 6, PR 27): with autodiff's backward the two
 transposed head matmuls each asked for dlogits in a layout of their own and
 XLA wrote the 819 MB array twice (``copy.804``, 2.5 ms of a 43.8 ms step).
+And the step runs the head's two Pallas kernels, one
+``lm_head_fwd`` and one ``lm_head_dx`` a step (``ops/pallas_xent.py``),
+whose times head the printed table.
 
 And of the same executable, after it ran (PERF.md section 6, PR 31): the
 train state is donated into it, so it copies none of the six 205 MB arrays
@@ -136,16 +139,26 @@ def cell():
 
 def test_config5_step_holds_no_vocab_relayout(cell):
     compiled, dispatches = cell
-    relayouts = vocab_relayouts(compiled.as_text())
+    text = compiled.as_text()
+    relayouts = vocab_relayouts(text)
+    kernels = sorted(re.findall(
+        r"^\s*%[\w.\-]*(lm_head_fwd|lm_head_dx)[\w.\-]* = .* custom-call\(",
+        text, re.M))
     dispatches(2)  # warm
     ops = device_op_ms(lambda: dispatches(3), steps=3 * K)
     total = sum(ops.values())
+    head = {k: sum(ms for name, ms in ops.items() if k in name.split(" = ")[0])
+            for k in ("lm_head_fwd", "lm_head_dx")}
     print(f"\nconfig-5 step, {B} x {T}, V={V}: {total:.2f} ms of device work "
-          f"a step; operations that touch the vocabulary, >= 0.2 ms a step:")
+          f"a step; the head's kernels {head['lm_head_fwd']:.2f} (forward) + "
+          f"{head['lm_head_dx']:.2f} (dx) ms; operations that touch the "
+          f"vocabulary, >= 0.2 ms a step:")
     for name, ms in sorted(ops.items(), key=lambda kv: -kv[1]):
         if str(V) in name and ms >= 0.2:
             print(f"  {ms:6.2f} ms  {re.sub(r'{[^{}]*}', '', name)[:150]}")
     assert not relayouts, "\n".join(relayouts)
+    assert kernels == ["lm_head_dx", "lm_head_fwd"], kernels
+    assert all(ms > 0 for ms in head.values()), head
 
 
 def test_config5_step_updates_its_state_in_place(cell):
