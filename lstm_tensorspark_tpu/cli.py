@@ -835,12 +835,16 @@ def _make_logged_loop(args, state, train_step, batches, steps_per_epoch, logger,
         batches = plane.wrap_batches(
             batches, start_step=int(state.step), steps_per_call=k
         )
+    profiled = None
     if args.profile_dir:
+        from .utils.op_scopes import LastCall
+
         # the program's own spans (utils/tracing.py) say what the host
         # does; the Python tracer would hook every call of every thread
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         jax.profiler.start_trace(args.profile_dir, profiler_options=options)
+        train_step = profiled = LastCall(train_step)
     try:
         state = train_loop(
             state,
@@ -874,7 +878,31 @@ def _make_logged_loop(args, state, train_step, batches, steps_per_epoch, logger,
         fin = getattr(checkpoint_fn, "finalize", None)
         if fin is not None:
             fin()
+    if profiled is not None:
+        _write_op_scopes(args.profile_dir, profiled, state, logger)
     return state
+
+
+def _write_op_scopes(profile_dir, profiled, state, logger) -> None:
+    """``<profile_dir>/op_scopes.json``: which device scope each operation
+    of the train program that ran belongs to (utils/op_scopes.py), from the
+    executable in memory — no compile."""
+    import os
+    import time
+
+    from .utils import op_scopes
+
+    t0 = time.perf_counter()
+    lowered = profiled.lower(state)
+    if lowered is None:
+        logger.log({"note": "op_scopes: the train step cannot be lowered "
+                            "again; no op_scopes.json"})
+        return
+    written = op_scopes.write(profile_dir, lowered)
+    logger.log({"note": "op_scopes",
+                "path": os.path.join(profile_dir, op_scopes.FILE),
+                "ops": {m: len(e["ops"]) for m, e in written.items()},
+                "seconds": round(time.perf_counter() - t0, 3)})
 
 
 def _run_lm(args, logger) -> int:
@@ -886,6 +914,7 @@ def _run_lm(args, logger) -> int:
     from .parallel.data_parallel import replicate
 
     from .utils import span
+    from .utils.op_scopes import bind
 
     seq_len = args.seq_len or 64
     with span("load_dataset", dataset=args.dataset):
@@ -991,11 +1020,11 @@ def _run_lm(args, logger) -> int:
             ev_carries0 = init_carries(cfg, eval_bs) if stateful else None
             if mesh is not None and stateful:
                 ev_carries0 = shard_batch(ev_carries0, mesh)
-            train_step = lambda state, w0, do_eval: dstep(  # noqa: E731
-                state, ddata.arrays, w0, edata.arrays, do_eval, ev_carries0
-            )
+            train_step = bind(dstep, lambda state, w0, do_eval: (
+                state, ddata.arrays, w0, edata.arrays, do_eval, ev_carries0))
         else:
-            train_step = lambda state, w0: dstep(state, ddata.arrays, w0)  # noqa: E731
+            train_step = bind(dstep, lambda state, w0: (
+                state, ddata.arrays, w0))
         batches = window_index_stream(ddata, k, start_step=start_step)
     else:
         stream = lm_batch_stream(
@@ -1023,9 +1052,8 @@ def _run_lm(args, logger) -> int:
                     eval_windows=args.eval_batches,
                     stateful=stateful, grad_accum=args.grad_accum,
                 )
-            train_step = lambda state, b, do_eval: mstep(  # noqa: E731
-                state, b, edata.arrays, do_eval, ev_carries0
-            )
+            train_step = bind(mstep, lambda state, b, do_eval: (
+                state, b, edata.arrays, do_eval, ev_carries0))
             batches = wrap_stream(stream, always_stack=True)
         else:
             batches = wrap_stream(stream)

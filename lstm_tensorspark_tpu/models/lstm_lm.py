@@ -21,6 +21,7 @@ from ..ops.embedding import embed_lookup
 from ..ops.lstm_cell import LSTMParams, init_lstm_params, zero_carry
 from ..ops.scan import stacked_lstm_scan
 from ..ops.xent import chunked_xent_mean, dense_xent_mean
+from ..utils.tracing import scope
 
 # Above this vocab size lm_loss switches from the dense head + loss
 # (ops/xent.py dense_xent_mean: the whole [N,V] logits array in HBM) to the
@@ -138,21 +139,23 @@ def lm_backbone(
     # embed_lookup: gather forward; at small V the gradient is an MXU
     # matmul, not a scatter (ops/embedding.py — measured 28 us/step saved
     # at the config-1 shape)
-    xs = embed_lookup(params["embedding"], tokens)
-    return stacked_lstm_scan(
-        params["layers"],
-        xs,
-        carries,
-        mask=mask,
-        dropout_rate=cfg.dropout,
-        dropout_rng=dropout_rng,
-        deterministic=deterministic,
-        compute_dtype=None if cdtype == jnp.float32 else cdtype,
-        remat_chunk=cfg.remat_chunk,
-        unroll=cfg.scan_unroll,
-        use_pallas=cfg.use_pallas,
-        bptt=cfg.bptt,
-    )
+    with scope("embed"):
+        xs = embed_lookup(params["embedding"], tokens)
+    with scope("lstm"):
+        return stacked_lstm_scan(
+            params["layers"],
+            xs,
+            carries,
+            mask=mask,
+            dropout_rate=cfg.dropout,
+            dropout_rng=dropout_rng,
+            deterministic=deterministic,
+            compute_dtype=None if cdtype == jnp.float32 else cdtype,
+            remat_chunk=cfg.remat_chunk,
+            unroll=cfg.scan_unroll,
+            use_pallas=cfg.use_pallas,
+            bptt=cfg.bptt,
+        )
 
 
 def _head_kernel(params, cfg: LMConfig):
@@ -203,17 +206,19 @@ def lm_loss(
         params, batch["inputs"], cfg, carries=carries,
         dropout_rng=dropout_rng, deterministic=deterministic,
     )
-    kernel, bias = _head_kernel(params, cfg)
-    if cfg.vocab_size >= _CHUNKED_XENT_MIN_V:
-        # big-vocab path: vocab-chunked cross-entropy (ops/xent.py) — the
-        # [B,T,V] logits/dlogits arrays never exist in HBM; head matmul
-        # recomputed chunk-wise in the backward
-        loss = chunked_xent_mean(ys.astype(jnp.float32), kernel, bias,
-                                 batch["targets"])
-    else:
-        # dense path: lm_forward's head, then nll = lse - z_t, with a
-        # backward that needs dlogits in one layout only (ops/xent.py)
-        loss = dense_xent_mean(ys, kernel, bias, batch["targets"], cfg.ldtype)
+    with scope("head"):
+        kernel, bias = _head_kernel(params, cfg)
+        if cfg.vocab_size >= _CHUNKED_XENT_MIN_V:
+            # big-vocab path: vocab-chunked cross-entropy (ops/xent.py) —
+            # the [B,T,V] logits/dlogits arrays never exist in HBM; head
+            # matmul recomputed chunk-wise in the backward
+            loss = chunked_xent_mean(ys.astype(jnp.float32), kernel, bias,
+                                     batch["targets"])
+        else:
+            # dense path: lm_forward's head, then nll = lse - z_t, with a
+            # backward that needs dlogits in one layout only (ops/xent.py)
+            loss = dense_xent_mean(ys, kernel, bias, batch["targets"],
+                                   cfg.ldtype)
     aux = {
         "loss": loss,
         "tokens": jnp.array(batch["targets"].size, jnp.float32),

@@ -345,6 +345,7 @@ def _bi_forward(fused_f, fused_b, xs2, h0, c0, mask_tbl=None, *,
             pltpu.VMEM((B2, H), jnp.float32),  # c
         ],
         interpret=interpret,
+        name="bilstm_rec_fwd",
     )(*operands)
     ys2 = jnp.moveaxis(out[0], 0, 1)
     if save_c:
@@ -420,6 +421,7 @@ def _bi_backward(fused_f, fused_b, params_f, params_b, xs2, h0, c0,
             pltpu.VMEM((B2, H), jnp.float32),
         ],
         interpret=interpret,
+        name="bilstm_rec_bwd",
     )(*operands)
 
     # per-direction weight/input cotangents: large MXU contractions over

@@ -67,6 +67,12 @@ backward strategies:
 Tiling constraints (pallas_guide.md): last dim 128 lanes; float32 sublane 8.
 `supported()` gates on B % 8 == 0 plus the cost model; callers fall back to
 `lstm_scan` otherwise.
+
+Every kernel has a fixed ``name=`` with the stem ``lstm_rec_``
+(``fwdx``, ``fwd``, ``fwd_tiled``, ``bwdx``, ``bwd``, ``bwd_tiled``): the
+compiled step's custom calls, and so a profile's operations, are labelled
+with it (``lstm_rec_fwd_tiled.24``), which is how the recurrence's device
+time is found (docs/OPERATIONS.md).
 """
 
 from __future__ import annotations
@@ -924,6 +930,7 @@ def _pallas_forward(fused, xs, h0, c0, mask_tbl=None, *,
                 pltpu.VMEM((B, H), jnp.float32),  # c
             ],
             interpret=interpret,
+            name="lstm_rec_fwdx",
         )(*operands)
         ys = jnp.moveaxis(out[0], 0, 1)
         if save_residuals:
@@ -1014,6 +1021,8 @@ def _pallas_forward(fused, xs, h0, c0, mask_tbl=None, *,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name=("lstm_rec_fwd" if strategy == "resident"
+              else "lstm_rec_fwd_tiled"),
     )(*operands)
     ys = jnp.moveaxis(out[0], 0, 1)
     if save_residuals:
@@ -1099,6 +1108,7 @@ def _pallas_backward(fused, params, xs, h0, c0, mask_tbl, ys, z, cs,
                 pltpu.VMEM((B, H), jnp.float32),
             ],
             interpret=interpret,
+            name="lstm_rec_bwdx",
         )(*operands)
     elif strategy == "resident":
         C = _chunk_for(T, parg)
@@ -1142,6 +1152,7 @@ def _pallas_backward(fused, params, xs, h0, c0, mask_tbl, ys, z, cs,
                 pltpu.VMEM((B, H), jnp.float32),
             ],
             interpret=interpret,
+            name="lstm_rec_bwd",
         )(*operands)
     else:
         K = 4 * H // ttile
@@ -1190,6 +1201,7 @@ def _pallas_backward(fused, params, xs, h0, c0, mask_tbl, ys, z, cs,
             ],
             scratch_shapes=scratch,
             interpret=interpret,
+            name="lstm_rec_bwd_tiled",
         )(*operands)
 
     # dU contracts over all T·B at once — one large MXU matmul for EVERY

@@ -27,7 +27,7 @@ import optax
 from .. import obs
 from ..ops import parallel_scan as _pscan
 from ..resilience import faults as _faults
-from ..utils.tracing import span
+from ..utils.tracing import scope, span
 from .sharded_update import WHOLE, DpReduce
 
 
@@ -157,24 +157,26 @@ def step_body(
     grads = _faults.tamper_grads(grads, state.step)  # identity when unarmed
     if reduce_fn is not None:
         grads, loss = reduce_fn(grads, loss)
-    gnorm = part.global_norm(grads)
-    updates, opt_state = optax.with_extra_args_support(optimizer).update(
-        grads, state.opt_state, state.params, global_norm=gnorm)
-    params = optax.apply_updates(state.params, updates)
-    # Non-finite guard: a NaN/Inf loss or gradient must not poison the
-    # params/optimizer moments (one bad batch would otherwise end the run —
-    # every later step inherits the NaNs). Skip the whole update (params,
-    # moments, AND carries — a diverged forward pass taints the recurrent
-    # state too), advance step/rng so the budget and data order hold, and
-    # surface the skip as metrics["anomalous"] for the host loop to count.
-    # Under DP the guard decision is uniform across shards: the loss is
-    # pmean'd and the norm is the global one before the check.
-    finite = jnp.isfinite(loss) & jnp.isfinite(gnorm)
-    keep = lambda new, old: jnp.where(finite, new, old)  # noqa: E731
-    params = jax.tree.map(keep, params, state.params)
-    opt_state = jax.tree.map(keep, opt_state, state.opt_state)
-    if stateful:
-        carries = jax.tree.map(keep, carries, state.carries)
+    with scope("optimizer"):
+        gnorm = part.global_norm(grads)
+        updates, opt_state = optax.with_extra_args_support(optimizer).update(
+            grads, state.opt_state, state.params, global_norm=gnorm)
+        params = optax.apply_updates(state.params, updates)
+        # Non-finite guard: a NaN/Inf loss or gradient must not poison the
+        # params/optimizer moments (one bad batch would otherwise end the
+        # run — every later step inherits the NaNs). Skip the whole update
+        # (params, moments, AND carries — a diverged forward pass taints
+        # the recurrent state too), advance step/rng so the budget and data
+        # order hold, and surface the skip as metrics["anomalous"] for the
+        # host loop to count. Under DP the guard decision is uniform across
+        # shards: the loss is pmean'd and the norm is the global one before
+        # the check.
+        finite = jnp.isfinite(loss) & jnp.isfinite(gnorm)
+        keep = lambda new, old: jnp.where(finite, new, old)  # noqa: E731
+        params = jax.tree.map(keep, params, state.params)
+        opt_state = jax.tree.map(keep, opt_state, state.opt_state)
+        if stateful:
+            carries = jax.tree.map(keep, carries, state.carries)
     metrics = {
         "loss": loss,
         "grad_norm": gnorm,

@@ -27,7 +27,9 @@ into the step program and one out of it (0.63 ms each for 205 MB: all the
 update saved; PERF.md §6, PR 35). Gathered into a temporary there is none.
 
 Which leaves: from their shapes and the mesh alone — no flag, no model name.
-Imports nothing of this package, so train/loop.py can import it.
+Every collective here runs under the device scope ``dp_sync``
+(`utils/tracing.py`). Imports nothing of this package but that module, so
+train/loop.py can import it.
 """
 
 from __future__ import annotations
@@ -40,6 +42,8 @@ import jax.numpy as jnp
 import optax
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ..utils.tracing import scope
 
 # A leaf's update is sharded from this many bytes up. Below it the two
 # collectives' fixed cost outweighs the optimizer pass they save. Four TPU
@@ -108,24 +112,27 @@ class Partition(NamedTuple):
         if not self.sharded:
             return params
         leaves, treedef = jax.tree.flatten(params)
-        return treedef.unflatten([
-            p if d is None
-            else lax.all_gather(p, self.axis, axis=d, tiled=True)
-            for p, d in zip(leaves, self.dims)])
+        with scope("dp_sync"):
+            return treedef.unflatten([
+                p if d is None
+                else lax.all_gather(p, self.axis, axis=d, tiled=True)
+                for p, d in zip(leaves, self.dims)])
 
     def reduce(self, grads):
         """The cross-chip mean of whole per-chip ``grads``: replicated
         leaves in one all-reduce, a sharded leaf as this chip's share."""
-        if not self.sharded:
-            return lax.pmean(grads, self.axis)
-        dp = lax.axis_size(self.axis)
-        leaves, treedef = jax.tree.flatten(grads)
-        whole = iter(lax.pmean(
-            [g for g, d in zip(leaves, self.dims) if d is None], self.axis))
-        return treedef.unflatten([
-            next(whole) if d is None else lax.psum_scatter(
-                g, self.axis, scatter_dimension=d, tiled=True) / dp
-            for g, d in zip(leaves, self.dims)])
+        with scope("dp_sync"):
+            if not self.sharded:
+                return lax.pmean(grads, self.axis)
+            dp = lax.axis_size(self.axis)
+            leaves, treedef = jax.tree.flatten(grads)
+            whole = iter(lax.pmean(
+                [g for g, d in zip(leaves, self.dims) if d is None],
+                self.axis))
+            return treedef.unflatten([
+                next(whole) if d is None else lax.psum_scatter(
+                    g, self.axis, scatter_dimension=d, tiled=True) / dp
+                for g, d in zip(leaves, self.dims)])
 
     def global_norm(self, grads):
         """`optax.global_norm` of the whole gradient, from a tree whose
@@ -138,7 +145,9 @@ class Partition(NamedTuple):
                    for g, d in zip(leaves, self.dims) if d is not None)
         whole = sum(jnp.sum(jnp.square(g))
                     for g, d in zip(leaves, self.dims) if d is None)
-        return jnp.sqrt(lax.psum(mine, self.axis) + whole)
+        with scope("dp_sync"):
+            mine = lax.psum(mine, self.axis)
+        return jnp.sqrt(mine + whole)
 
 
 WHOLE = Partition()
@@ -155,7 +164,9 @@ class DpReduce:
         self.part = part
 
     def __call__(self, grads, loss):
-        return self.part.reduce(grads), lax.pmean(loss, self.part.axis)
+        grads = self.part.reduce(grads)
+        with scope("dp_sync"):
+            return grads, lax.pmean(loss, self.part.axis)
 
 
 # ---- where the state lives: specs for the step and for its placement ----

@@ -49,6 +49,7 @@ import threading
 import time
 from collections import deque
 
+import jax
 from jax.profiler import TraceAnnotation
 
 
@@ -158,3 +159,22 @@ class span:
         self._annotation.__exit__(*exc)
         if _tracer is not None:
             _tracer.complete(self.name, self.start, self.end, **self.args)
+
+
+#: The train program's device scopes: every operation of a train step (and
+#: of its gradient: JAX carries a scope into the backward as
+#: ``transpose(jvp(head))``) traced under one of these names is that
+#: layer's work, the innermost name winning. `utils/op_scopes.py` reads them
+#: back from the compiled program into ``op_scopes.json`` beside a
+#: ``--profile-dir`` trace, where the benchmark's per-layer device metrics
+#: find them.
+SCOPES = ("embed", "lstm", "head", "optimizer", "dp_sync")
+
+
+def scope(name: str):
+    """``with scope("head"):`` — `jax.named_scope` for one of `SCOPES`.
+    Compile-time metadata only (the ``op_name`` of every HLO instruction
+    traced inside): nothing runs, with or without a profiler."""
+    if name not in SCOPES:
+        raise ValueError(f"unknown device scope {name!r}: one of {SCOPES}")
+    return jax.named_scope(name)
